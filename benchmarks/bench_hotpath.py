@@ -44,6 +44,7 @@ import numpy as np
 
 from benchmarks.programs import NON_CONVERTIBLE, REGISTRY
 from repro.core import function as terra_function
+from repro.launch import compile_cache
 
 DEFAULT_PROGRAMS = ["resnet", "gpt2", "bert_qa", "fasterrcnn"]
 BASELINE_PATH = os.path.join(os.path.dirname(__file__),
@@ -335,6 +336,7 @@ def main(argv=None):
     ap.add_argument("--out", default="BENCH_hotpath.json")
     ap.add_argument("--baseline", default=BASELINE_PATH)
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.smoke:
         args.programs = args.programs[:2]
         args.warmup, args.iters, args.rounds = 6, 20, 1

@@ -64,6 +64,7 @@ from repro.configs import smoke_config
 from repro.core.events import (JsonlSink, RequestTraceProcessor,
                                TimingProcessor)
 from repro.core.events.schema import validate_jsonl
+from repro.launch import compile_cache
 from repro.models import model as M
 from repro.obs import Histogram, MetricsProcessor, TraceViewerExporter
 from repro.serve.engine import Request, ServingEngine
@@ -370,6 +371,7 @@ def main():
                          "traced re-run (schema-validated; '' disables)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = smoke_config(args.arch)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))

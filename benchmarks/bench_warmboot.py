@@ -23,6 +23,10 @@ the ambient ``$TERRA_CACHE_DIR``, no tempdir) twice: the second
 invocation adds ``--expect-warm``, which fails the job if anything was
 retraced or recompiled.
 
+Unlike the other benchmarks, this one leaves JAX's persistent compilation
+cache (``repro.launch.compile_cache``) off: its cold arm has to compile
+cold, and XLA's cache would hide the compile the store exists to skip.
+
 Writes ``BENCH_warmboot.json``.
 
 Usage:

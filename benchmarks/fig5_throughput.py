@@ -10,6 +10,7 @@ import numpy as np
 
 from benchmarks.programs import NON_CONVERTIBLE, REGISTRY
 from repro.core import function as terra_function, imperative
+from repro.launch import compile_cache
 
 
 def time_variant(name: str, variant: str, warmup: int = 12,
@@ -50,6 +51,7 @@ def time_variant(name: str, variant: str, warmup: int = 12,
 
 
 def main():
+    compile_cache.enable()
     print("program,imperative_us,terra_us,fulljit_us,"
           "terra_speedup,fulljit_speedup,traced_iters,transitions,replays")
     rows = []

@@ -18,6 +18,7 @@ import time
 
 from benchmarks.programs import REGISTRY
 from repro.core import function as terra_function
+from repro.launch import compile_cache
 from repro.obs import MetricsRegistry, counters_table
 
 COUNTER_KEYS = ("segment_cache_hits", "segments_recompiled",
@@ -62,6 +63,7 @@ def breakdown(name: str, warmup: int = 12, measure: int = 40):
 
 
 def main():
+    compile_cache.enable()
     report = {}
     for name in sorted(REGISTRY):
         reg = breakdown(name)

@@ -8,6 +8,7 @@ import numpy as np
 
 from benchmarks.programs import NON_CONVERTIBLE, REGISTRY
 from repro.core import function as terra_function
+from repro.launch import compile_cache
 
 
 def classify_fulljit(name: str, steps: int = 10):
@@ -39,6 +40,7 @@ def run_terra(name: str, steps: int = 10):
 
 
 def main():
+    compile_cache.enable()
     rows = []
     print("program,terra,fulljit,failure_reason")
     for name in sorted(REGISTRY):

@@ -17,6 +17,7 @@ import time
 
 from benchmarks.programs import REGISTRY
 from repro.core import function as terra_function, imperative
+from repro.launch import compile_cache
 
 PROGRAMS = ["resnet", "bert_qa", "dcgan"]
 IO_S = 0.010                      # simulated data-pipeline wait per step
@@ -60,6 +61,7 @@ def timed_imperative(name, warmup=12, measure=40):
 
 
 def main():
+    compile_cache.enable()
     print("program,terra_speedup,terra_lazyeval_speedup")
     for name in PROGRAMS:
         imp = timed_imperative(name)

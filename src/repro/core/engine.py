@@ -111,6 +111,7 @@ class TerraFunction:
                 eng.end_iteration()
                 steady.attach_futures(eng, out)
                 steady.observe(eng, args, kwargs, out)
+            eng.settle()
         except BaseException:
             # leave the engine usable: cancel the half-open iteration and
             # roll back to its start snapshot before propagating
@@ -155,8 +156,9 @@ def function(fn: Callable = None, *, lazy: bool = False, seed: int = 0,
 
     ``optimize`` selects the symbolic optimization pipeline run over each
     shape family's TraceGraph before segment compilation (DESIGN.md §10):
-    ``"all"`` (default; adds Pallas kernel substitution on TPU), ``"safe"``
-    (no constant-feed folding — for drivers whose feeds change per call),
+    ``"all"`` (default), ``"safe"`` (no constant-feed folding — for
+    programs whose feeds change per call; both add Pallas kernel
+    substitution on TPU),
     ``"none"`` (compile the trace verbatim, the pre-pass behaviour), or an
     explicit tuple of pass names.  ``None`` defers to ``$TERRA_OPTIMIZE``.
 

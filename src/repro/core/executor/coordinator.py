@@ -112,7 +112,7 @@ class TerraEngine(PythonRunnerOps, VariableOps):
         self.walker: Optional[Walker] = None
         self.dispatcher = None
         self._iter_open = False
-        self._snapshot_slot: Dict[int, Any] = {}
+        self._snapshot_slot: Optional[Dict[int, Any]] = None   # settle()
 
     @property
     def vars(self) -> Dict[int, Variable]:
@@ -125,6 +125,7 @@ class TerraEngine(PythonRunnerOps, VariableOps):
         # load this shape class's TraceGraph/GraphProgram/phase (§8)
         self.fm.switch(self, (feed_sig, self.store.avals_digest()))
         self.iter_id += 1
+        self.cut_snapshot_hold()
         ev.iteration_start(self.events, self.iter_id, self.mode,
                            self.family.key)
         self.trace = Trace()
@@ -205,6 +206,7 @@ class TerraEngine(PythonRunnerOps, VariableOps):
         for vid, t in self._var_binding.items():
             self.store.put(vid, t._eager if t._eager is not None
                            else t.value())
+        self.store.release_seeds()
         rolled = roll_loops(self.trace)
         covered = self.tg.merge_trace(self.trace, rolled)
         fam = self.family
@@ -263,9 +265,8 @@ class TerraEngine(PythonRunnerOps, VariableOps):
             es.inc("walker_fast_hits", self.walker.fast_hits)
             es.inc("fold_divergences", self.walker.fold_misses)
         self._fallback.cancel_and_replay(self.trace, self._feed_log,
-                                         self._snapshot_slot, self._vals,
-                                         self._tensors,
-                                         iter_id=self.iter_id)
+                                         self.start_snapshot(), self._vals,
+                                         self._tensors, iter_id=self.iter_id)
         self.mode = TRACING
         es.inc("retraces")
         self._covered_streak = 0
@@ -306,8 +307,7 @@ class TerraEngine(PythonRunnerOps, VariableOps):
         if was_skeleton:
             es = self.events
             self.runner.cancel()
-            self.store.restore(self._snapshot_slot)
-            ev.rollback(es, self.iter_id, len(self._snapshot_slot))
+            self.rollback_open_iteration()
             ev.retrace(es, self.iter_id, "abort")
             self.mode = TRACING
             es.inc("retraces")

@@ -291,6 +291,7 @@ def _dispatch(eng, plan: SteadyPlan, leaves):
     # iteration read as stale (their values arrive through ``_future``) and
     # a later walker iteration starts from a clean binding map
     eng.iter_id += 1
+    eng.cut_snapshot_hold()
     eng._var_binding = {}
     stats["iterations"] += 1
     stats["steady_iters"] += 1

@@ -1,5 +1,6 @@
 """Variable surface of the TerraEngine: reads, assigns, out-of-band
-rebinds, RNG and the per-value fence wait.
+rebinds, the iteration-start snapshot's lifetime, RNG and the per-value
+fence wait.
 
 Split out of coordinator.py as a mixin for the same reason
 python_runner.py is one — the phase machine and the variable API are
@@ -12,6 +13,7 @@ updates (the serving prefill path) live in varops.py.
 from __future__ import annotations
 
 import time
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -24,10 +26,32 @@ from repro.core.trace import Aval, Ref, VarAssign, VarRef
 SKELETON = "skeleton"
 
 
+class _SnapshotHold:
+    """An iteration-start snapshot kept alive by the placeholders that may
+    still replay a closed iteration (``VariableOps.settle``)."""
+
+    __slots__ = ("snap", "__weakref__")
+
+    def __init__(self, snap):
+        self.snap = snap
+
+
+def no_hold():
+    return None
+
+
 class VariableOps:
     """Mixin for TerraEngine: the variable-facing API."""
 
+    _snapshot_hold = staticmethod(no_hold)     # weakref to a _SnapshotHold
+
     def _ensure_var(self, var: Variable):
+        owner = var._owner
+        if owner is None:
+            var._owner = self           # this store holds the buffer now
+        elif owner is not self and var.var_id not in self.store.buffers:
+            # another engine holds the buffer: start from its value
+            self.store.put(var.var_id, owner.variable_value(var))
         self.store.ensure(var)
 
     def read_variable(self, var: Variable) -> TerraTensor:
@@ -105,7 +129,6 @@ class VariableOps:
         self._await_fence(self.store.use_fence(var.var_id))
         value = jnp.asarray(value)
         self.store.put(var.var_id, value)
-        var._value = value
         new_aval = Aval.of(value)
         if new_aval != var.aval:
             var.aval = new_aval
@@ -115,6 +138,60 @@ class VariableOps:
         """Drop a variable's buffer from the store (driver-retired state)."""
         self._await_fence(self.store.use_fence(var.var_id))
         self.store.remove(var.var_id)
+
+    # ------------------------------------------------------------------
+    # iteration-start snapshot lifetime
+    # ------------------------------------------------------------------
+    def settle(self):
+        """End of a call: stop keeping the previous state alive.  A replay
+        of the closed iteration restarts from its start snapshot, and only
+        a placeholder with neither a value nor a fetch future can ask for
+        one.  Each such placeholder holds the snapshot, and the engine
+        keeps weak references to them and to the snapshot, so the
+        snapshot's buffers — the whole state before the call when every
+        variable was written — are freed once the driver drops them."""
+        pending = {k: t for k, t in self._tensors.items()
+                   if t._eager is None and t._future is None
+                   and t._iter == self.iter_id}
+        if pending and self._snapshot_slot is not None:
+            hold = _SnapshotHold(self._snapshot_slot)
+            for t in pending.values():
+                t._hold = hold
+            self._snapshot_hold = weakref.ref(hold)
+            self._tensors = weakref.WeakValueDictionary(pending)
+        else:
+            self._tensors = {}
+        self._snapshot_slot = None
+        self._var_binding = {}
+
+    def cut_snapshot_hold(self):
+        """The iteration clock moved on: placeholders of earlier
+        iterations are stale and can no longer replay, so the snapshot
+        they hold goes."""
+        hold = self._snapshot_hold()
+        if hold is not None:
+            hold.snap = None
+        self._snapshot_hold = no_hold
+
+    def rollback_open_iteration(self):
+        """Restore the store to the open iteration's start (an aborted
+        call).  A steady-state call takes no snapshot: nothing to undo."""
+        snap = self._snapshot_slot
+        if snap is not None:
+            self.store.restore(snap)
+            ev.rollback(self.events, self.iter_id, len(snap))
+
+    def start_snapshot(self):
+        """The snapshot a replay of the current iteration restores: the
+        open iteration's, or the one a placeholder of the closed iteration
+        holds."""
+        if self._snapshot_slot is not None:
+            return self._snapshot_slot
+        hold = self._snapshot_hold()
+        if hold is None or hold.snap is None:
+            raise RuntimeError("replay of a closed iteration whose start "
+                               "snapshot was released")
+        return hold.snap
 
     # ------------------------------------------------------------------
     # RNG

@@ -110,12 +110,15 @@ class VariableStore:
 
     def remove(self, var_id: int) -> None:
         """Unregister a variable and release its device buffer (drivers
-        retiring state, e.g. serving caches whose shapes changed)."""
+        retiring state, e.g. serving caches whose shapes changed).  The
+        buffer goes back to the Variable, which no engine owns any more."""
         buf = self.buffers.pop(var_id, None)
-        self.vars.pop(var_id, None)
+        var = self.vars.pop(var_id, None)
         self._avals_digest = None
         if buf is not None:
             self.tombstones[var_id] = (tuple(buf.shape), buf.dtype)
+        if var is not None:
+            var._value, var._owner = buf, None
 
     def read(self, var_id: int):
         """Dispatch-time read: live buffer, or a zeros placeholder for a
@@ -143,6 +146,17 @@ class VariableStore:
     def snapshot_into(self, snap: Dict[int, Any]) -> None:
         """Copy current buffer refs into ``snap`` (runner-thread closure)."""
         snap.update(self.buffers)
+        self.release_seeds()
+
+    def release_seeds(self) -> None:
+        """Let go of the initial value of every variable with a committed
+        buffer (in a snapshot, or written by a traced iteration): no
+        rollback can need a re-seed any more, and the state before the
+        first write is not kept for the whole run."""
+        for vid in tuple(self.buffers):
+            var = self.vars.get(vid)
+            if var is not None:
+                var._value = None
 
     def restore(self, snap: Dict[int, Any]) -> None:
         """Roll the store back to a snapshot (divergence cancellation)."""

@@ -93,7 +93,7 @@ def _classify_arg(a):
         return ("tensor", a)
     if isinstance(a, Variable):
         # implicit read
-        return ("tensor", a.read()) if current_engine() is not None else ("feed", a._value)
+        return ("tensor", a.read()) if current_engine() is not None else ("feed", a.value())
     if isinstance(a, (bool, int, float)) or a is None:
         return ("const", a)
     if isinstance(a, (np.ndarray, np.generic)):

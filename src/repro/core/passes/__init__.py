@@ -45,7 +45,9 @@ PASS_ORDER = ("fold", "cse", "kernels", "dce", "coalesce")
 PIPELINES = {
     "none": (),
     # "safe": everything that never bakes a Python value into the graph —
-    # serving uses this so per-call feeds (decode tokens) are never folded
+    # serving uses this so per-call feeds (decode tokens) are never folded.
+    # Kernel substitution bakes nothing either, so it belongs to both
+    # levels on TPU (added by resolve_pipeline)
     "safe": ("cse", "dce", "coalesce"),
     "all": ("fold", "cse", "dce", "coalesce"),
 }
@@ -55,10 +57,11 @@ def resolve_pipeline(optimize, backend: Optional[str] = None) -> Tuple[str, ...]
     """Normalize the ``optimize=`` knob to a canonical pass tuple.
 
     ``None`` defers to ``TERRA_OPTIMIZE`` (default ``all``).  ``"all"``
-    additionally enables kernel substitution on TPU backends, where the
-    Pallas kernels compile natively; elsewhere ``kernels`` must be
-    requested explicitly (interpret-mode execution is for validation, not
-    speed).  An explicit tuple/list is validated and reordered."""
+    and ``"safe"`` additionally enable kernel substitution on TPU
+    backends, where the Pallas kernels compile natively; elsewhere
+    ``kernels`` must be requested explicitly (interpret-mode execution is
+    for validation, not speed).  An explicit tuple/list is validated and
+    reordered."""
     if optimize is None:
         optimize = os.environ.get("TERRA_OPTIMIZE") or "all"
     if isinstance(optimize, str):
@@ -67,7 +70,7 @@ def resolve_pipeline(optimize, backend: Optional[str] = None) -> Tuple[str, ...]
                              f"expected one of {sorted(PIPELINES)} or a "
                              f"tuple of pass names {PASS_ORDER}")
         passes = set(PIPELINES[optimize])
-        if optimize == "all":
+        if optimize != "none":
             if backend is None:
                 import jax
                 backend = jax.default_backend()

@@ -23,9 +23,11 @@ Patterns:
   outside the pattern (in particular no ``.vjp`` tape consumers — a
   differentiated attention keeps its unfused form) and fall to DCE.
 
-The pass only runs when requested: ``optimize="all"`` enables it on TPU
-backends where the kernels compile natively; elsewhere it must be named
-explicitly (interpret-mode Pallas validates numerics but is not fast).
+On TPU backends, where the kernels compile natively, ``optimize="all"``
+and ``"safe"`` both run the pass (it bakes no Python value into the
+graph), so the paged serving pool reaches its kernel by default.
+Elsewhere it must be named explicitly (interpret-mode Pallas validates
+numerics but is not fast).
 """
 
 from __future__ import annotations

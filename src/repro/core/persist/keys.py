@@ -3,9 +3,10 @@
 Nothing derived from Python's process-salted ``hash()`` (entry stamps,
 ``avals_digest``, ``FoldedConst._key``) ever reaches disk: on-disk keys
 are sha256 digests of the canonical JSON form produced by codec.py.  The
-store namespace folds in the jax version, the active backend and a digest
-of the repro source tree, so upgrading jax, switching platform or editing
-the engine makes every prior artifact a clean miss — never a wrong load.
+store namespace folds in the jax version, the active backend and device
+kind, and a digest of the repro source tree, so upgrading jax, switching
+platform or chip generation, or editing the engine makes every prior
+artifact a clean miss — never a wrong load.
 ``$TERRA_CACHE_SALT`` is appended to the namespace when set (the tests'
 version-skew lever; also handy for manual cache busting)."""
 
@@ -15,6 +16,8 @@ import functools
 import hashlib
 import json
 import os
+import re
+import subprocess
 from typing import Optional
 
 import jax
@@ -39,20 +42,47 @@ def digest_of(v) -> Optional[str]:
     return hashlib.sha256(s.encode("utf-8")).hexdigest()[:24]
 
 
-@functools.lru_cache(maxsize=1)
-def code_digest() -> str:
-    """Digest of every .py file under the repro package — any source edit
-    invalidates the whole cache namespace."""
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    h = hashlib.sha256()
+def _git_listed(root: str) -> Optional[set]:
+    """Absolute paths under ``root`` that git tracks or would track (an
+    untracked file no ignore rule matches), or None when ``root`` is not
+    inside a git work tree (or git is unavailable)."""
+    try:
+        out = subprocess.run(["git", "ls-files", "-z", "--cached", "--others",
+                              "--exclude-standard", "--", "."], cwd=root,
+                             capture_output=True, check=True,
+                             timeout=30).stdout
+    except (OSError, subprocess.SubprocessError):
+        return None
+    return {os.path.join(root, p) for p in out.decode().split("\0") if p}
+
+
+def source_files(root: str, anchor: str) -> list:
+    """The .py files under ``root`` that make up the code, sorted.  Files
+    git ignores do not count, so a build output or a scratch copy of the
+    tree does not move the key.  When git's listing lacks ``anchor`` (no
+    git, or ``root`` is itself a gitignored copy, where git lists
+    nothing) every .py file counts."""
+    listed = _git_listed(root)
+    if listed is not None and anchor not in listed:
+        listed = None
     paths = []
     for dirpath, dirnames, filenames in os.walk(root):
         dirnames.sort()
         for fn in filenames:
-            if fn.endswith(".py"):
-                paths.append(os.path.join(dirpath, fn))
-    for p in sorted(paths):
+            p = os.path.join(dirpath, fn)
+            if fn.endswith(".py") and (listed is None or p in listed):
+                paths.append(p)
+    return sorted(paths)
+
+
+@functools.lru_cache(maxsize=1)
+def code_digest() -> str:
+    """Digest of the repro package's .py files (:func:`source_files`) —
+    any source edit invalidates the whole cache namespace."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    h = hashlib.sha256()
+    for p in source_files(root, os.path.abspath(__file__)):
         h.update(os.path.relpath(p, root).encode("utf-8"))
         try:
             with open(p, "rb") as f:
@@ -64,8 +94,11 @@ def code_digest() -> str:
 
 def namespace() -> str:
     """Versioned manifest key: the store root subdirectory all artifacts
-    of this (jax version, backend, code) combination live under."""
-    ns = f"jax{jax.__version__}-{jax.default_backend()}-code{code_digest()}"
+    of this (jax version, backend, device kind, code) combination live
+    under."""
+    kind = re.sub(r"[^A-Za-z0-9]+", "_", jax.devices()[0].device_kind)
+    ns = (f"jax{jax.__version__}-{jax.default_backend()}-{kind}"
+          f"-code{code_digest()}")
     salt = os.environ.get("TERRA_CACHE_SALT", "")
     if salt:
         ns += f"-{salt}"

@@ -36,7 +36,7 @@ class TerraTensor:
     """Handle for a DL-op result inside a Terra-managed program."""
 
     __slots__ = ("ref", "aval", "_eager", "engine", "_iter", "_future",
-                 "__weakref__")
+                 "_hold", "__weakref__")
 
     def __init__(self, ref, aval: Aval, eager=None, engine=None, iter_id=-1):
         self.ref = ref
@@ -48,6 +48,9 @@ class TerraTensor:
         # iteration closes: lets the value be awaited *after* a later
         # iteration has started (the scheduler's lag-harvest window)
         self._future = None
+        # the iteration-start snapshot, held by a placeholder of a closed
+        # iteration that only a replay can still fill (varapi.py settle)
+        self._hold = None
 
     # -- metadata (always available; no materialization needed) ------------
     @property
@@ -160,6 +163,12 @@ class Variable:
     recorded in the trace so the generated symbolic graph threads the update
     — this is what lets Terra run programs with Python *object mutation*
     (Figure 1c) that static converters mishandle.
+
+    The first engine to use a variable becomes its owner: outside any
+    engine call ``read``, ``value`` and ``assign`` go through the owner's
+    store, and once a traced commit or an iteration-start snapshot holds
+    the buffer the Variable lets its initial value go (variables.py
+    ``release_seeds``).
     """
 
     _next_id = [0]
@@ -173,19 +182,27 @@ class Variable:
         self.name = name or f"var{self.var_id}"
         self._value = jnp.asarray(init_value)
         self.aval = Aval.of(self._value)
+        self._owner = None          # the engine holding the buffer, once used
 
     # read
     def read(self) -> Any:
         eng = current_engine()
         if eng is None:
-            return self._value
+            return self.value()
         return eng.read_variable(self)
 
     def assign(self, new_value) -> None:
         eng = current_engine()
         if eng is None:
             import jax.numpy as jnp
-            self._value = jnp.asarray(new_value)
+            if isinstance(new_value, TerraTensor):
+                # keep the device buffer (and its sharding); going through
+                # __array__ would copy it to the host and back to device 0
+                new_value = new_value.value()
+            if self._owner is not None:
+                self._owner.reset_variable(self, new_value)
+            else:
+                self._value = jnp.asarray(new_value)
             return
         eng.assign_variable(self, new_value)
 
@@ -198,7 +215,7 @@ class Variable:
         self.assign(ops.add(self.read(), delta))
 
     def value(self):
-        eng = current_engine()
+        eng = current_engine() or self._owner
         if eng is None:
             return self._value
         return eng.variable_value(self)

@@ -31,7 +31,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import SHAPES, get_config
 from repro.configs.registry import ARCHS, LONG_CONTEXT_ARCHS
 from repro.launch import roofline as RL
-from repro.launch.mesh import HBM_PER_CHIP, make_production_mesh
+from repro.launch.mesh import (HBM_PER_CHIP, make_mesh,
+                               make_production_mesh)
 from repro.models import model as M
 from repro.parallel import specs as S
 from repro.parallel.sharding import ShardingPolicy, use_policy
@@ -257,8 +258,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                          "sub-quadratic attention (DESIGN.md §5)")
         return rec
     if opts.get("mesh_shape"):
-        import jax as _jax
-        mesh = _jax.make_mesh(tuple(opts["mesh_shape"]), ("data", "model"))
+        mesh = make_mesh(opts["mesh_shape"], ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     n_chips = mesh.size

@@ -9,6 +9,20 @@ initialization, and smoke tests must keep seeing a single device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis in ``Auto`` mode.
+
+    Since jax 0.7 ``make_mesh`` defaults to ``Explicit`` axes, under which
+    ``with_sharding_constraint`` may only name Auto axes and gathers and
+    ``shard_map`` bodies type-check shardings eagerly.  This code base
+    annotates activations with ``parallel.sharding.logical`` constraints
+    and lets XLA propagate the rest, which is the Auto-mode contract."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,15 +30,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods x 256 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh_for(devices_per_axis: dict):
-    """Elastic helper: build a mesh for whatever devices are available,
-    e.g. {'data': 4, 'model': 2} on an 8-device slice."""
-    shape = tuple(devices_per_axis.values())
-    axes = tuple(devices_per_axis.keys())
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 # Hardware constants for the roofline analysis (TPU v5e, per brief):

@@ -26,6 +26,8 @@ import os
 import jax
 
 from repro.configs import get_config, smoke_config
+from repro.launch import compile_cache
+from repro.launch.mesh import make_mesh
 from repro.train.optimizer import OptConfig
 from repro.train.trainer import Trainer
 
@@ -36,8 +38,8 @@ def build_mesh(model_parallel: int):
         return None
     assert n % model_parallel == 0, \
         f"{n} devices not divisible by model_parallel={model_parallel}"
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return make_mesh((n // model_parallel, model_parallel),
+                     ("data", "model"))
 
 
 def main():
@@ -57,6 +59,7 @@ def main():
     ap.add_argument("--no-terra", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     if "TPU_WORKER_ID" in os.environ or "MEGASCALE_COORDINATOR_ADDRESS" \
             in os.environ:

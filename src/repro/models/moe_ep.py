@@ -26,12 +26,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-    _REPL_CHECK_KW = "check_vma"
-except ImportError:                     # jax < 0.5 ships it as experimental
-    from jax.experimental.shard_map import shard_map
-    _REPL_CHECK_KW = "check_rep"        # pre-rename replication-check kwarg
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import dense
@@ -123,4 +118,4 @@ def moe_block_ep(p, x, cfg):
     xspec = P(batch_axes if batch_axes else None, None, None)
 
     return shard_map(body, mesh=mesh, in_specs=(pspec, xspec),
-                     out_specs=xspec, **{_REPL_CHECK_KW: False})(p, x)
+                     out_specs=xspec, check_vma=False)(p, x)

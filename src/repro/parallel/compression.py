@@ -23,11 +23,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:                     # jax < 0.5 ships it as experimental
-    from jax.experimental.shard_map import shard_map
 
 
 # --------------------------------------------------------------------------

@@ -14,20 +14,12 @@ unit tests run it on a host-device mesh.
 
 from __future__ import annotations
 
-import functools
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:                     # jax < 0.5 ships it as experimental
-    from jax.experimental.shard_map import shard_map
-
-# pvary marks device-varying values for the new replication checker; older
-# jax has no checker to satisfy, so it degenerates to identity
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def pipeline_forward(stage_fn: Callable, n_stages: int, axis: str):
@@ -43,8 +35,8 @@ def pipeline_forward(stage_fn: Callable, n_stages: int, axis: str):
         stage = jax.lax.axis_index(axis)
         n_ticks = M + n_stages - 1
         # replicated inputs feed device-varying collectives: mark them as
-        # varying along the pipeline axis (jax >= 0.8 vma typing)
-        mbs = _pvary(mbs, (axis,))
+        # varying along the pipeline axis (shard_map's vma typing)
+        mbs = jax.lax.pcast(mbs, (axis,), to="varying")
 
         def tick(carry, t):
             buf, outs = carry            # buf: activation entering this stage

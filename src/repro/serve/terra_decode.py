@@ -99,6 +99,7 @@ class TerraDecoder:
         # decode step's token feed changes every call, so constant-feed
         # folding must never bake one batch's tokens into the graph —
         # "safe" excludes the fold pass while keeping DCE/CSE/coalescing
+        # (and, on TPU, kernel substitution)
         self._tf = terra_function(self._step, optimize=optimize)
 
     # ------------------------------------------------------------------

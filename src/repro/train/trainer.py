@@ -22,16 +22,36 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import Variable, function as terra_function, ops as terra_ops
 from repro.core.ops import def_op
 from repro.models import model as M
+from repro.parallel import specs as S
 from repro.parallel.sharding import ShardingPolicy, use_policy
 from repro.train import checkpoint as ckpt
 from repro.train import data as data_mod
 from repro.train import optimizer as opt
 from repro.train.train_step import build_train_step
+
+
+def init_state(cfg: ModelConfig, key, mesh=None):
+    """(params, opt_state) for a fresh run.  With a mesh both are created
+    inside one jit whose ``out_shardings`` follow parallel/specs.py, so
+    every leaf is born sharded: nothing is built whole on one device."""
+    def build(k):
+        params = M.init_params(cfg, k)
+        return params, opt.init(params)
+
+    if mesh is None:
+        return build(key)
+    pspecs = S.tree_param_specs(mesh, M.abstract_params(cfg))
+    ospecs = S.opt_state_specs(mesh, None, pspecs)
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                             (pspecs, ospecs),
+                             is_leaf=lambda x: isinstance(x, P))
+    return jax.jit(build, out_shardings=shardings)(key)
 
 
 class Trainer:
@@ -52,30 +72,30 @@ class Trainer:
         self.history: list = []
         self.straggler_events: list = []
 
-        key = jax.random.PRNGKey(seed)
-        params = M.init_params(cfg, key)
-        opt_state = opt.init(params)
+        params, opt_state = init_state(cfg, jax.random.PRNGKey(seed), mesh)
         self.start_step = 0
         if ckpt_dir is not None:
             last = ckpt.latest_step(ckpt_dir)
             if last is not None:
-                # auto-resume: params+opt are stored together as one tree
-                tree = ckpt.restore(ckpt_dir, last,
-                                    {"params": params, "opt": opt_state})
+                # auto-resume: params+opt are stored together as one tree,
+                # and each leaf is put back on its fresh twin's sharding
+                tree = {"params": params, "opt": opt_state}
+                place = (None if mesh is None else
+                         jax.tree.map(lambda x: x.sharding, tree))
+                tree = ckpt.restore(ckpt_dir, last, tree, shardings=place)
                 params, opt_state = tree["params"], tree["opt"]
                 self.start_step = last
 
-        # flatten state into Terra Variables (graph-resident)
-        self._p_leaves, self._p_def = jax.tree_util.tree_flatten(params)
-        self._o_leaves, self._o_def = jax.tree_util.tree_flatten(opt_state)
-        self.p_vars = [Variable(x, f"p{i}") for i, x in
-                       enumerate(self._p_leaves)]
-        self.o_vars = [Variable(x, f"o{i}") for i, x in
-                       enumerate(self._o_leaves)]
+        # flatten state into Terra Variables (graph-resident); they are
+        # its only holders, so a step's old state can be freed
+        p_leaves, self._p_def = jax.tree_util.tree_flatten(params)
+        o_leaves, self._o_def = jax.tree_util.tree_flatten(opt_state)
+        self.p_vars = [Variable(x, f"p{i}") for i, x in enumerate(p_leaves)]
+        self.o_vars = [Variable(x, f"o{i}") for i, x in enumerate(o_leaves)]
 
         step_fn = build_train_step(cfg, self.opt_cfg,
                                    microbatches=microbatches)
-        n_p, n_o = len(self._p_leaves), len(self._o_leaves)
+        n_p, n_o = len(p_leaves), len(o_leaves)
         p_def, o_def = self._p_def, self._o_def
 
         def flat_step(*args):

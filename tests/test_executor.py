@@ -262,3 +262,71 @@ def test_serving_decode_coexecutes():
     assert stats["replays"] == 0
     assert stats["graph_versions"] == 1       # one graph serves both batches
     engine.terra.close()
+
+
+def test_eager_assign_keeps_the_device_buffer():
+    """Outside an engine an op returns an eager TerraTensor; assigning it
+    to a Variable keeps that device buffer (and so its sharding) instead
+    of a round trip through the host onto the default device."""
+    v = Variable(np.ones(4, np.float32))
+    out = ops.add(v.read(), 1.0)
+    v.assign(out)
+    assert v._value is out._eager
+    np.testing.assert_array_equal(np.asarray(v._value), np.full(4, 2.0))
+
+
+def test_closed_iteration_frees_the_previous_state():
+    """After a co-executed call returns, only the new state stays alive:
+    the Variable's initial value and the iteration-start snapshot (the
+    state before the call) are freed once nothing can replay the call,
+    and outside a call the Variable reads its committed value."""
+    import gc
+    import weakref
+    import jax.numpy as jnp
+    w = Variable(jnp.ones(4, jnp.float32), "w")
+
+    @function
+    def step(x):
+        w.assign(ops.add(w.read(), x))
+        return ops.reduce_sum(w.read())
+
+    initial = weakref.ref(w._value)
+    olds = []
+    for i in range(5):
+        out = step(np.full(4, 1.0, np.float32))
+        assert float(out) == pytest.approx(4 * (2.0 + i))
+        step.wait()
+        olds.append(weakref.ref(step.engine.store.buffers[w.var_id]))
+        if i == 0:                  # a traced iteration committed
+            gc.collect()
+            assert initial() is None
+    assert step.phase == "co-execution"
+    gc.collect()
+    assert [r() is None for r in olds] == [True] * 4 + [False]
+    np.testing.assert_array_equal(np.asarray(w.value()), np.full(4, 6.0))
+    w.assign(np.zeros(4, np.float32))          # out of band, via the owner
+    assert float(step(np.full(4, 1.0, np.float32))) == pytest.approx(4.0)
+    step.close()
+
+
+def test_kept_placeholder_replays_its_closed_iteration():
+    """A placeholder the graph does not output, kept past the call, still
+    materializes: it holds its iteration's start snapshot, and the replay
+    from it leaves the committed state unchanged."""
+    w = Variable(np.ones(4, np.float32), "w")
+    kept = []
+
+    @function
+    def step(x):
+        kept.append(ops.mul(w.read(), 2.0))
+        w.assign(ops.add(w.read(), x))
+        return ops.reduce_sum(w.read())
+
+    for i in range(3):
+        step(np.full(4, 1.0, np.float32))
+    assert step.phase == "co-execution"
+    replays = step.stats["replays"]
+    np.testing.assert_array_equal(np.asarray(kept[-1]), np.full(4, 6.0))
+    assert step.stats["replays"] == replays + 1
+    np.testing.assert_array_equal(np.asarray(w.value()), np.full(4, 4.0))
+    step.close()

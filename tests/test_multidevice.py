@@ -34,9 +34,10 @@ def test_pipeline_matches_sequential():
     import json
     import jax, jax.numpy as jnp
     import numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.parallel.pipeline import make_pipelined_apply
 
-    mesh = jax.make_mesh((8,), ("stage",))
+    mesh = make_mesh((8,), ("stage",))
     S, M, mb, d = 8, 16, 4, 32
     rng = np.random.RandomState(0)
     ws = jnp.asarray(rng.randn(S, d, d).astype(np.float32) * 0.2)
@@ -65,6 +66,7 @@ def test_shardmap_ep_moe_matches_pjit_path():
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.configs import smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.models import model as M
     from repro.parallel.sharding import ShardingPolicy, use_policy
 
@@ -74,7 +76,7 @@ def test_shardmap_ep_moe_matches_pjit_path():
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.RandomState(0)
     tokens = rng.randint(0, cfg.vocab, (4, 32)).astype(np.int32)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with mesh, use_policy(ShardingPolicy(mesh)):
         ref = M.forward(cfg, params, tokens)
         cfg2 = dataclasses.replace(cfg, moe_impl="shard_map")
@@ -91,9 +93,10 @@ def test_compressed_dp_allreduce_matches_mean():
     import json
     import jax, jax.numpy as jnp
     import numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.parallel.compression import dp_allreduce, zero_residuals
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.RandomState(1)
     g = jnp.asarray(rng.randn(8, 64).astype(np.float32))
     grads = {"w": g}
@@ -114,13 +117,14 @@ def test_sharded_train_step_runs_on_8_devices():
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.models import model as M
     from repro.parallel import specs as S
     from repro.parallel.sharding import ShardingPolicy, use_policy
     from repro.train import optimizer as opt
     from repro.train.train_step import build_train_step
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = smoke_config("llama3-8b")
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     ost = opt.init(params)
@@ -151,3 +155,41 @@ def test_sharded_train_step_runs_on_8_devices():
     losses = res["losses"]
     assert np.isfinite(losses).all()
     assert losses[-1] < losses[0]
+
+
+def test_trainer_state_stays_sharded_terra_and_plain():
+    """Trainer(mesh=...) creates params and optimizer state sharded over
+    the mesh, and the co-executed and plain paths both keep every leaf
+    spread over all 8 devices after steps, with matching losses."""
+    res = run_sub("""
+    import json
+    from repro.configs import smoke_config
+    from repro.launch.mesh import make_mesh
+    from repro.train.optimizer import OptConfig
+    from repro.train.trainer import Trainer
+
+    mesh = make_mesh((4, 2), ("data", "model"))
+    cfg = smoke_config("granite-3-2b")
+    out = {}
+    for use_terra in (True, False):
+        tr = Trainer(cfg, OptConfig(lr=1e-3, warmup_steps=1, total_steps=3),
+                     mesh=mesh, batch=8, seq_len=32, log_every=1,
+                     use_terra=use_terra)
+        state = tr.p_vars + tr.o_vars
+        born = min(len(v.value().sharding.device_set) for v in state)
+        hist = tr.train(3, verbose=False)
+        read = (tr._iteration.engine.variable_value if use_terra
+                else lambda v: v.value())
+        after = min(len(read(v).sharding.device_set) for v in state)
+        if use_terra:
+            tr._iteration.close()
+        out["terra" if use_terra else "plain"] = {
+            "born": born, "after": after,
+            "losses": [loss for _, loss in hist]}
+    print(json.dumps(out))
+    """)
+    import numpy as np
+    for arm in res.values():
+        assert arm["born"] == 8 and arm["after"] == 8, res
+    np.testing.assert_allclose(res["terra"]["losses"],
+                               res["plain"]["losses"], rtol=1e-3)

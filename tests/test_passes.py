@@ -370,6 +370,9 @@ def test_resolve_pipeline_validation():
     assert "fold" in resolve_pipeline("all", backend="cpu")
     assert "kernels" not in resolve_pipeline("all", backend="cpu")
     assert "kernels" in resolve_pipeline("all", backend="tpu")
+    assert "kernels" in resolve_pipeline("safe", backend="tpu")
+    assert "kernels" not in resolve_pipeline("safe", backend="cpu")
+    assert resolve_pipeline("none", backend="tpu") == ()
     assert resolve_pipeline(("dce", "cse")) == ("cse", "dce")
     with pytest.raises(ValueError):
         resolve_pipeline("everything")

@@ -38,6 +38,38 @@ def run_sub(code: str, cache_dir: str, **extra_env) -> dict:
 # codec + store units
 # ==========================================================================
 
+def test_namespace_keys_chip_kind_and_git_tracked_sources(tmp_path):
+    """The store namespace names the device kind (one TPU generation's
+    executables never load on another).  The source digest skips files
+    git ignores, yet counts a new module not yet added; inside a
+    gitignored copy of the tree (git lists nothing there) every .py file
+    counts, so an edit to the copy still moves its key."""
+    import re
+    import jax
+    from repro.core.persist import keys
+    kind = re.sub(r"[^A-Za-z0-9]+", "_", jax.devices()[0].device_kind)
+    assert f"-{kind}-" in keys.namespace()
+    root = tmp_path / "repo"
+    copy = root / "copy"
+    copy.mkdir(parents=True)
+    for d in (root, copy):
+        (d / "kept.py").write_text("x = 1\n")
+        (d / "new.py").write_text("y = 2\n")
+    (root / "built.py").write_text("z = 3\n")
+    (root / ".gitignore").write_text("built.py\ncopy/\n")
+    assert keys._git_listed(str(root)) is None           # not a work tree
+    assert keys.source_files(str(copy), str(copy / "kept.py")) == sorted(
+        [str(copy / "kept.py"), str(copy / "new.py")])
+    git = ["git", "-c", "user.email=t@t", "-c", "user.name=t"]
+    subprocess.run(git + ["init", "-q"], cwd=root, check=True)
+    subprocess.run(git + ["add", "kept.py"], cwd=root, check=True)
+    assert keys.source_files(str(root), str(root / "kept.py")) == sorted(
+        [str(root / "kept.py"), str(root / "new.py")])
+    assert keys._git_listed(str(copy)) == set()
+    assert keys.source_files(str(copy), str(copy / "kept.py")) == sorted(
+        [str(copy / "kept.py"), str(copy / "new.py")])
+
+
 def test_codec_roundtrip():
     vals = [None, True, 3, -1.5, "s", (1, (2, "x")), [1, [2]],
             {"a": 1, (1, 2): (3,)}, {3, 1, 2}, Aval((2, 3), "float32"),

@@ -14,6 +14,7 @@ pytest.importorskip("hypothesis",
                            "(pip install -r requirements-dev.txt)")
 from hypothesis import given, settings, strategies as st
 
+from repro.launch.mesh import make_mesh
 from repro.train import checkpoint as ckpt
 from repro.train import data as data_mod
 from repro.train import optimizer as opt
@@ -124,7 +125,7 @@ def test_param_specs_divisible_everywhere():
     from repro.models import model as M
     from repro.parallel import specs as S
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_config("llama3-8b")
     aparams = M.abstract_params(cfg)
     spec_tree = S.tree_param_specs(mesh, aparams)
@@ -147,7 +148,7 @@ def test_gpipe_pipeline_matches_sequential():
     from repro.parallel.pipeline import make_pipelined_apply
 
     n_stages = jax.device_count()
-    mesh = jax.make_mesh((n_stages,), ("stage",))
+    mesh = make_mesh((n_stages,), ("stage",))
     mb, d = 4, 16
     rng = np.random.RandomState(0)
     ws = jnp.asarray(rng.randn(n_stages, d, d).astype(np.float32) * 0.2)

@@ -24,9 +24,16 @@ def test_trainer_coexec_converges_and_resumes():
                                     total_steps=100),
                      ckpt_dir=d, batch=4, seq_len=32, log_every=5,
                      ckpt_every=10)
+        init = np.asarray(tr.p_vars[0].value())
         hist = tr.train(20, verbose=False)
         assert tr._iteration.phase == "co-execution"
         assert hist[-1][1] < hist[0][1]
+        # outside a call a Variable reads the engine's committed state, so
+        # the checkpoint holds the trained params, not the initial ones
+        final = np.asarray(jax.tree.leaves(tr.state_tree()["params"])[0])
+        np.testing.assert_array_equal(final, np.asarray(
+            tr._iteration.engine.variable_value(tr.p_vars[0])))
+        assert not np.array_equal(final, init)
         tr._iteration.close()
 
         tr2 = Trainer(cfg, OptConfig(lr=1e-3, warmup_steps=5,
@@ -34,6 +41,8 @@ def test_trainer_coexec_converges_and_resumes():
                       ckpt_dir=d, batch=4, seq_len=32, log_every=5,
                       ckpt_every=100)
         assert tr2.start_step == 20        # auto-resume (fault tolerance)
+        np.testing.assert_array_equal(
+            np.asarray(tr2.p_vars[0].value()), final)
         h2 = tr2.train(10, verbose=False)
         assert np.isfinite(h2[-1][1])
         tr2._iteration.close()
