@@ -70,12 +70,6 @@ from repro.obs import Histogram, MetricsProcessor, TraceViewerExporter
 from repro.serve.engine import Request, ServingEngine
 from repro.serve.scheduler import ContinuousBatchingScheduler
 
-# sampled device-time attribution cadence for the traced re-runs: every
-# PROFILE_EVERY-th engine iteration blocks on the segment's outputs on
-# the runner thread (DESIGN.md §15); part of the ≥0.98x tracing gate
-PROFILE_EVERY = 8
-
-
 def build_workload(cfg, seed, n, mean_gap_s, lens, max_new_lo, max_new_hi):
     """(arrival_offset, prompt, max_new) triples; Poisson arrivals."""
     rng = np.random.RandomState(seed)
@@ -166,13 +160,13 @@ def run_scheduler(sch, workload, trials=5, trace_path=None):
     """Serve the workload both counters-only (the deployment
     configuration) and with the full observability stack attached —
     structured events, request traces, JSONL export, live metrics
-    registry, Chrome/Perfetto timeline export, and sampled device-time
-    profiling (``PROFILE_EVERY``) — interleaved per round, alternating
+    registry and Chrome/Perfetto timeline export — interleaved per
+    round, alternating
     which goes first, so machine drift and any within-round warmth hit
     both configurations equally; report the best-throughput trial of
     each — the steady-state estimator.  The TimingProcessor supplies the
     host-overhead breakdown, and the best-vs-best throughput ratio is
-    the ≤2 % profiling/tracing-cost gate (DESIGN.md §15)."""
+    the ≤2 % tracing-cost gate (DESIGN.md §15)."""
     timing = TimingProcessor()
     metrics = MetricsProcessor()
     extras = [metrics]
@@ -181,7 +175,6 @@ def run_scheduler(sch, workload, trials=5, trace_path=None):
         open(trace_path, "w").close()       # truncate any stale artifact
         viewer = TraceViewerExporter(trace_path + ".trace.json")
         extras += [RequestTraceProcessor(), JsonlSink(trace_path), viewer]
-    can_profile = getattr(sch, "use_terra", False)
     best = tbest = None
     for i in range(max(1, trials)):
         for with_events in ((False, True) if i % 2 == 0 else (True, False)):
@@ -192,13 +185,9 @@ def run_scheduler(sch, workload, trials=5, trace_path=None):
                 continue
             timing.reset()                  # breakdown = winning window
             procs = [sch.events.attach(p) for p in [timing] + extras]
-            if can_profile:
-                sch.set_profile(PROFILE_EVERY)
             try:
                 traced = _one_trial(sch, workload)
             finally:
-                if can_profile:
-                    sch.set_profile(0)
                 for p in procs:
                     sch.events.detach(p)
             if tbest is None or traced[1] < tbest[1]:
@@ -226,15 +215,12 @@ def run_scheduler(sch, workload, trials=5, trace_path=None):
         (twall - ov.pop("dispatch_s") - ov.pop("fetch_wait_s")) * 1e3, 3)
     out["overhead"] = ov
     snap = metrics.registry.snapshot()
-    prof = snap["histograms"].get("segment_device_us", {"count": 0})
     out["tracing"] = {
         "tokens_per_s": traced["tokens_per_s"],
         "ratio_vs_counters_only": round(
             traced["tokens_per_s"] / out["tokens_per_s"], 4),
         "trace": trace_path,
         "perfetto": viewer.path if viewer is not None else None,
-        "profile_every": PROFILE_EVERY if can_profile else 0,
-        "device_samples": prof["count"],
         "metrics": {k: {kk: round(vv, 3) for kk, vv in h.items()}
                     for k, h in snap["histograms"].items()},
     }

@@ -83,7 +83,7 @@ class TerraFunction:
                  min_covered: int = 1, max_families: int = 8,
                  strict_feeds: bool = True, optimize=None,
                  steady_state: int = 0, steady_probe: int = 64,
-                 cache_dir: Optional[str] = None, profile: int = 0):
+                 cache_dir: Optional[str] = None):
         self.fn = fn
         self.engine = TerraEngine(lazy=lazy, seed=seed,
                                   min_covered=min_covered,
@@ -94,7 +94,6 @@ class TerraFunction:
                                   cache_scope=_cache_scope(fn))
         self.engine.steady_state = int(steady_state)
         self.engine.steady_probe = int(steady_probe)
-        self.engine.profile_every = int(profile)
         functools.update_wrapper(self, fn)
 
     def __call__(self, *args, **kwargs):
@@ -151,7 +150,7 @@ def function(fn: Callable = None, *, lazy: bool = False, seed: int = 0,
              min_covered: int = 1, max_families: int = 8,
              strict_feeds: bool = True, optimize=None,
              steady_state: int = 0, steady_probe: int = 64,
-             cache_dir: Optional[str] = None, profile: int = 0):
+             cache_dir: Optional[str] = None):
     """Decorator/factory: manage an imperative step function with Terra.
 
     ``optimize`` selects the symbolic optimization pipeline run over each
@@ -164,19 +163,11 @@ def function(fn: Callable = None, *, lazy: bool = False, seed: int = 0,
 
     ``cache_dir`` enables the persistent artifact store for warm boots
     (DESIGN.md §14); ``None`` defers to ``$TERRA_CACHE_DIR`` (unset: off).
-
-    ``profile`` (opt-in, default 0 = off) samples device-time attribution
-    every ``profile``-th iteration (DESIGN.md §15): on a sampled iteration
-    the GraphRunner thread blocks on each segment's outputs and emits a
-    ``SegmentProfile`` event splitting host dispatch time from device
-    execution time.  Requires a structured event processor to be attached;
-    non-sampled iterations stay zero-overhead.
     """
     kw = dict(lazy=lazy, seed=seed, min_covered=min_covered,
               max_families=max_families, strict_feeds=strict_feeds,
               optimize=optimize, steady_state=steady_state,
-              steady_probe=steady_probe, cache_dir=cache_dir,
-              profile=profile)
+              steady_probe=steady_probe, cache_dir=cache_dir)
     if fn is None:
         return lambda f: TerraFunction(f, **kw)
     return TerraFunction(fn, **kw)

@@ -45,18 +45,6 @@ def segment_dispatch(es, iter_id, kind, index, seq, feeds=0) -> None:
         es.emit(T.SegmentDispatch(iter_id, kind, index, seq, feeds))
 
 
-def runner_complete(es, seq, wall, stall) -> None:
-    if es.on:
-        es.emit(T.RunnerComplete(seq, wall, stall))
-
-
-def segment_profile(es, iter_id, kind, index, dispatch, device,
-                    kernels=()) -> None:
-    if es.on:
-        es.emit(T.SegmentProfile(iter_id, kind, index, dispatch, device,
-                                 tuple(kernels)))
-
-
 def fork_observed(es, key, fork, case) -> None:
     if es.on:
         es.emit(T.ForkObserved(fam_digest(key), fork, case))

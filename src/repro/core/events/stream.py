@@ -25,6 +25,12 @@ virtual clock implies (never sleep real time against a frozen clock).
 Processors may be attached/detached at any time; emission is serialized
 by a lock because the GraphRunner thread emits completion events
 concurrently with the Python thread.
+
+The process's GC pauses and compiles (``runtime.py``) are recorded by
+process-wide sources that take no lock; a stream delivers the ones that
+ended since its last emit, as ``Span`` events, at the head of its next
+emit, and reports their counts and seconds since it was made in
+:meth:`runtime_counters`.
 """
 
 from __future__ import annotations
@@ -33,7 +39,9 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from repro.core.events import types as T
 from repro.core.events.processors import CountersProcessor, Processor
+from repro.core.events.runtime import RUNTIME, span_name
 
 
 class EventStream:
@@ -47,6 +55,9 @@ class EventStream:
         self._procs: List[Processor] = []
         self.on = False                 # any structured processor attached
         self._lock = threading.Lock()
+        rt = self._rt = RUNTIME.install()
+        self._rt_base = rt.counters()
+        self._gc_seen, self._cc_seen = rt.gc.n, rt.compile.n
 
     # ------------------------------------------------------------------
     # counter tier (always on; the hot path)
@@ -73,6 +84,11 @@ class EventStream:
     # ------------------------------------------------------------------
     def attach(self, proc: Processor) -> Processor:
         with self._lock:
+            if not self._procs:
+                # GC / compile spans are delivered from attach on, not
+                # everything the process recorded while nobody listened
+                self._gc_seen = self._rt.gc.n
+                self._cc_seen = self._rt.compile.n
             self._procs.append(proc)
             self.on = True
         return proc
@@ -83,13 +99,40 @@ class EventStream:
             self.on = bool(self._procs)
 
     def emit(self, event) -> None:
-        """Deliver one event to every structured processor.  Callers guard
+        """Deliver one event to every structured processor, after the GC
+        and compile spans that ended since the last emit.  Callers guard
         with ``es.on`` so the event object exists only when someone
         listens; emitting on a stream that raced to empty is harmless."""
-        event.ts = self.clock()
         with self._lock:
+            rt = self._rt
+            if rt.gc.n != self._gc_seen or rt.compile.n != self._cc_seen:
+                self._deliver_runtime()
+            event.ts = self.clock()
             for p in self._procs:
                 p.process(event)
+
+    def _deliver_runtime(self) -> None:
+        """GC and compile spans since the last delivery, as ``Span``
+        events stamped at their end (under a virtual clock: now)."""
+        rt, real = self._rt, self.clock_is_real
+        gcs, lost = rt.gc.since(self._gc_seen)
+        self._gc_seen += len(gcs) + lost
+        ccs, lost = rt.compile.since(self._cc_seen)
+        self._cc_seen += len(ccs) + lost
+        found = [(s, d, span_name("gc", g)) for s, d, g in gcs]
+        found += [(s, d, span_name("compile", st)) for s, d, st in ccs]
+        for s, d, name in sorted(found):
+            e = T.Span(name, d)
+            e.ts = s + d if real else self.clock()
+            for p in self._procs:
+                p.process(e)
+
+    def runtime_counters(self) -> Dict:
+        """The process's GC pauses and compiles since this stream was
+        made: ``gc_pauses``, ``gc_pause_s``, ``compiles``, ``compile_s``.
+        Process-wide, so they stay out of the engine's own counter dict."""
+        now = self._rt.counters()
+        return {k: now[k] - self._rt_base[k] for k in now}
 
     # ------------------------------------------------------------------
     # the injected clock

@@ -2,8 +2,8 @@
 
 Every instrumented moment of the co-execution lifecycle is one of the
 dataclasses below (DESIGN.md §13): iteration open/close, segment dispatch
-and GraphRunner completion, walker validation outcomes, the divergence →
-rollback → replay chain (causally linked by ``iter_id``), steady-state
+and GraphRunner completion, GC and compile spans, walker validation
+outcomes, the divergence → rollback → replay chain (causally linked by ``iter_id``), steady-state
 entry/exit/probe/poison, pass-pipeline runs, and the serving request
 lifecycle (submit → admit → prefill → per-token → retire, keyed by
 ``rid``).
@@ -85,27 +85,24 @@ class SegmentDispatch(Event):
 @_event
 class RunnerComplete(Event):
     """One GraphRunner closure finished (emitted from the runner thread);
-    joins to :class:`SegmentDispatch` on ``seq``."""
+    joins to :class:`SegmentDispatch` on ``seq``.  It started at
+    ``ts - wall``."""
     seq: int
     wall: float                     # closure execution wall time
     stall: float                    # queue-empty time before it started
+    label: str = ""                 # the submitter's: "steady" | "segment"
+    #                                 | "chain" | "variable_update" |
+    #                                 "snapshot"
+    queued: float = 0.0             # submit -> start
 
 
 @_event
-class SegmentProfile(Event):
-    """Sampled device-time attribution for one dispatched segment
-    (DESIGN.md §15): on a profiling iteration the GraphRunner thread
-    blocks on the segment's outputs and stamps host dispatch time and
-    dispatch-to-device-done wall separately.  Joins to
-    :class:`SegmentDispatch` on ``(iter_id, kind, index)``; ``kernels``
-    lists the Pallas-substituted ops baked into the segment (pass
-    metadata carried through the DispatchPlan)."""
-    iter_id: int
-    kind: str                       # "segment" | "chain" | "steady"
-    index: int
-    dispatch: float                 # host time in the dispatch call
-    device: float                   # dispatch start -> outputs ready
-    kernels: Tuple[str, ...] = ()
+class Span(Event):
+    """A host span of the process runtime that ended at ``ts``: a GC pause
+    (``terra.gc.gen<N>``) or a compile stage (``terra.compile.<stage>``),
+    delivered by the stream after the fact (runtime.py)."""
+    name: str
+    dur: float
 
 
 # --------------------------------------------------------------------------

@@ -16,7 +16,6 @@ ChainDispatcher so historical import paths keep working.
 
 from __future__ import annotations
 
-import time
 from concurrent.futures import Future
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -152,15 +151,12 @@ class ChainDispatcher(Dispatcher):
 
         def run(fn=fn, var_ids=tuple(var_ids), feeds=tuple(feeds),
                 ext_plan=tuple(ext_plan), futures=futures, assigns=assigns,
-                produced=tuple(produced), start=start,
-                profile=self.parent.profile):
+                produced=tuple(produced)):
             var_vals = tuple(buffers[v] for v in var_ids)
             exts = tuple(
                 chain_env[(p[1], p[2])] if p[0] == "chain"
                 else fetch_futures[(p[1], p[2])].result() if p[0] == "fetch"
                 else iter_env[(p[1], p[2])] for p in ext_plan)
-            if profile:
-                pt0 = time.perf_counter()
             try:
                 outs = fn(var_vals, feeds, exts)
             except Exception as exc:        # noqa: BLE001
@@ -168,22 +164,13 @@ class ChainDispatcher(Dispatcher):
                     if not f.done():
                         f.set_exception(exc)
                 raise
-            if profile:
-                # sampled device-time attribution (DESIGN.md §15); the
-                # chain index is its trace-ordinal start, matching the
-                # SegmentDispatch "chain" event
-                pt1 = time.perf_counter()
-                jax.block_until_ready(outs)
-                ev.segment_profile(self.events, self.iter_id, "chain",
-                                   start, pt1 - pt0,
-                                   time.perf_counter() - pt0)
             for (ordv, v) in zip(produced, outs):
                 chain_env[ordv] = v
                 futures[ordv].set_result(v)
             for vid, ref in assigns.items():
                 buffers[vid] = chain_env[(ref.entry, ref.out_idx)]
 
-        seq = self.runner.submit(run)
+        seq = self.runner.submit(run, "chain")
         self.store.fence(var_ids, assigns, seq)
         self.stats["segments_dispatched"] += 1
         ev.segment_dispatch(self.events, self.iter_id, "chain", start, seq,

@@ -81,8 +81,6 @@ class TerraEngine(PythonRunnerOps, VariableOps):
         self.skip_files: Tuple[str, ...] = ()
         self._base_key = jax.random.PRNGKey(seed)
         self._chain_cache: Dict[Tuple, Any] = {}
-        # sampled device-time profiling cadence (DESIGN.md §15); 0 = off
-        self.profile_every = 0
 
         self._fallback = DivergenceHandler(self.runner, self.store,
                                            self.events)
@@ -138,17 +136,15 @@ class TerraEngine(PythonRunnerOps, VariableOps):
         self.dispatcher = None
         if self.mode == SKELETON:
             self.walker = Walker(self.gp)
-            pe = self.profile_every
             self.dispatcher = SegmentDispatcher(
                 self.gp, self.walker, self.trace, self.runner, self.store,
                 self.events, self.strict_feeds, self._feed_warned,
-                iter_id=self.iter_id,
-                profile=bool(pe and self.events.on
-                             and self.iter_id % pe == 0))
+                iter_id=self.iter_id)
             snap: Dict[int, Any] = {}
             self._snapshot_slot = snap
             store = self.store
-            seq = self.runner.submit(lambda: store.snapshot_into(snap))
+            seq = self.runner.submit(lambda: store.snapshot_into(snap),
+                                     "snapshot")
             # the snapshot reads every live buffer: fence it so a driver
             # rebind/release (reset_variable / release_variable) cannot
             # swap a buffer out from under the pending snapshot

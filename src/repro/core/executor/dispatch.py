@@ -32,7 +32,6 @@ import warnings
 from concurrent.futures import Future
 from typing import Any, Dict, Optional, Tuple
 
-import jax
 import numpy as np
 
 _EMPTY_I32 = np.zeros(0, np.int32)      # shared: no Case Select / Loop Cond
@@ -80,11 +79,8 @@ class SegmentDispatcher(Dispatcher):
 
     def __init__(self, gp, walker: Walker, trace: Trace, runner, store,
                  events, strict_feeds: bool = True, warn_latch=None,
-                 iter_id: int = -1, profile: bool = False):
+                 iter_id: int = -1):
         self.gp = gp
-        # sampled device-time attribution (DESIGN.md §15): decided once
-        # per iteration by the coordinator; captured by run closures
-        self.profile = profile
         self.walker = walker
         self.trace = trace
         self.runner = runner
@@ -174,16 +170,13 @@ class SegmentDispatcher(Dispatcher):
                 futures = {}
 
             def run(sp=sp, plan=plan, feeds=tuple(feeds), sels=sels,
-                    trips=trips, futures=futures, si=si,
-                    profile=self.profile):
+                    trips=trips, futures=futures):
                 don_in = tuple(store.read(v) for v in plan.don_var_ids)
                 keep_in = tuple(store.read(v) for v in plan.keep_var_ids)
                 if don_in:
                     stats["donated_bytes"] += sum(
                         int(getattr(b, "nbytes", 0)) for b in don_in)
                 carries = tuple(iter_env[k] for k in plan.carries_in)
-                if profile:
-                    pt0 = time.perf_counter()
                 try:
                     with warnings.catch_warnings():
                         warnings.filterwarnings(
@@ -196,17 +189,6 @@ class SegmentDispatcher(Dispatcher):
                         if not f.done():
                             f.set_exception(e)
                     raise
-                if profile:
-                    # sampled device-time attribution (DESIGN.md §15):
-                    # the dispatch call returns as soon as XLA enqueues;
-                    # blocking on the outputs here — on the runner thread,
-                    # off the imperative thread — exposes device time
-                    pt1 = time.perf_counter()
-                    jax.block_until_ready((var_out, fetches, carries_out))
-                    ev.segment_profile(
-                        self.events, self.iter_id, "segment", si,
-                        pt1 - pt0, time.perf_counter() - pt0,
-                        plan.kernel_ops)
                 for vid, v in zip(plan.var_writes, var_out):
                     buffers[vid] = v
                 for k, v in zip(plan.carries_out, carries_out):
@@ -216,7 +198,7 @@ class SegmentDispatcher(Dispatcher):
 
             # the fence is the submit sequence itself: even if the closure
             # raises, the runner completes the sequence, so fences release
-            seq = self.runner.submit(run)
+            seq = self.runner.submit(run, "segment")
             store.fence(plan.don_var_ids, plan.var_writes, seq)
             store.fence(plan.keep_var_ids, (), seq)
             stats["segments_dispatched"] += 1

@@ -25,6 +25,13 @@ behind the fetch futures, and blocking happens only when a future's value is
 actually converted/read on the Python side.  ``exec_time`` therefore measures
 enqueue-to-enqueue runner occupancy, and wall-clock device sync is visible
 only in ``py_stall_time`` at fetch points (see DESIGN.md §4).
+
+Each closure carries its submitter's label (``steady``, ``segment``,
+``chain``, ``variable_update``, ``snapshot``): it runs inside a
+``terra.runner.<label>`` profiler span while a profiler session collects,
+its completion event carries the label and its time in the queue, and its
+span goes into the process's record of runner closures (runtime.py), so a
+host stall can be put down to the closure that held the runner.
 """
 
 from __future__ import annotations
@@ -34,6 +41,13 @@ import time
 from collections import deque
 
 from repro.core.events import types as _T
+from repro.core.events.runtime import RUNTIME
+from repro.core.events.spans import span
+
+
+_SPAN_NAMES = {label: "terra.runner." + label for label in
+               ("steady", "segment", "chain", "variable_update",
+                "snapshot")}
 
 
 class GraphRunner:
@@ -63,10 +77,11 @@ class GraphRunner:
             self._worker.start()
 
     # ------------------------------------------------------------------
-    def submit(self, closure) -> int:
+    def submit(self, closure, label: str) -> int:
         """Enqueue; returns the closure's 1-based completion sequence."""
+        item = (closure, label, time.perf_counter())
         with self._cv:
-            self._dq.append(closure)
+            self._dq.append(item)
             self._submitted += 1
             seq = self._submitted
             self._cv.notify()
@@ -77,13 +92,15 @@ class GraphRunner:
         a stale read only under-reports, which at worst waits once more)."""
         return self._completed >= seq
 
-    def _run_one(self, closure):
+    def _run_one(self, item):
+        closure, label, t_submit = item
         t0 = time.perf_counter()
         stalled = max(0.0, t0 - self._last_done) if self._open else 0.0
         self.stall_time += stalled
         err = None
         try:
-            closure()
+            with span(_SPAN_NAMES.get(label) or "terra.runner." + label):
+                closure()
         except Exception as e:                  # noqa: BLE001 — keep alive
             err = e
         finally:
@@ -99,9 +116,11 @@ class GraphRunner:
                 self._completed += 1
                 seq = self._completed
                 self._cv.notify_all()
+            RUNTIME.runner_span(t0, t1 - t0, label)
             es = self.events
             if es is not None and es.on:
-                es.emit(_T.RunnerComplete(seq, t1 - t0, stalled))
+                es.emit(_T.RunnerComplete(seq, t1 - t0, stalled, label,
+                                          t0 - t_submit))
 
     def _run(self):
         dq, cv = self._dq, self._cv
@@ -109,10 +128,10 @@ class GraphRunner:
             with cv:
                 while not dq:
                     cv.wait()
-                closure = dq.popleft()
-            if closure is None:
+                item = dq.popleft()
+            if item is None:
                 return
-            self._run_one(closure)
+            self._run_one(item)
 
     # ------------------------------------------------------------------
     # iteration window (stall accounting) + cancellation
@@ -164,11 +183,11 @@ class GraphRunner:
         dq = self._dq
         while True:
             try:
-                closure = dq.popleft()
+                item = dq.popleft()
             except IndexError:
                 break
-            if closure is not None:
-                self._run_one(closure)
+            if item is not None:
+                self._run_one(item)
         err = self.pending_error
         if err is not None:
             self.pending_error = None

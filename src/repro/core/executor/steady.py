@@ -245,13 +245,6 @@ def _dispatch(eng, plan: SteadyPlan, leaves):
     stage = _walker_mod._STAGE_FEED or _walker_mod._feed_stager()
     feeds = tuple(stage(leaves[li]) for li in plan.feed_slots)
     futures = {k: Future() for k in dp.fetch_keys}
-    # sampled device-time attribution (DESIGN.md §15): steady iterations
-    # stay eligible — the block-on-done runs on the runner thread, so the
-    # imperative thread keeps its zero-walker dispatch cost; sampling
-    # keeps the runner's pipelining intact on the other N-1 iterations
-    pe = eng.profile_every
-    profile = bool(pe and eng.events.on and (eng.iter_id + 1) % pe == 0)
-    events, iter_id = eng.events, eng.iter_id + 1
 
     def run():
         don_in = tuple(store.read(v) for v in dp.don_var_ids)
@@ -259,8 +252,6 @@ def _dispatch(eng, plan: SteadyPlan, leaves):
         if don_in:
             stats["donated_bytes"] += sum(
                 int(getattr(b, "nbytes", 0)) for b in don_in)
-        if profile:
-            pt0 = time.perf_counter()
         try:
             with warnings.catch_warnings():
                 warnings.filterwarnings(
@@ -273,18 +264,12 @@ def _dispatch(eng, plan: SteadyPlan, leaves):
                 if not f.done():
                     f.set_exception(e)
             raise
-        if profile:
-            pt1 = time.perf_counter()
-            jax.block_until_ready((var_out, fetches))
-            ev.segment_profile(events, iter_id, "steady", 0,
-                               pt1 - pt0, time.perf_counter() - pt0,
-                               dp.kernel_ops)
         for vid, v in zip(dp.var_writes, var_out):
             buffers[vid] = v
         for k, v in zip(dp.fetch_keys, fetches):
             futures[k].set_result(v)
 
-    seq = eng.runner.submit(run)
+    seq = eng.runner.submit(run, "steady")
     store.fence(dp.don_var_ids, dp.var_writes, seq)
     store.fence(dp.keep_var_ids, (), seq)
     # advance the engine's iteration clock so tensors of the *previous*

@@ -363,8 +363,18 @@ class GraphProgram:
             carries_out = tuple(env[k] for k in sp.carries_out)
             return var_out, fetches, carries_out
 
+        seg_fn.__name__ = seg_fn.__qualname__ = self._segment_name(sp)
         # arg 0 carries exactly the donation-eligible buffers (may be empty)
         return jax.jit(seg_fn, donate_argnums=(0,)) if jit_each else seg_fn
+
+    def _segment_name(self, sp: SegProg) -> str:
+        """A segment of a single op is named after it, so that its XLA
+        module says its role on the profiler's lines
+        (``jit_serve.slot_decode``); any other is ``seg_fn``."""
+        ops = [self._node(uid).op_name
+               for uid in self.structure.uids_in(sp.items)
+               if uid not in self._dead and uid not in self._alias]
+        return ops[0] if len(ops) == 1 else "seg_fn"
 
     # ------------------------------------------------------------------
     def _resolve(self, src, sp: SegProg, ctx, uid: int, pos: int):

@@ -190,8 +190,7 @@ class MetricsProcessor(Processor):
                           T.RequestRetire: self._retire,
                           T.StepDispatch: self._step,
                           T.StepHarvest: self._harvest,
-                          T.SegmentDispatch: self._segment,
-                          T.SegmentProfile: self._profile}
+                          T.SegmentDispatch: self._segment}
 
     def process(self, event) -> None:
         h = self._handlers.get(type(event))
@@ -237,11 +236,6 @@ class MetricsProcessor(Processor):
             self._steady_segments += 1
         self.registry.set_gauge(
             "steady_occupancy", self._steady_segments / self._segments)
-
-    def _profile(self, e) -> None:
-        r = self.registry
-        r.observe("segment_dispatch_us", e.dispatch * 1e6)
-        r.observe("segment_device_us", e.device * 1e6)
 
 
 def counters_table(stats: Dict[str, Any],

@@ -4,10 +4,10 @@
 (the ``JsonlSink`` artifact the benches export), validates it against the
 schema, and prints:
 
-* the per-segment host/device time table — ``SegmentDispatch`` joined to
-  ``RunnerComplete`` on ``seq`` (host closure wall) and to the sampled
-  ``SegmentProfile`` events on ``(iter_id, kind, index)`` (dispatch vs
-  device split, per-kernel attribution),
+* the per-segment host time table — ``SegmentDispatch`` joined to the
+  labelled ``RunnerComplete`` on ``seq`` (host closure wall, time in the
+  runner's queue); device time comes from a profiler trace, where the
+  program's ``terra.*`` spans lie on the same clock,
 * the divergence → rollback → replay audit,
 * per-family fork selector distributions (``ForkObserved``),
 * the serving metrics snapshot (the same ``MetricsRegistry`` the live
@@ -35,43 +35,31 @@ def _fmt_us(s: float) -> str:
 
 
 def segment_table(events: List[Any]) -> str:
-    """Aggregate per-(kind, index) segment rows: dispatch count, mean host
-    closure wall (all iterations, via RunnerComplete), and — where sampled
-    — mean host-dispatch and device time from SegmentProfile."""
+    """Aggregate per-(kind, index) segment rows: dispatch count, and the
+    mean host closure wall and queue time on the GraphRunner thread (its
+    labelled ``RunnerComplete``, joined on ``seq``)."""
     complete = {e.seq: e for e in events if type(e) is T.RunnerComplete}
     rows: Dict[tuple, Dict[str, Any]] = {}
     for e in events:
         if type(e) is T.SegmentDispatch:
             r = rows.setdefault((e.kind, e.index),
-                                {"n": 0, "wall": 0.0, "walls": 0,
-                                 "disp": 0.0, "dev": 0.0, "prof": 0,
-                                 "kernels": ()})
+                                {"n": 0, "wall": 0.0, "queued": 0.0,
+                                 "walls": 0})
             r["n"] += 1
             c = complete.get(e.seq)
             if c is not None:
                 r["wall"] += c.wall
+                r["queued"] += c.queued
                 r["walls"] += 1
-        elif type(e) is T.SegmentProfile:
-            r = rows.setdefault((e.kind, e.index),
-                                {"n": 0, "wall": 0.0, "walls": 0,
-                                 "disp": 0.0, "dev": 0.0, "prof": 0,
-                                 "kernels": ()})
-            r["disp"] += e.dispatch
-            r["dev"] += e.device
-            r["prof"] += 1
-            if e.kernels:
-                r["kernels"] = tuple(e.kernels)
     if not rows:
         return "(no segment dispatches in trace)"
-    lines = [f"{'segment':<14}{'count':>7}{'host µs':>11}{'disp µs':>11}"
-             f"{'device µs':>11}{'sampled':>9}  kernels"]
+    lines = [f"{'segment':<14}{'count':>7}{'host µs':>11}{'queued µs':>11}"]
     for (kind, idx), r in sorted(rows.items()):
-        wall = _fmt_us(r["wall"] / r["walls"]) if r["walls"] else " " * 10
-        disp = _fmt_us(r["disp"] / r["prof"]) if r["prof"] else " " * 10
-        dev = _fmt_us(r["dev"] / r["prof"]) if r["prof"] else " " * 10
+        n = r["walls"]
+        wall = _fmt_us(r["wall"] / n) if n else " " * 10
+        queued = _fmt_us(r["queued"] / n) if n else " " * 10
         lines.append(f"{kind + '[' + str(idx) + ']':<14}{r['n']:>7}"
-                     f"{wall:>11}{disp:>11}{dev:>11}{r['prof']:>9}  "
-                     f"{','.join(r['kernels']) or '-'}")
+                     f"{wall:>11}{queued:>11}")
     return "\n".join(lines)
 
 
@@ -140,7 +128,7 @@ def main(argv=None) -> int:
     print(f"{args.trace}: {len(events)} events, "
           f"{len({type(e).__name__ for e in events})} types")
 
-    print("\n== per-segment host/device time ==")
+    print("\n== per-segment host time ==")
     print(segment_table(events))
     print("\n== divergence/replay audit ==")
     print(divergence_audit(events))

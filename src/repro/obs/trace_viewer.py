@@ -8,9 +8,10 @@ layout makes the paper's overlap claim *visible*:
 * process 1 ``terra-engine`` — one lane per runtime actor: the
   imperative Python thread (iteration spans), walker validation
   (divergence → rollback → replay instants, linked by flow arrows),
-  GraphRunner execution (per-seq closure spans, from RunnerComplete),
-  device execution (sampled SegmentProfile spans, host-dispatch split in
-  ``args``), and the serving scheduler's step loop.
+  GraphRunner execution (per-seq closure spans labelled by submitter,
+  from RunnerComplete), the process runtime (GC pauses and compile
+  stages, from Span), and the serving scheduler's step loop.  Device
+  time is the profiler trace's, where the same ``terra.*`` spans land.
 * process 2 ``requests`` — one lane per request id; the admit → retire
   span with per-token instants, and flow arrows chaining
   submit → admit → prefill → first token → retire.
@@ -30,9 +31,9 @@ from repro.core.events import types as T
 from repro.core.events.processors import Processor
 
 PID_ENGINE, PID_REQ = 1, 2
-TID_PY, TID_WALKER, TID_RUNNER, TID_DEVICE, TID_SCHED = 1, 2, 3, 4, 5
+TID_PY, TID_WALKER, TID_RUNNER, TID_RUNTIME, TID_SCHED = 1, 2, 3, 4, 5
 _TID_NAMES = {TID_PY: "python (imperative)", TID_WALKER: "walker",
-              TID_RUNNER: "graph-runner", TID_DEVICE: "device (sampled)",
+              TID_RUNNER: "graph-runner", TID_RUNTIME: "runtime (gc, compile)",
               TID_SCHED: "scheduler"}
 
 
@@ -99,15 +100,13 @@ def chrome_trace(events: List[Any]) -> Dict[str, Any]:
                           TID_PY, ts, {"seq": e.seq, "iter": e.iter_id,
                                        "feeds": e.feeds}))
         elif k is T.RunnerComplete:
-            out.append(_x(f"seq {e.seq}", PID_ENGINE, TID_RUNNER,
-                          ts - e.wall * 1e6, e.wall * 1e6,
-                          {"stall_us": round(e.stall * 1e6, 1)}))
-        elif k is T.SegmentProfile:
-            out.append(_x(f"{e.kind}[{e.index}] device", PID_ENGINE,
-                          TID_DEVICE, ts - e.device * 1e6, e.device * 1e6,
-                          {"iter": e.iter_id,
-                           "dispatch_us": round(e.dispatch * 1e6, 1),
-                           "kernels": list(e.kernels)}))
+            out.append(_x(f"{e.label or 'closure'} seq {e.seq}", PID_ENGINE,
+                          TID_RUNNER, ts - e.wall * 1e6, e.wall * 1e6,
+                          {"stall_us": round(e.stall * 1e6, 1),
+                           "queued_us": round(e.queued * 1e6, 1)}))
+        elif k is T.Span:
+            out.append(_x(e.name, PID_ENGINE, TID_RUNTIME, ts - e.dur * 1e6,
+                          e.dur * 1e6))
         elif k is T.Divergence:
             fid = f"div:{e.iter_id}"
             out.append(_i(f"divergence {e.iter_id}", PID_ENGINE, TID_WALKER,

@@ -121,6 +121,7 @@ def restore_scheduler(cls, path: str, cfg, params, *,
     if meta.get("fmt") != FORMAT:
         raise ValueError(f"unsupported scheduler checkpoint {path}")
     kw = dict(meta["ctor"])
+    kw.pop("profile", None)     # an option of older checkpoints, removed
     kw.update(overrides)
     if clock is not None:
         kw["clock"] = clock

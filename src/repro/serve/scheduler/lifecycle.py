@@ -18,6 +18,7 @@ from __future__ import annotations
 import time
 from typing import Callable, List, Optional, Tuple
 
+from repro.core.events.spans import span
 from repro.core.executor.families import bucket_pow2
 from repro.serve.scheduler.pool_ops import pads_allowed
 
@@ -142,10 +143,11 @@ class CallbackQueue:
     def flush(self) -> None:
         queued, self._queue = self._queue, []
         try:
-            while queued:
-                cb, req, tok, idx = queued.pop(0)
-                cb(req, tok, idx)
-                self.delivered += 1
+            with span("terra.sched.callback_flush"):
+                while queued:
+                    cb, req, tok, idx = queued.pop(0)
+                    cb(req, tok, idx)
+                    self.delivered += 1
         finally:
             # a raising callback loses only its own delivery: everything
             # still queued (other requests' tokens) goes back in front

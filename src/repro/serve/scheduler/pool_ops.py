@@ -40,12 +40,13 @@ Pytrees are flattened at the op boundary; a meta registry keeps the
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, List, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.ops import def_op
+from repro.core.ops import def_op, op_impl
 from repro.models import layers as L
 from repro.models import model as M
 from repro.models import transformer as T
@@ -285,6 +286,19 @@ def _slot_decode_kernel_impl(*leaves, **attrs):
         return _slot_decode_impl(*leaves, **attrs)
     finally:
         A.PAGED_KERNEL = prev
+
+
+def program(op: str):
+    """The op's implementation under the op's name, for a jitted step
+    program whose XLA module then says its role
+    (``jit_serve.slot_prefill``) on the profiler's lines."""
+    impl = op_impl(op)
+
+    @functools.wraps(impl)
+    def step(*leaves, **attrs):
+        return impl(*leaves, **attrs)
+    step.__name__ = step.__qualname__ = op
+    return step
 
 
 slot_prefill = def_op("serve.slot_prefill", _slot_prefill_impl)

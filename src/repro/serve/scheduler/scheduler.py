@@ -24,8 +24,8 @@ import numpy as np
 
 from repro.core import function as terra_function
 from repro.core import ops as ops_mod
+from repro.core.events.spans import span
 from repro.core.executor import SKELETON, varops
-from repro.core.ops import op_impl
 from repro.core.tensor import TerraTensor, Variable
 from repro.serve.scheduler import pool_ops
 from repro.serve.scheduler import telemetry as tm
@@ -50,7 +50,6 @@ class ContinuousBatchingScheduler:
                  page_size: Optional[int] = None,
                  num_blocks: Optional[int] = None,
                  steady_state: int = 8, steady_probe: int = 128,
-                 profile: int = 0,
                  clock: Callable[[], float] = time.perf_counter):
         pool_ops.check_supported(cfg)
         self.cfg = cfg
@@ -92,19 +91,18 @@ class ContinuousBatchingScheduler:
             self._tokf_var = Variable(tokf0, name="sched.tokf")
             self._tf = terra_function(self._step, optimize=optimize,
                                       steady_state=steady_state,
-                                      steady_probe=steady_probe,
-                                      profile=profile)
-            self._prefill_jit = jax.jit(op_impl("serve.slot_prefill"),
+                                      steady_probe=steady_probe)
+            self._prefill_jit = jax.jit(pool_ops.program("serve.slot_prefill"),
                                         static_argnames=_STATIC)
         else:
             self._cache_leaves = list(leaves0)
             self._pos, self._tokf = pos0, tokf0
             # donate pool state (cache + pos + tokf) for in-place reuse
             donate = tuple(range(self._np, self._np + self._nc + 2))
-            self._decode_jit = jax.jit(op_impl("serve.slot_decode"),
+            self._decode_jit = jax.jit(pool_ops.program("serve.slot_decode"),
                                        static_argnames=_STATIC,
                                        donate_argnums=donate)
-            self._prefill_jit = jax.jit(op_impl("serve.slot_prefill"),
+            self._prefill_jit = jax.jit(pool_ops.program("serve.slot_prefill"),
                                         static_argnames=_STATIC,
                                         donate_argnums=donate)
 
@@ -124,7 +122,7 @@ class ContinuousBatchingScheduler:
             max_slots=max_slots, max_len=max_len, temperature=temperature,
             use_terra=use_terra, optimize=optimize,
             prefill_batch_cap=prefill_batch_cap, bucket_floor=bucket_floor,
-            page_size=ps or None, num_blocks=nb or None, profile=profile,
+            page_size=ps or None, num_blocks=nb or None,
             steady_state=steady_state, steady_probe=steady_probe)
 
     # ------------------------------------------------------------------
@@ -157,15 +155,19 @@ class ContinuousBatchingScheduler:
 
     def run(self, max_steps: Optional[int] = None) -> None:
         """Serve until drained, one step deep: dispatch the next step,
-        *then* harvest the previous step's token frame."""
+        *then* harvest the previous step's token frame, each phase in a
+        ``terra.sched.*`` profiler span (DESIGN.md §13)."""
         steps = 0
         while (len(self.queue) or self.pool.active_count
                or self._pending is not None):
-            plan = self.planner.next_plan(self.clock())
+            with span("terra.sched.plan"):
+                plan = self.planner.next_plan(self.clock())
             if isinstance(plan, PrefillPlan):
-                nxt = self._dispatch_prefill(plan)
+                with span("terra.sched.prefill_splice"):
+                    nxt = self._dispatch_prefill(plan)
             elif isinstance(plan, DecodePlan):
-                nxt = self._dispatch_decode(plan)
+                with span("terra.sched.decode_dispatch"):
+                    nxt = self._dispatch_decode(plan)
             else:
                 nxt = None
             prev, self._pending = self._pending, nxt
@@ -173,7 +175,8 @@ class ContinuousBatchingScheduler:
                 self._harvest(prev)
                 self.callbacks.flush()
             elif nxt is None:
-                self._idle(plan)
+                with span("terra.sched.idle"):
+                    self._idle(plan)
             steps += 1
             if max_steps is not None and steps >= max_steps:
                 break
@@ -187,10 +190,6 @@ class ContinuousBatchingScheduler:
     @property
     def stats(self) -> dict:
         return tm.merged_stats(self)
-
-    def set_profile(self, every: int) -> None:
-        """Runtime-mutable sampled profiling cadence (DESIGN.md §15)."""
-        tm.set_profile(self, every)
 
     def enable_metrics(self, registry=None):
         """Attach a live metrics processor; returns its registry (§15)."""
@@ -321,8 +320,9 @@ class ContinuousBatchingScheduler:
     def _harvest(self, entry) -> None:
         kind, payload, extra = entry
         t0 = time.perf_counter()
-        toks = np.asarray(payload.result()) if isinstance(payload, Future) \
-            else np.asarray(payload)
+        with span("terra.sched.harvest_wait"):
+            toks = (np.asarray(payload.result())
+                    if isinstance(payload, Future) else np.asarray(payload))
         tm.harvest_done(self, kind, t0)
         now = self.clock()
         if kind == "decode":

@@ -47,10 +47,12 @@ def make_stream(engine_events: Optional[EventStream],
 def merged_stats(sch) -> dict:
     """The scheduler's flat ``stats`` view: the shared counter dict (which
     already holds the engine counters under co-execution), the callback /
-    pool gauges, and the engine phase."""
+    pool gauges, the process's GC pauses and compiles since the stream
+    was made, and the engine phase."""
     out = dict(sch.sched_stats)
     out["callbacks_delivered"] = sch.callbacks.delivered
     out["peak_resident_tokens"] = sch.pool.peak_resident_tokens
+    out.update(sch.events.runtime_counters())
     if sch.use_terra:
         out.update(sch._tf.stats)
         out["phase"] = sch._tf.phase
@@ -124,14 +126,6 @@ def harvest_done(sch, kind: str, t0: float) -> None:
 # --------------------------------------------------------------------------
 # observability surface (repro.obs, DESIGN.md §15)
 # --------------------------------------------------------------------------
-
-def set_profile(sch, every: int) -> None:
-    """(Re)set the sampled device-time profiling cadence — mutable at
-    runtime so a serving process can turn attribution on for a window
-    and back off without restarting."""
-    if sch.use_terra:
-        sch._tf.engine.profile_every = int(every)
-
 
 def enable_metrics(sch, registry=None):
     """Attach a live :class:`repro.obs.MetricsProcessor` to the

@@ -2,10 +2,16 @@
 bit-compatibility with and without structured processors, causal
 completeness of divergence → rollback → replay chains, per-request
 serving traces (mid-decode admission and early-EOS retirement) with
-monotone timestamps, steady-state lifecycle events, and the strict
-JSONL schema roundtrip."""
+monotone timestamps, steady-state lifecycle events, the strict JSONL
+schema roundtrip, and the span tier: nothing built at a span site while
+nobody listens, GC and compile spans from the process-wide sources,
+labelled runner closures, and no deadlock when a processor collects
+garbage on the runner thread."""
 
+import contextlib
+import gc
 import json
+import threading
 
 import numpy as np
 import pytest
@@ -15,9 +21,10 @@ import jax
 from repro.configs import smoke_config
 from repro.core import Variable, function, ops
 from repro.core.events import (EventStream, JsonlSink, ListProcessor,
-                               RequestTraceProcessor, dict_to_event,
-                               event_to_dict, load_jsonl, types,
-                               validate_jsonl)
+                               Processor, RequestTraceProcessor,
+                               dict_to_event, event_to_dict, load_jsonl,
+                               types, validate_jsonl)
+from repro.core.events.runtime import RUNTIME
 from repro.models import model as M
 from repro.serve.engine import Request
 from repro.serve.scheduler import ContinuousBatchingScheduler
@@ -29,6 +36,18 @@ MAX_LEN = 64
 def llama():
     cfg = smoke_config("llama3-8b")
     return cfg, M.init_params(cfg, jax.random.PRNGKey(0))
+
+
+@contextlib.contextmanager
+def no_gc():
+    """No automatic collection, so no GC span joins a stream's events."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
 
 
 def make_requests(cfg, lens, max_news, seed=1, **kw):
@@ -82,7 +101,8 @@ def test_no_events_constructed_when_off():
     es.inc("n")
     lp = es.attach(ListProcessor())
     assert es.on is True
-    es.emit(types.Transition(0))
+    with no_gc():
+        es.emit(types.Transition(0))
     es.detach(lp)
     assert es.on is False and len(lp.events) == 1
     assert es.counters["n"] == 1
@@ -250,9 +270,10 @@ def test_jsonl_sink_and_validation(tmp_path):
     path = str(tmp_path / "trace.jsonl")
     es = EventStream()
     sink = es.attach(JsonlSink(path))
-    es.emit(types.IterationStart(0, "tracing", "00000000"))
-    es.emit(types.RequestSubmit(1, 8, 4))
-    es.emit(types.RequestRetire(1, "eos", 3))
+    with no_gc():
+        es.emit(types.IterationStart(0, "tracing", "00000000"))
+        es.emit(types.RequestSubmit(1, 8, 4))
+        es.emit(types.RequestRetire(1, "eos", 3))
     es.close()                          # close flushes the sink
     events = load_jsonl(path)
     assert [type(e).__name__ for e in events] == \
@@ -265,3 +286,124 @@ def test_jsonl_sink_and_validation(tmp_path):
     bad.write_text('{"type": "NoSuchEvent"}\n')
     with pytest.raises(ValueError, match="bad.jsonl:1"):
         validate_jsonl(str(bad))
+
+
+# ==========================================================================
+# the span tier (DESIGN.md §13)
+# ==========================================================================
+
+def test_span_sites_build_nothing_when_off(llama, monkeypatch):
+    """No processor and no profiler session: no span site builds an event
+    object or a TraceAnnotation — through warm-up, co-execution, steady
+    state, prefill splices, harvests, idle waits and a GC pause."""
+    cfg, params = llama
+    built = []
+
+    def refuse(name):
+        def ctor(*a, **k):
+            built.append(name)
+            raise AssertionError(f"{name} built with nobody listening")
+        return ctor
+    for name in types.EVENT_TYPES:
+        monkeypatch.setattr(types, name, refuse(name))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        refuse("TraceAnnotation"))
+    sch = ContinuousBatchingScheduler(cfg, params, max_slots=2,
+                                      max_len=MAX_LEN, steady_state=2)
+    reqs = make_requests(cfg, [5, 8, 6], [6, 4, 5])
+    reqs[0].stream = lambda *a: gc.collect()
+    sch.serve(reqs)
+    st = sch.stats
+    sch.close()
+    assert not built
+    assert st["steady_iters"] > 0 and st["prefill_steps"] > 0
+    assert st["gc_pauses"] >= 6
+
+
+def test_gc_collect_gives_one_pause_span_and_counters():
+    es = EventStream()
+    lp = es.attach(ListProcessor())
+    with no_gc():
+        c0 = es.runtime_counters()
+        gc.collect()
+        es.emit(types.Transition(0))
+        c1 = es.runtime_counters()
+    spans = lp.of_type(types.Span)
+    assert [s.name for s in spans] == ["terra.gc.gen2"]
+    assert spans[0].dur > 0.0 and spans[0].ts <= lp.events[-1].ts
+    assert c1["gc_pauses"] == c0["gc_pauses"] + 1
+    assert c1["gc_pause_s"] > c0["gc_pause_s"]
+    assert sum(cb == RUNTIME._on_gc for cb in gc.callbacks) == 1
+
+
+def test_fresh_jit_gives_compile_spans_and_counts():
+    es = EventStream()
+    lp = es.attach(ListProcessor())
+    c0 = es.runtime_counters()
+    f = jax.jit(lambda x: x * 3.0 - 1.0)
+    np.asarray(f(np.arange(7, dtype=np.float32)))
+    es.emit(types.Transition(0))
+    c1 = es.runtime_counters()
+    names = {s.name for s in lp.of_type(types.Span)}
+    assert {"terra.compile.trace", "terra.compile.lower",
+            "terra.compile.backend"} <= names
+    assert c1["compiles"] >= c0["compiles"] + 1
+    assert c1["compile_s"] > c0["compile_s"]
+
+
+def _steady_step():
+    v = Variable(np.zeros(4, np.float32))
+
+    @function(optimize="safe", steady_state=2, steady_probe=4)
+    def step(x):
+        y = ops.mul(x, 2.0)
+        v.assign(ops.add(v.read(), y))
+        return y
+    return step
+
+
+def test_runner_closures_carry_their_label():
+    step = _steady_step()
+    lp = step.engine.events.attach(ListProcessor())
+    n0 = RUNTIME.runner.n
+    for i in range(12):
+        np.asarray(step(np.full(4, float(i + 1), np.float32)))
+    step.wait()
+    done = lp.of_type(types.RunnerComplete)
+    assert {"steady", "segment", "snapshot"} <= {e.label for e in done}
+    assert all(e.queued >= 0.0 and e.wall >= 0.0 for e in done)
+    # every closure also lands in the process's record, with its label
+    held, _ = RUNTIME.runner.since(n0)
+    assert len(held) >= len(done)
+    assert {"steady", "segment", "snapshot"} <= {lab for _, _, lab in held}
+    step.close()
+
+
+def test_processor_collecting_garbage_on_the_runner_thread():
+    """A GC on the runner thread inside ``emit`` (the stream's lock held)
+    must not deadlock: the GC source takes no lock and calls no
+    processor; its span arrives with a later event."""
+    class Collecting(Processor):
+        def __init__(self):
+            self.seen = []
+
+        def process(self, event):
+            self.seen.append(event)
+            if isinstance(event, types.RunnerComplete):
+                gc.collect()
+
+    step = _steady_step()
+    proc = step.engine.events.attach(Collecting())
+
+    def drive():
+        for i in range(10):
+            np.asarray(step(np.full(4, float(i + 1), np.float32)))
+        step.wait()
+
+    t = threading.Thread(target=drive, daemon=True)
+    t.start()
+    t.join(timeout=120)
+    assert not t.is_alive(), "deadlock: runner-thread GC inside emit"
+    step.close()
+    assert any(isinstance(e, types.Span) and e.name == "terra.gc.gen2"
+               for e in proc.seen)

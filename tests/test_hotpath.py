@@ -43,7 +43,7 @@ def test_early_fetch_does_not_block_on_trailing_segments():
     watchdog = threading.Timer(20.0, gate.set)
     watchdog.start()
     try:
-        hook[0] = lambda: eng.runner.submit(gate.wait)
+        hook[0] = lambda: eng.runner.submit(gate.wait, "test")
         x = np.full(8, 7.0, np.float32)
         s = step(x)
         assert s == pytest.approx(8 * 14.0)
@@ -295,7 +295,7 @@ def test_runner_survives_closure_exception():
     def boom():
         raise RuntimeError("boom")
 
-    seq = eng.runner.submit(boom)
+    seq = eng.runner.submit(boom, "test")
     eng.runner.wait_for(seq)                   # fence releases despite raise
     with pytest.raises(RuntimeError, match="boom"):
         step.wait()                            # sync surfaces the error once

@@ -1,11 +1,13 @@
 """Observability-layer tests (repro.obs, DESIGN.md §15): timeline trace
 validity (JSON, per-track monotone timestamps, request flow completeness)
-for a mid-decode-admission serving run under sampled device profiling,
+for a mid-decode-admission serving run under a profiler session,
 streaming-histogram accuracy against exact rank statistics, Prometheus
-exposition wellformedness, profiling-mode token equality + steady entry,
-fork-observation distributions, and the perf-regression guard's
-injected-regression failure mode."""
+exposition wellformedness, token equality + steady entry with the
+program's spans on, fork-observation distributions, and the
+perf-regression guard's injected-regression failure mode."""
 
+import gc
+import glob
 import json
 import math
 import os
@@ -45,10 +47,23 @@ def make_requests(cfg, lens, max_news, seed=1):
             for L, mn in zip(lens, max_news)]
 
 
+def profiler_span_names(trace_dir) -> set:
+    """Names of the program's ``terra.*`` spans in a profiler trace."""
+    from jax.profiler import ProfileData
+    names = set()
+    for path in glob.glob(os.path.join(trace_dir, "plugins", "profile",
+                                       "*", "*.xplane.pb")):
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                names.update(e.name for e in line.events
+                             if e.name.startswith("terra."))
+    return names
+
+
 @pytest.fixture(scope="module")
-def served(llama):
-    """One mid-decode-admission serving run with sampled profiling,
-    metrics, and the trace buffer attached — shared by the timeline,
+def served(llama, tmp_path_factory):
+    """One mid-decode-admission serving run under a profiler session,
+    with metrics and the trace buffer attached — shared by the timeline,
     metrics, and equality tests below."""
     cfg, params = llama
     lens = [5, 8, 13, 8, 5, 16]
@@ -60,17 +75,24 @@ def served(llama):
     eng.terra.close()
 
     sch = ContinuousBatchingScheduler(cfg, params, max_slots=3,
-                                      max_len=MAX_LEN, steady_state=4,
-                                      profile=3)
+                                      max_len=MAX_LEN, steady_state=4)
     registry = sch.enable_metrics()
     lp = ListProcessor()
     sch.events.attach(lp)
     got = make_requests(cfg, lens, mns)
-    sch.serve(got)
+    trace_dir = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        sch.serve(got)
+        gc.collect()
+    finally:
+        jax.profiler.stop_trace()
     stats = sch.stats
     sch.close()
     return dict(ref=ref, got=got, events=lp.events, registry=registry,
-                stats=stats)
+                stats=stats, span_names=profiler_span_names(trace_dir))
 
 
 # ==========================================================================
@@ -78,21 +100,29 @@ def served(llama):
 # ==========================================================================
 
 def test_profiling_preserves_token_equality_and_steady_entry(served):
-    """profile=3 blocks on device outputs on the GraphRunner thread only:
-    every request still matches its solo lock-step decode, and the engine
-    still reaches zero-walker steady state."""
+    """A profiler session and a structured processor both on: every
+    request still matches its solo lock-step decode, the engine still
+    reaches zero-walker steady state with no replay, and the program's
+    spans are there — on the profiler's host line (scheduler phases,
+    labelled runner closures, a GC pause) and in the event stream."""
     for i, (a, b) in enumerate(zip(served["ref"], served["got"])):
         assert a.out_tokens == b.out_tokens, f"request {i}"
     st = served["stats"]
     assert st["phase"] == "co-execution"
     assert st["retraces"] == 0 and st["replays"] == 0
     assert st["steady_iters"] > 0                  # steady entry happened
-    profs = [e for e in served["events"] if isinstance(e, T.SegmentProfile)]
-    assert profs, "profile=3 emitted no SegmentProfile events"
-    for e in profs:
-        assert e.kind in ("segment", "chain", "steady")
-        assert 0.0 < e.dispatch <= e.device        # host slice of the wall
-    assert any(e.kind == "steady" for e in profs)  # sampling survives steady
+    names = served["span_names"]
+    for name in ("terra.sched.plan", "terra.sched.decode_dispatch",
+                 "terra.sched.prefill_splice", "terra.sched.harvest_wait",
+                 "terra.sched.callback_flush", "terra.runner.steady",
+                 "terra.runner.segment", "terra.runner.variable_update",
+                 "terra.runner.snapshot", "terra.gc.gen2"):
+        assert name in names, name
+    done = [e for e in served["events"] if isinstance(e, T.RunnerComplete)]
+    assert {"steady", "segment", "variable_update", "snapshot"} <= {
+        e.label for e in done}
+    assert all(e.queued >= 0.0 for e in done)
+    assert st["gc_pauses"] >= 1 and st["gc_pause_s"] > 0.0
 
 
 def test_dense_pool_counts_resident_tokens(served):
@@ -233,7 +263,7 @@ def test_metrics_processor_replay_and_counters_table(served):
         mp.process(e)
     live = served["registry"]
     for name in ("ttft_ms", "token_latency_ms", "dispatch_us",
-                 "segment_device_us"):
+                 "fetch_us"):
         assert mp.registry.histograms[name].count == \
             live.histograms[name].count, name
     table = counters_table({"b_num": 3, "a_str": "x", "c_f": 1.5})

@@ -290,3 +290,36 @@ def test_run_batch_streaming_callbacks(llama):
     for r in reqs:
         mine = [(t, i) for rid, t, i in got if rid == id(r)]
         assert mine == list(zip(r.out_tokens, range(len(r.out_tokens))))
+
+
+# ==========================================================================
+# stable names of the step programs (the profiler's module lines)
+# ==========================================================================
+
+def test_step_programs_are_named_after_their_role(llama):
+    """The co-executed decode segment and the prefill splice lower to XLA
+    modules named for what they do."""
+    cfg, params = llama
+    sch = ContinuousBatchingScheduler(cfg, params, max_slots=2,
+                                      max_len=MAX_LEN, steady_state=0)
+    sch.serve(make_requests(cfg, [5, 7], [4, 3]))
+    eng = sch._tf.engine
+    assert sch.stats["phase"] == "co-execution"
+    (sp,) = eng.gp.seg_progs
+    plan = sp.plan
+    decode = sp.fn.lower(
+        tuple(eng.store.read(v) for v in plan.don_var_ids),
+        tuple(eng.store.read(v) for v in plan.keep_var_ids),
+        tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
+              for _, _, a in plan.feed_keys),
+        np.zeros(len(plan.sel_uids), np.int32),
+        np.zeros(len(plan.trip_uids), np.int32), ()).as_text()
+    state = [eng.variable_value(v)
+             for v in sch._cache_vars + [sch._pos_var, sch._tokf_var]]
+    prefill = sch._prefill_jit.lower(
+        *sch._params_leaves, *state, np.zeros((1, 8), np.int32),
+        np.zeros(1, np.int32), np.full(1, 5, np.int32),
+        **sch._attrs).as_text()
+    sch.close()
+    assert "module @jit_serve.slot_decode" in decode
+    assert "module @jit_serve.slot_prefill" in prefill

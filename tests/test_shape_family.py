@@ -244,7 +244,7 @@ def test_graphrunner_cancel_is_public_and_clears_error():
     def boom():
         raise RuntimeError("boom")
 
-    eng.runner.submit(boom)
+    eng.runner.submit(boom, "test")
     eng.runner.cancel()                        # drains + clears the stash
     step.wait()                                # must NOT re-raise "boom"
     out = step(np.full(4, 3.0, np.float32))    # runner still alive
@@ -270,7 +270,7 @@ def test_lazy_mode_closure_error_surfaces_at_fetch():
     def boom():
         raise RuntimeError("lazy boom")
 
-    eng.runner.submit(boom)
+    eng.runner.submit(boom, "test")
     with pytest.raises(RuntimeError, match="lazy boom"):
         eng.variable_value(w)                  # fence wait drains -> raises
     # error consumed; the engine keeps working afterwards
