@@ -11,7 +11,6 @@ shapes at production batch sizes.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -117,8 +116,10 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
 
 
 def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0):
-    """Single-token decode: q [B,1,Hq,D]; caches [B,Smax,Hkv,D];
-    cache_len: [B] or scalar valid length."""
+    """Single-token decode over an already written cache: q [B,1,Hq,D];
+    caches [B,Smax,Hkv,D]; cache_len: [B] or scalar valid length.  The
+    serving path appends instead (:func:`decode_attention_append`); this
+    write-then-attend form is its reference in the tests."""
     B, _, Hq, D = q.shape
     _, Smax, Hkv, _ = k_cache.shape
     G = Hq // Hkv
@@ -136,6 +137,43 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0):
     return out.reshape(B, 1, Hq, D).astype(q.dtype)
 
 
+def decode_attention_append(q, k_cache, v_cache, k_new, v_new, cache_len, *,
+                            window: int = 0):
+    """Single-token decode against the cache as it was *before* the step,
+    plus the new token's own K/V as a separate term — the same function as
+    writing the row at ``cache_len`` and calling :func:`decode_attention`.
+
+    q [B,1,Hq,D]; caches [B,Smax,Hkv,D]; k_new/v_new [B,Hkv,D]; cache_len:
+    [B] or scalar, the new token's position.  A position at or beyond
+    ``Smax`` stands for the clamped write at ``Smax - 1``: that stored
+    position is left out, as the write would have replaced it.
+    """
+    B, _, Hq, D = q.shape
+    _, Smax, Hkv, _ = k_cache.shape
+    G = Hq // Hkv
+    qr = q.reshape(B, Hkv, G, D) * (D ** -0.5)
+    s = jnp.einsum("bhgd,bshd->bhgs", qr, k_cache,
+                   preferred_element_type=jnp.float32)
+    s_new = jnp.einsum("bhgd,bhd->bhg", qr, k_new,
+                       preferred_element_type=jnp.float32)
+    pos = jnp.arange(Smax)
+    idx = jnp.reshape(cache_len, (-1, 1))
+    valid = pos[None, :] < jnp.minimum(idx, Smax - 1)
+    if window:
+        valid &= pos[None, :] >= idx + 1 - window
+    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+    m = jnp.maximum(s.max(-1), s_new)
+    p = jnp.exp(s - m[..., None])
+    p_new = jnp.exp(s_new - m)
+    denom = p.sum(-1) + p_new
+    out = jnp.einsum("bhgs,bshd->bhgd",
+                     (p / denom[..., None]).astype(v_cache.dtype), v_cache,
+                     preferred_element_type=jnp.float32)
+    w_new = (p_new / denom).astype(v_new.dtype).astype(jnp.float32)
+    out = out + w_new[..., None] * v_new.astype(jnp.float32)[:, :, None]
+    return out.reshape(B, 1, Hq, D).astype(q.dtype)
+
+
 # --------------------------------------------------------------------------
 # Full attention block (projections + rope + cache handling)
 # --------------------------------------------------------------------------
@@ -146,9 +184,14 @@ def attention_block(p, x, cfg, *, positions=None, cache=None,
     """Returns (out, new_cache).
 
     cache: None (training/prefill-no-cache) or dict with k/v [B,Smax,Hkv,D]
-    and ``len`` (filled length).  When ``cross_states`` is given, k/v come
-    from the encoder/vision states and no cache/causal masking applies
-    (cross-attention caches are precomputed at prefill in serve mode).
+    and ``len`` (filled length).  Single-token decode against a dense
+    cache *appends*: it reads the cache as it was, attends over it plus
+    the new token's own K/V, and returns only the new rows
+    ``{"k_row", "v_row"}`` [B,Hkv,D] — the caller writes them
+    (``transformer.append_rows``, DESIGN.md §11).  When ``cross_states``
+    is given, k/v come from the encoder/vision states and no cache/causal
+    masking applies (cross-attention caches are precomputed at prefill in
+    serve mode).
     """
     B, S, _ = x.shape
     H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -194,42 +237,44 @@ def attention_block(p, x, cfg, *, positions=None, cache=None,
             from repro.kernels import ops as kops
             out = kops.paged_attention(q, kp, vp, bt, idx + 1, window=window)
         else:
+            # the same formula as dense decode: the arena's row is masked
+            # out at the new position and the new K/V enters as its own term
             Bq, nbps = bt.shape
             kg = kp[bt].reshape(Bq, nbps * bs, Hkv, D)
             vg = vp[bt].reshape(Bq, nbps * bs, Hkv, D)
-            out = decode_attention(q, kg, vg, idx + 1, window=window)
-    elif cache is not None and cross_states is None:
-        # decode/step mode: append to cache then attend over it.  ``len``
-        # is a scalar (lock-step serving: every row at the same fill) or a
-        # [B] vector (slot-pooled serving: per-slot positions) — the vector
-        # case writes each row at its own offset via a vmapped update.
+            out = decode_attention_append(q, kg, vg, kv, vv, idx,
+                                          window=window)
+    elif cache is not None and cross_states is None and S == 1:
+        # single-token decode appends: attend over the cache as it was plus
+        # the new K/V, and hand the new rows back for the caller to write
+        # after the layer loop.  ``len`` is a scalar (lock-step serving:
+        # every row at the same fill) or a [B] vector (slot-pooled serving:
+        # per-slot positions).
         idx = cache["len"]
-        kv, vv = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
+        kv = k.astype(cache["k"].dtype)[:, 0]       # [B, Hkv, D]
+        vv = v.astype(cache["v"].dtype)[:, 0]
+        out = decode_attention_append(q, cache["k"], cache["v"], kv, vv, idx,
+                                      window=window)
+        new_cache = {"k_row": kv, "v_row": vv, "len": idx + 1}
+    elif cache is not None and cross_states is None:
+        # multi-token step (prefill into a cache): write, then attend
+        idx = cache["len"]
         if jnp.ndim(idx):
-            if S != 1:
-                raise NotImplementedError(
-                    "per-row cache positions support single-token decode "
-                    "only (got S=%d)" % S)
-            upd = jax.vmap(functools.partial(
-                jax.lax.dynamic_update_slice_in_dim, axis=0))
-            k_cache = upd(cache["k"], kv, idx)
-            v_cache = upd(cache["v"], vv, idx)
-        else:
-            k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], kv,
-                                                          idx, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], vv,
-                                                          idx, axis=1)
+            raise NotImplementedError(
+                "per-row cache positions support single-token decode "
+                "only (got S=%d)" % S)
+        kv, vv = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
+        k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], kv,
+                                                      idx, axis=1)
+        v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], vv,
+                                                      idx, axis=1)
         new_cache = {"k": k_cache, "v": v_cache, "len": idx + S}
-        if S == 1:
-            out = decode_attention(q, k_cache, v_cache, idx + 1,
-                                   window=window)
-        else:
-            out = chunked_attention(q, k_cache, v_cache, causal=causal,
-                                    window=window, q_offset=idx,
-                                    kv_valid_len=idx + S,
-                                    q_block=cfg.q_block,
-                                    kv_block=cfg.kv_block,
-                                    unroll=cfg.unroll)
+        out = chunked_attention(q, k_cache, v_cache, causal=causal,
+                                window=window, q_offset=idx,
+                                kv_valid_len=idx + S,
+                                q_block=cfg.q_block,
+                                kv_block=cfg.kv_block,
+                                unroll=cfg.unroll)
     else:
         out = chunked_attention(q, k, v,
                                 causal=causal and cross_states is None,

@@ -289,8 +289,58 @@ def _superblock(cfg, slot_params, x, *, positions, caches=None,
     return x, new_caches
 
 
-def run_stack(cfg, params, x, *, positions, caches=None, cross_states=None):
-    """Scan over super-blocks (+ unrolled extra blocks)."""
+APPEND_TILE = 128     # positions per read-modify-write of an appended row
+
+
+def append_rows(cache, rows, pos):
+    """Write each row's new K or V into a dense cache leaf at its position.
+
+    cache [..., B, Smax, Hkv, D] (a leading layer axis when stacked), rows
+    [..., B, Hkv, D], pos [B] or scalar.  Each row is a read-modify-write
+    of the aligned tile of ``min(128, Smax)`` positions that holds it, in a
+    loop over the rows: on a TPU that keeps the leaf in its stored,
+    position-minor layout, where a scatter or a one-position update makes
+    XLA re-lay out the whole leaf.  A position at or beyond ``Smax`` lands
+    at ``Smax - 1``, as ``dynamic_update_slice`` clamps.
+    """
+    lead = cache.ndim - 4
+    B, Smax = cache.shape[lead], cache.shape[lead + 1]
+    blk = min(APPEND_TILE, Smax)
+    p = jnp.minimum(jnp.broadcast_to(pos, (B,)), Smax - 1).astype(jnp.int32)
+    s0 = jnp.minimum(p // blk * blk, Smax - blk)
+    tile_shape = cache.shape[:lead] + (1, blk) + cache.shape[lead + 2:]
+    lane = jnp.arange(blk).reshape(blk, 1, 1)
+
+    def write(b, c):
+        start = (0,) * lead + (b, s0[b], 0, 0)
+        tile = jax.lax.dynamic_slice(c, start, tile_shape)
+        row = jax.lax.dynamic_slice_in_dim(rows, b, 1, axis=lead)
+        row = jnp.expand_dims(row, lead + 1).astype(c.dtype)
+        tile = jnp.where(lane == p[b] - s0[b], row, tile)
+        return jax.lax.dynamic_update_slice(c, tile, start)
+
+    return jax.lax.fori_loop(0, B, write, cache)
+
+
+def _settle(cache, out, pos):
+    """A block's cache after the step: the rows a single-token decode
+    appended, written into ``cache``; else the cache the block returned."""
+    if out is None or "k_row" not in out:
+        return out
+    return {"k": append_rows(cache["k"], out["k_row"], pos),
+            "v": append_rows(cache["v"], out["v_row"], pos)}
+
+
+def run_stack(cfg, params, x, *, positions, caches=None, cross_states=None,
+              record=None):
+    """Scan over super-blocks (+ unrolled extra blocks).
+
+    Single-token decode against dense attention caches reads each layer's
+    cache as scan input only; the scan emits the new K/V rows, written
+    after the layer loop (``append_rows``, DESIGN.md §11).  ``record``, if
+    given, receives at trace time ``kv_append_leaves``: the number of cache
+    leaves this step writes by appending (0 when the path did not engage).
+    """
     x = logical(x, "batch", None, None)
     cache_len = caches["len"] if caches is not None else None
     cache_bt = caches.get("bt") if caches is not None else None
@@ -340,10 +390,18 @@ def run_stack(cfg, params, x, *, positions, caches=None, cross_states=None):
                               cross_states=cross_states)
         new_extra.append(nc)
 
-    new_caches = None
-    if caches is not None:
-        new_caches = {"layers": new_layer_caches, "extra": new_extra,
-                      "len": cache_len + x.shape[1]}
+    if caches is None:
+        return x, None
+    outs = list(new_layer_caches) + new_extra
+    if record is not None:
+        record["kv_append_leaves"] = 2 * sum(
+            1 for o in outs if o is not None and "k_row" in o)
+    new_caches = {
+        "layers": [_settle(c, o, cache_len)
+                   for c, o in zip(scanned_caches, new_layer_caches)],
+        "extra": [_settle(c, o, cache_len)
+                  for c, o in zip(caches["extra"], new_extra)],
+        "len": cache_len + x.shape[1]}
     return x, new_caches
 
 

@@ -14,8 +14,10 @@ Variables:
   counters to the prompt lengths.
 * ``serve.slot_decode`` — one masked decode step over *all* slots: each
   row attends at its own position (vector ``cache["len"]``, see
-  models/attention.py), the new K/V lands at that row's position, and
-  only *active* rows advance their counter / produce a real token.
+  models/attention.py) over the cache as it was plus its own new K/V, the
+  new K/V is appended at that row's position after the layer loop
+  (``transformer.append_rows``), and only *active* rows advance their
+  counter / produce a real token.
   Inactive rows compute garbage that stays beyond their valid length —
   masked at every future read and overwritten by the next prefill into
   that slot — so slot churn never changes the op's shape.
@@ -138,6 +140,8 @@ class PoolMeta:
     page_size: int = 0
     num_blocks: int = 0
     paged: Tuple[bool, ...] = ()
+    # what the decode step's trace found (``run_stack``'s record)
+    trace: dict = dataclasses.field(default_factory=dict, compare=False)
 
 
 _META = MetaRegistry()
@@ -154,6 +158,12 @@ def register_pool_meta(cfg, params_def, cache_def, batch_axes,
 
 def pool_meta(mid: int) -> PoolMeta:
     return _META.get(mid)
+
+
+def kv_append_leaves(mid: int) -> int:
+    """Cache leaves the compiled decode step writes by appending its new
+    K/V rows (set when the step is traced; 0 before, and on paged pools)."""
+    return _META.get(mid).trace.get("kv_append_leaves", 0)
 
 
 # --------------------------------------------------------------------------
@@ -231,7 +241,7 @@ def _pool_decode(meta: PoolMeta, params, cache_leaves, pos, tokf,
         caches["bt"] = bt
     x = L.embed(params["embed"], tokf).astype(jnp.dtype(cfg.dtype))
     x, new_caches = T.run_stack(cfg, params, x, positions=pos[:, None],
-                                caches=caches)
+                                caches=caches, record=meta.trace)
     x = T._norm(cfg, params["final_norm"], x)
     tok = _sample(_head_logits(cfg, params, x[:, 0]), meta.temperature, rng)
     tok = jnp.where(mask, tok, 0)[:, None]

@@ -18,6 +18,7 @@ from typing import Callable, Optional
 
 from repro.core.events import EventStream
 from repro.core.events import types as T
+from repro.serve.scheduler import pool_ops
 
 # counter keys the scheduler contributes to the shared stream; the same
 # registry role executor/stats.py plays for the engine
@@ -47,11 +48,13 @@ def make_stream(engine_events: Optional[EventStream],
 def merged_stats(sch) -> dict:
     """The scheduler's flat ``stats`` view: the shared counter dict (which
     already holds the engine counters under co-execution), the callback /
-    pool gauges, the process's GC pauses and compiles since the stream
+    pool gauges, the decode step's appended cache leaves (set when it is
+    traced), the process's GC pauses and compiles since the stream
     was made, and the engine phase."""
     out = dict(sch.sched_stats)
     out["callbacks_delivered"] = sch.callbacks.delivered
     out["peak_resident_tokens"] = sch.pool.peak_resident_tokens
+    out["kv_append_leaves"] = pool_ops.kv_append_leaves(sch._mid)
     out.update(sch.events.runtime_counters())
     if sch.use_terra:
         out.update(sch._tf.stats)

@@ -1,4 +1,5 @@
-"""Compile the serving path's Pallas kernels for a TPU v5e, without one.
+"""Compile the serving path's Pallas kernels and its decode step for a
+TPU v5e, without one.
 
 The TPU compiler is installed even where no chip is attached: a described
 ``v5e:2x2`` topology lets XLA and Mosaic compile for the chip, and refuse
@@ -11,16 +12,21 @@ process may load the TPU library at a time, and every xdist worker
 imports this file.  Nothing here runs; see chip_smoke.py for that.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.rmsnorm import rmsnorm
+from repro.models import model as M
+from repro.serve.scheduler import pool_ops
 
 Hq, Hkv, D, DM = 32, 8, 64, 2048        # granite-3-2b
 ROWS, TOKENS = 8, 2048
@@ -82,3 +88,64 @@ def test_rmsnorm_compiles(one_chip, shape):
         lambda x, g: rmsnorm(x, g, interpret=False),
         _spec(one_chip, *shape), _spec(one_chip, DM))
     assert "tpu_custom_call" in text
+
+
+# --------------------------------------------------------------------------
+# the dense slot-pool decode step: appends, never re-lays out its cache
+# --------------------------------------------------------------------------
+
+SLOTS, POOL_LEN, LAYERS = 16, 2048, 2
+MIB = 2 ** 20
+
+
+def _slot_decode(sharding, donate: bool):
+    """Compile ``serve.slot_decode`` over a dense 16 x 2048 pool at
+    granite-3-2b widths (``LAYERS`` layers); returns (compiled, cache
+    leaf shapes)."""
+    cfg = dataclasses.replace(get_config("granite-3-2b"), n_layers=LAYERS)
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    p_leaves, p_def = jax.tree_util.tree_flatten(M.abstract_params(cfg))
+    c_leaves = jax.eval_shape(
+        lambda: pool_ops.build_pool_cache(cfg, SLOTS, POOL_LEN)[0])
+    _, c_def, axes, paged = pool_ops.build_pool_cache(cfg, 1, 8)
+    mid = pool_ops.register_pool_meta(cfg, p_def, c_def, axes, 0.0,
+                                      POOL_LEN, 0, 0, paged)
+    attrs = dict(_meta=mid, _n_params=len(p_leaves),
+                 _n_cache=len(c_leaves), _has_rng=False)
+    state = [spec(x) for x in p_leaves + c_leaves] + [
+        _spec(sharding, SLOTS, dtype=jnp.int32),
+        _spec(sharding, SLOTS, 1, dtype=jnp.int32)]
+    donated = (tuple(range(len(p_leaves), len(state))) if donate else ())
+    step = jax.jit(pool_ops.program("serve.slot_decode"),
+                   static_argnames=tuple(attrs), donate_argnums=donated)
+    compiled = step.lower(*state, _spec(sharding, SLOTS, dtype=jnp.bool_),
+                          **attrs).compile()
+    assert pool_ops.kv_append_leaves(mid) == len(c_leaves) == 2
+    return compiled, [tuple(x.shape) for x in c_leaves]
+
+
+def _copied_shapes(text: str):
+    """Result shape of every copy op in compiled HLO text."""
+    return [tuple(int(d) for d in m.group(1).split(","))
+            for m in re.finditer(
+                r"= \w+\[([\d,]+)\]\S* copy(?:-start)?\(", text)]
+
+
+@pytest.mark.parametrize("donate", [False, True],
+                         ids=["co-execution", "donated"])
+def test_dense_slot_decode_appends_without_relayout(one_chip, donate):
+    compiled, leaf_shapes = _slot_decode(one_chip, donate)
+    copies = _copied_shapes(compiled.as_text())
+    layer = leaf_shapes[0][1:]
+    # no layer's K/V slice is copied (re-laid out) inside the layer loop
+    assert not [c for c in copies if c in (layer, (1,) + layer)], copies
+    whole = [c for c in copies if c in leaf_shapes]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if donate:
+        assert not whole and temp < 4 * MIB, (whole, temp / MIB)
+    else:
+        # the step may not write its input: one input-to-output copy each
+        assert len(whole) <= len(leaf_shapes), whole
