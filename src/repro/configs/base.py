@@ -40,6 +40,7 @@ class ModelConfig:
     # block pattern
     block_pattern: Tuple[str, ...] = ("attn",)
     extra_blocks: Tuple[str, ...] = ()   # appended after the scanned stack
+    lead_blocks: Tuple[str, ...] = ()    # applied before the scanned stack
     window: int = 0                    # SWA window for attn_swa
     local_window: int = 0              # window for attn_local
 
@@ -54,6 +55,32 @@ class ModelConfig:
     # explicit local-dispatch + all_to_all expert parallelism (requires
     # n_experts % model-axis == 0)
     moe_impl: str = "pjit"
+    # router weights: "topk_softmax" softmaxes the top-k logits (Mixtral,
+    # renormalised); "softmax_topk" softmaxes all logits and keeps the
+    # top-k probabilities as they are, times ``routed_scaling`` (DeepSeek-V2)
+    router: str = "topk_softmax"
+    routed_scaling: float = 1.0
+    # the routed experts this chip holds, [experts_first, experts_first +
+    # experts_held) of n_experts; 0 holds them all.  Read by the dropless
+    # layer (mla_moe blocks), which routes over all n_experts.
+    experts_first: int = 0
+    experts_held: int = 0
+
+    # multi-head latent attention (mla / mla_moe blocks): K/V come from a
+    # kv_lora_rank latent plus one shared rope key of qk_rope_head_dim;
+    # per head, queries and keys are qk_nope_head_dim + qk_rope_head_dim
+    # wide and values v_head_dim (no query LoRA)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (DeepSeek-V2); yarn_factor 0 is plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # SSM (Mamba-2)
     ssm_heads: int = 0
@@ -95,13 +122,25 @@ class ModelConfig:
     @property
     def n_pattern_blocks(self) -> int:
         per = len(self.block_pattern)
-        return (self.n_layers - len(self.extra_blocks)) // per
+        return (self.n_layers - len(self.extra_blocks)
+                - len(self.lead_blocks)) // per
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts this chip holds."""
+        return self.experts_first, self.experts_held or self.n_experts
 
     def validate(self):
         per = len(self.block_pattern)
-        assert (self.n_layers - len(self.extra_blocks)) % per == 0, \
+        assert (self.n_layers - len(self.extra_blocks)
+                - len(self.lead_blocks)) % per == 0, \
             f"{self.name}: {self.n_layers} layers not divisible by " \
-            f"pattern {self.block_pattern} + extras {self.extra_blocks}"
+            f"pattern {self.block_pattern} + lead {self.lead_blocks} + " \
+            f"extras {self.extra_blocks}"
+        first, held = self.held_experts
+        assert first + held <= self.n_experts, \
+            f"{self.name}: experts {first}..{first + held - 1} held of " \
+            f"{self.n_experts}"
 
 
 @dataclasses.dataclass(frozen=True)

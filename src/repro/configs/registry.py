@@ -10,8 +10,9 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.configs import (codeqwen15_7b, deepseek_moe_16b, granite3_2b,
-                           llama3_8b, llama32_vision_90b, mamba2_130m,
+from repro.configs import (codeqwen15_7b, deepseek_moe_16b,
+                           deepseek_v2_lite, granite3_2b, llama3_8b,
+                           llama32_vision_90b, mamba2_130m,
                            mixtral_8x22b, qwen25_14b, recurrentgemma_2b,
                            whisper_small)
 from repro.configs.base import ModelConfig
@@ -23,6 +24,7 @@ ARCHS = {
     "granite-3-2b": granite3_2b.CONFIG,
     "mixtral-8x22b": mixtral_8x22b.CONFIG,
     "deepseek-moe-16b": deepseek_moe_16b.CONFIG,
+    "deepseek-v2-lite": deepseek_v2_lite.CONFIG,
     "mamba2-130m": mamba2_130m.CONFIG,
     "llama-3.2-vision-90b": llama32_vision_90b.CONFIG,
     "whisper-small": whisper_small.CONFIG,
@@ -47,7 +49,7 @@ def smoke_config(arch: str) -> ModelConfig:
     per = len(cfg.block_pattern)
     repl = dict(
         name=cfg.name + "-smoke",
-        n_layers=per + len(cfg.extra_blocks),
+        n_layers=per + len(cfg.extra_blocks) + len(cfg.lead_blocks),
         d_model=64,
         n_heads=4 if cfg.n_heads else 0,
         n_kv_heads=min(cfg.n_kv_heads, 2) if cfg.n_kv_heads else 0,
@@ -64,7 +66,10 @@ def smoke_config(arch: str) -> ModelConfig:
         repl.update(n_experts=4, top_k=2,
                     moe_d_ff=64 if cfg.moe_d_ff else 0,
                     n_shared_experts=min(cfg.n_shared_experts, 1),
-                    capacity_factor=4.0)
+                    capacity_factor=4.0, experts_first=0, experts_held=0)
+    if cfg.kv_lora_rank:
+        repl.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                    v_head_dim=16)
     if cfg.ssm_heads:
         repl.update(ssm_heads=4, ssm_head_dim=16, ssm_state=16, ssd_chunk=16)
     if cfg.rglru_width:

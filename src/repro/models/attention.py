@@ -50,17 +50,21 @@ def _scan_or_unroll(f, init, n, unroll):
 
 def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
                       q_block: int = 512, kv_block: int = 1024,
-                      q_offset=0, kv_valid_len=None, unroll: bool = False):
-    """q: [B,Sq,Hq,D], k/v: [B,Skv,Hkv,D] with Hq % Hkv == 0.
+                      q_offset=0, kv_valid_len=None, unroll: bool = False,
+                      scale=None):
+    """q: [B,Sq,Hq,D], k: [B,Skv,Hkv,D], v: [B,Skv,Hkv,Dv] with
+    Hq % Hkv == 0; returns [B,Sq,Hq,Dv].
 
     ``window`` > 0 restricts attention to the last ``window`` keys (SWA /
     local attention).  ``q_offset`` is the absolute position of q[0]
     (used at decode time and for local attention in cache mode).
+    ``scale`` defaults to D**-0.5.
     """
     B, Sq, Hq, D = q.shape
     _, Skv, Hkv, _ = k.shape
+    Dv = v.shape[-1]
     G = Hq // Hkv
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
 
     qb = _pick_block(Sq, q_block)
     kb = _pick_block(Skv, kv_block)
@@ -69,7 +73,7 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
     # [B, Hkv, G, nq, qb, D]
     qr = q.reshape(B, nq, qb, Hkv, G, D).transpose(0, 3, 4, 1, 2, 5) * scale
     kr = k.reshape(B, nk, kb, Hkv, D).transpose(0, 3, 1, 2, 4)
-    vr = v.reshape(B, nk, kb, Hkv, D).transpose(0, 3, 1, 2, 4)
+    vr = v.reshape(B, nk, kb, Hkv, Dv).transpose(0, 3, 1, 2, 4)
 
     q_pos = q_offset + jnp.arange(Sq).reshape(nq, qb)
     k_pos = jnp.arange(Skv).reshape(nk, kb)
@@ -102,7 +106,7 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
                 preferred_element_type=jnp.float32)
             return (acc_new, m_new, l_new), None
 
-        init = (jnp.zeros((B, Hkv, G, qb, D), jnp.float32),
+        init = (jnp.zeros((B, Hkv, G, qb, Dv), jnp.float32),
                 jnp.full((B, Hkv, G, qb), NEG_INF, jnp.float32),
                 jnp.zeros((B, Hkv, G, qb), jnp.float32))
         (acc, m, l), _ = _scan_or_unroll(kv_step, init, nk, unroll)
@@ -110,8 +114,8 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
         return None, out.astype(q.dtype)
 
     _, outs = _scan_or_unroll(q_step, None, nq, unroll)
-    # outs: [nq, B, Hkv, G, qb, D] -> [B, Sq, Hq, D]
-    out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq, Hq, D)
+    # outs: [nq, B, Hkv, G, qb, Dv] -> [B, Sq, Hq, Dv]
+    out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq, Hq, Dv)
     return out
 
 

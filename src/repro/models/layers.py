@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -37,17 +38,43 @@ def dense(x, w, b=None):
 # Rotary position embedding
 # --------------------------------------------------------------------------
 
-def rope(x, positions, theta: float = 500000.0):
-    """x: [..., S, H, D]; positions: [..., S] (broadcastable)."""
+def rope(x, positions, theta: float = 500000.0, inv_freq=None):
+    """x: [..., S, H, D]; positions: [..., S] (broadcastable).
+    ``inv_freq`` [D/2] replaces the plain ``theta`` frequencies (YaRN)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freqs = (1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+             if inv_freq is None else inv_freq)
     ang = positions[..., :, None].astype(jnp.float32) * freqs  # [..., S, half]
     cos = jnp.cos(ang)[..., :, None, :]
     sin = jnp.sin(ang)[..., :, None, :]
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention scale for a context stretched ``factor`` times."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_len: int,
+                  beta_fast: float, beta_slow: float):
+    """DeepSeek-V2's YaRN frequencies [dim/2]: the plain frequencies where
+    a dimension turns more than ``beta_fast`` times over the original
+    context, those divided by ``factor`` where it turns fewer than
+    ``beta_slow`` times, and a linear ramp between."""
+    def corr(rot):
+        return (dim * math.log(original_len / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    extra = 1.0 / theta ** (2 * i / dim)
+    keep = 1.0 - jnp.clip((i - low) / (high - low), 0.0, 1.0)
+    return extra / factor * (1.0 - keep) + extra * keep
 
 
 # --------------------------------------------------------------------------

@@ -40,8 +40,12 @@ def active_param_count(cfg: ModelConfig) -> int:
         return total
     f = cfg.moe_d_ff or cfg.d_ff
     per_expert = 3 * cfg.d_model * f
-    n_blocks = cfg.n_pattern_blocks * cfg.block_pattern.count("moe")
-    inactive = n_blocks * (cfg.n_experts - cfg.top_k) * per_expert
+    kinds = cfg.lead_blocks + cfg.block_pattern * cfg.n_pattern_blocks \
+        + cfg.extra_blocks
+    n_blocks = sum(1 for k in kinds if k in ("moe", "mla_moe"))
+    held = cfg.held_experts[1]      # of which top_k / n_experts are active
+    inactive = (n_blocks * held * (cfg.n_experts - cfg.top_k) * per_expert
+                // cfg.n_experts)
     return total - inactive
 
 
@@ -60,6 +64,10 @@ def _slot_cache(cfg, kind: str, nb: Optional[int], batch: int, max_len: int):
         Hkv, D = cfg.n_kv_heads, cfg.head_dim
         return {"k": jnp.zeros(shp(batch, max_len, Hkv, D), dt),
                 "v": jnp.zeros(shp(batch, max_len, Hkv, D), dt)}
+    if kind in T.MLA_KINDS:
+        # one latent row [c ; k_pe] per token, no head axis (DESIGN.md §11)
+        width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        return {"ckv": jnp.zeros(shp(batch, max_len, width), dt)}
     if kind == "ssd":
         H, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
         dc = H * P + 2 * N                      # conv runs over (x, B, C)
@@ -75,15 +83,29 @@ def _slot_cache(cfg, kind: str, nb: Optional[int], batch: int, max_len: int):
     raise ValueError(kind)
 
 
+def counts_experts(cfg: ModelConfig) -> bool:
+    """True when the stack has dropless expert blocks, whose routed pairs
+    per row and held expert a cache accumulates (``expert_tokens``)."""
+    kinds = cfg.lead_blocks + cfg.block_pattern + cfg.extra_blocks
+    return "mla_moe" in kinds
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int):
     nb = cfg.n_pattern_blocks
-    return {
+    cache = {
         "layers": [_slot_cache(cfg, kind, nb, batch, max_len)
                    for kind in cfg.block_pattern],
         "extra": [_slot_cache(cfg, kind, None, batch, max_len)
                   for kind in cfg.extra_blocks],
         "len": jnp.zeros((), jnp.int32),
     }
+    if cfg.lead_blocks:
+        cache["lead"] = [_slot_cache(cfg, kind, None, batch, max_len)
+                         for kind in cfg.lead_blocks]
+    if counts_experts(cfg):
+        cache["expert_tokens"] = jnp.zeros((batch, cfg.held_experts[1]),
+                                           jnp.int32)
+    return cache
 
 
 # ==========================================================================

@@ -11,6 +11,12 @@ rules in parallel/sharding.py.
 
 DeepSeek-MoE fine-grained routing (64 routed + 2 shared experts, top-6) is
 the same code path with ``n_shared_experts`` > 0.
+
+``moe_dropless`` is the serving layer of the ``mla_moe`` blocks
+(DeepSeek-V2): no capacity, so no token is dropped, and told which
+experts this chip holds (``cfg.held_experts``).  It routes over all
+``n_experts``, computes only the (token, held expert) pairs and the
+shared experts, and counts the pairs per held expert.
 """
 
 from __future__ import annotations
@@ -42,6 +48,19 @@ def _moe_axes(E: int):
     return None, "capacity"
 
 
+def route(p, xt, cfg):
+    """Router over all ``n_experts`` (float32): (weights [T, K], expert
+    ids [T, K]).  ``topk_softmax`` renormalises over the top k (Mixtral);
+    ``softmax_topk`` keeps the top k of the softmax over every expert, as
+    they are, times ``routed_scaling`` (DeepSeek-V2)."""
+    logits = dense(xt.astype(jnp.float32), p["w_router"])       # [T, E]
+    if cfg.router == "softmax_topk":
+        w, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+        return w * cfg.routed_scaling, ids
+    w, ids = jax.lax.top_k(logits, cfg.top_k)
+    return jax.nn.softmax(w, axis=-1), ids
+
+
 def moe_block(p, x, cfg):
     """x: [B, S, d] -> [B, S, d]."""
     B, S, d = x.shape
@@ -50,9 +69,8 @@ def moe_block(p, x, cfg):
     xt = x.reshape(T, d)
 
     # ---- router ----------------------------------------------------------
-    logits = dense(xt.astype(jnp.float32), p["w_router"])       # [T, E]
-    gate_w, gate_ids = jax.lax.top_k(logits, K)                 # [T, K]
-    gate_w = jax.nn.softmax(gate_w, axis=-1).astype(x.dtype)
+    gate_w, gate_ids = route(p, xt, cfg)                        # [T, K]
+    gate_w = gate_w.astype(x.dtype)
 
     # ---- capacity + position-in-expert ------------------------------------
     C = int(cfg.capacity_factor * T * K / E)
@@ -92,3 +110,58 @@ def moe_block(p, x, cfg):
         combined = combined + mlp_swiglu(p["shared"], xt)
 
     return combined.reshape(B, S, d)
+
+
+DENSE_TOKENS = 64   # at or below: every held expert runs over every token
+
+
+def moe_dropless(p, x, cfg, valid=None):
+    """x [B, S, d] -> (y [B, S, d], routed pairs per row and held expert
+    [B, n] int32).
+
+    ``p`` holds the held experts' weights ([n, ...], expert
+    ``cfg.experts_first`` first) and the full-width router.  ``valid``
+    [B, S] bool marks real tokens: the others (bucket padding, idle pool
+    rows) are neither counted nor, on the grouped path, computed.
+
+    Up to ``DENSE_TOKENS`` tokens (a decode step) every held expert runs
+    over every token, weighted by zero where not routed: each expert's
+    weights are read once either way.  Above, the held pairs are sorted by
+    expert and run as ``ragged_dot`` groups, so only routed pairs are
+    computed.  Either way nothing is dropped.
+    """
+    B, S, d = x.shape
+    T, K = B * S, cfg.top_k
+    first, n = cfg.held_experts
+    f32 = jnp.float32
+    xt = x.reshape(T, d)
+    w, ids = route(p, xt, cfg)
+    local = ids - first
+    here = (local >= 0) & (local < n)
+    if valid is not None:
+        here &= jnp.reshape(valid, (T, 1))
+    slot = jnp.where(here, local, n)                            # n: elsewhere
+    hot = jax.nn.one_hot(slot, n, dtype=jnp.int32)              # [T, K, n]
+    counts = hot.reshape(B, S * K, n).sum(axis=1)
+    if T <= DENSE_TOKENS:
+        gate = jnp.einsum("tke,tk->te", hot.astype(f32), w)     # [T, n]
+        h = jax.nn.silu(jnp.einsum("td,edf->etf", xt, p["w_gate"])) \
+            * jnp.einsum("td,edf->etf", xt, p["w_up"])
+        ye = jnp.einsum("etf,efd->etd", h, p["w_down"])
+        routed = jnp.einsum("te,etd->td", gate, ye.astype(f32))
+    else:
+        flat = slot.reshape(-1)                                 # [T*K]
+        order = jnp.argsort(flat, stable=True)  # held pairs first, by expert
+        pair_here = flat[order] < n
+        tok = order // K
+        sizes = jnp.bincount(flat, length=n + 1)[:n].astype(jnp.int32)
+        xs = xt[tok]
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"], sizes)) \
+            * jax.lax.ragged_dot(xs, p["w_up"], sizes)
+        ys = jax.lax.ragged_dot(h, p["w_down"], sizes)          # [T*K, d]
+        ys = jnp.where(pair_here[:, None], ys.astype(f32)
+                       * w.reshape(-1)[order][:, None], 0.0)
+        routed = jnp.zeros((T, d), f32).at[tok].add(ys)
+    if cfg.n_shared_experts:
+        routed = routed + mlp_swiglu(p["shared"], xt).astype(f32)
+    return routed.astype(x.dtype).reshape(B, S, d), counts

@@ -23,12 +23,14 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models.attention import attention_block
-from repro.models.moe import moe_block
+from repro.models.mla import mla_attention
+from repro.models.moe import moe_block, moe_dropless
 from repro.models.rglru import rglru_block
 from repro.models.ssm import mamba2_block
 from repro.parallel.sharding import logical
 
 ATTN_KINDS = ("attn", "attn_swa", "attn_local", "moe", "enc_attn")
+MLA_KINDS = ("mla", "mla_moe")
 
 
 # ==========================================================================
@@ -55,6 +57,20 @@ def _attn_params(cfg, key, cross: bool = False):
     return p
 
 
+def _mla_params(cfg, key):
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 5)
+    return {
+        "wq": L.he_init(ks[0], (d, H * (dn + dr)), _dt(cfg)),
+        "wkv_a": L.he_init(ks[1], (d, r + dr), _dt(cfg)),
+        "kv_norm": jnp.zeros((r,), _dt(cfg)),
+        "w_uk": L.he_init(ks[2], (r, H * dn), _dt(cfg)),
+        "w_uv": L.he_init(ks[3], (r, H * dv), _dt(cfg)),
+        "wo": L.he_init(ks[4], (H * dv, d), _dt(cfg)),
+    }
+
+
 def _mlp_params(cfg, key, d_ff=None):
     d, f = cfg.d_model, d_ff or cfg.d_ff
     ks = jax.random.split(key, 3)
@@ -68,10 +84,10 @@ def _mlp_params(cfg, key, d_ff=None):
 def _moe_params(cfg, key):
     d = cfg.d_model
     f = cfg.moe_d_ff or cfg.d_ff
-    E = cfg.n_experts
+    E = cfg.held_experts[1]          # the router spans all n_experts
     ks = jax.random.split(key, 5)
     p = {
-        "w_router": L.he_init(ks[0], (d, E), jnp.float32),
+        "w_router": L.he_init(ks[0], (d, cfg.n_experts), jnp.float32),
         "w_gate": L.he_init(ks[1], (E, d, f), _dt(cfg)),
         "w_up": L.he_init(ks[2], (E, d, f), _dt(cfg)),
         "w_down": L.he_init(ks[3], (E, f, d), _dt(cfg)),
@@ -131,6 +147,13 @@ def _block_params(cfg, key, kind: str):
         p["attn"] = _attn_params(cfg, ks[0])
         p["norm2"] = _norm_params(cfg)
         p["moe"] = _moe_params(cfg, ks[1])
+    elif kind in MLA_KINDS:
+        p["attn"] = _mla_params(cfg, ks[0])
+        p["norm2"] = _norm_params(cfg)
+        if kind == "mla_moe":
+            p["moe"] = _moe_params(cfg, ks[1])
+        else:
+            p["mlp"] = _mlp_params(cfg, ks[1])
     elif kind == "ssd":
         p["ssd"] = _ssd_params(cfg, ks[0])
     elif kind == "rglru":
@@ -176,6 +199,10 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
                         for i, kind in enumerate(cfg.block_pattern)]
     params["extra"] = [_block_params(cfg, jax.random.fold_in(keys[3], i), k)
                        for i, k in enumerate(cfg.extra_blocks)]
+    if cfg.lead_blocks:
+        params["lead"] = [_block_params(cfg, jax.random.fold_in(keys[6], i),
+                                        k)
+                          for i, k in enumerate(cfg.lead_blocks)]
     if cfg.enc_layers:
         kse = jax.random.split(keys[4], cfg.enc_layers)
         params["encoder"] = jax.tree.map(
@@ -200,13 +227,16 @@ def _norm(cfg, p, x):
 
 def block_forward(cfg, kind: str, p, x, *, positions, cache=None,
                   cache_len=None, cache_bt=None, cross_states=None,
-                  causal=True):
+                  causal=True, valid=None, counts=None):
     """One block of kind ``kind``.  Returns (x, new_cache).
 
-    Attention caches are stored per layer as {"k","v"} (dense rows) or
-    {"kp","vp"} (paged block arenas); the shared fill length — and, for
-    paged caches, the shared block table ``cache_bt`` — is threaded
-    separately so layer caches can be stacked and scanned.
+    Attention caches are stored per layer as {"k","v"} (dense rows),
+    {"kp","vp"} (paged block arenas) or {"ckv"} (latent rows, MLA); the
+    shared fill length — and, for paged caches, the shared block table
+    ``cache_bt`` — is threaded separately so layer caches can be stacked
+    and scanned.  A dropless expert block appends its routed pairs per
+    row and held expert [B, n] to ``counts`` (a list), counting the
+    tokens ``valid`` [B, S] marks (all when None).
     """
     def _with_len(c):
         if c is None:
@@ -242,6 +272,22 @@ def block_forward(cfg, kind: str, p, x, *, positions, cache=None,
                 x = x + moe_block(p["moe"], ff_in, cfg)
         else:
             x = x + L.mlp_swiglu(p["mlp"], ff_in)
+    elif kind in MLA_KINDS:
+        with jax.named_scope("terra.mla"):
+            h, new_cache = mla_attention(
+                p["attn"], _norm(cfg, p["norm1"], x), cfg,
+                positions=positions, cache=_with_len(cache), causal=causal)
+        new_cache = _strip_len(new_cache)
+        x = x + jax.ad_checkpoint.checkpoint_name(h, "attn_out")
+        ff_in = _norm(cfg, p["norm2"], x)
+        if kind == "mla_moe":
+            with jax.named_scope("terra.moe"):
+                y, n_pairs = moe_dropless(p["moe"], ff_in, cfg, valid=valid)
+            if counts is not None:
+                counts.append(n_pairs)
+            x = x + y
+        else:
+            x = x + L.mlp_swiglu(p["mlp"], ff_in)
     elif kind == "ssd":
         h, new_cache = mamba2_block(p["ssd"], _norm(cfg, p["norm1"], x),
                                     cfg, cache=cache)
@@ -275,7 +321,8 @@ def block_forward(cfg, kind: str, p, x, *, positions, cache=None,
 
 
 def _superblock(cfg, slot_params, x, *, positions, caches=None,
-                cache_len=None, cache_bt=None, cross_states=None):
+                cache_len=None, cache_bt=None, cross_states=None,
+                valid=None, counts=None):
     """Apply one instance of the block pattern.  slot_params/caches are
     per-slot lists (already sliced to this super-block)."""
     new_caches = []
@@ -284,7 +331,8 @@ def _superblock(cfg, slot_params, x, *, positions, caches=None,
         x, nc = block_forward(cfg, kind, slot_params[slot], x,
                               positions=positions, cache=c,
                               cache_len=cache_len, cache_bt=cache_bt,
-                              cross_states=cross_states)
+                              cross_states=cross_states, valid=valid,
+                              counts=counts)
         new_caches.append(nc)
     return x, new_caches
 
@@ -292,27 +340,29 @@ def _superblock(cfg, slot_params, x, *, positions, caches=None,
 APPEND_TILE = 128     # positions per read-modify-write of an appended row
 
 
-def append_rows(cache, rows, pos):
+def append_rows(cache, rows, pos, lead=None):
     """Write each row's new K or V into a dense cache leaf at its position.
 
-    cache [..., B, Smax, Hkv, D] (a leading layer axis when stacked), rows
-    [..., B, Hkv, D], pos [B] or scalar.  Each row is a read-modify-write
+    cache [..., B, Smax, *F] (``lead`` leading layer axes when stacked;
+    by default F is [Hkv, D]), rows [..., B, *F], pos [B] or scalar.  An
+    MLA latent leaf has F = [r + dr].  Each row is a read-modify-write
     of the aligned tile of ``min(128, Smax)`` positions that holds it, in a
     loop over the rows: on a TPU that keeps the leaf in its stored,
     position-minor layout, where a scatter or a one-position update makes
     XLA re-lay out the whole leaf.  A position at or beyond ``Smax`` lands
     at ``Smax - 1``, as ``dynamic_update_slice`` clamps.
     """
-    lead = cache.ndim - 4
+    lead = cache.ndim - 4 if lead is None else lead
     B, Smax = cache.shape[lead], cache.shape[lead + 1]
+    feat = cache.ndim - lead - 2
     blk = min(APPEND_TILE, Smax)
     p = jnp.minimum(jnp.broadcast_to(pos, (B,)), Smax - 1).astype(jnp.int32)
     s0 = jnp.minimum(p // blk * blk, Smax - blk)
     tile_shape = cache.shape[:lead] + (1, blk) + cache.shape[lead + 2:]
-    lane = jnp.arange(blk).reshape(blk, 1, 1)
+    lane = jnp.arange(blk).reshape((blk,) + (1,) * feat)
 
     def write(b, c):
-        start = (0,) * lead + (b, s0[b], 0, 0)
+        start = (0,) * lead + (b, s0[b]) + (0,) * feat
         tile = jax.lax.dynamic_slice(c, start, tile_shape)
         row = jax.lax.dynamic_slice_in_dim(rows, b, 1, axis=lead)
         row = jnp.expand_dims(row, lead + 1).astype(c.dtype)
@@ -322,38 +372,63 @@ def append_rows(cache, rows, pos):
     return jax.lax.fori_loop(0, B, write, cache)
 
 
-def _settle(cache, out, pos):
+def _appended(out) -> int:
+    """Cache leaves a block's output appends to (its ``*_row`` entries)."""
+    return 0 if out is None else sum(1 for k in out if k.endswith("_row"))
+
+
+def _settle(cache, out, pos, lead: int):
     """A block's cache after the step: the rows a single-token decode
-    appended, written into ``cache``; else the cache the block returned."""
-    if out is None or "k_row" not in out:
+    appended (``<leaf>_row``), written into ``cache``; else the cache the
+    block returned.  ``lead`` is 1 for a scanned (stacked) cache."""
+    if not _appended(out):
         return out
-    return {"k": append_rows(cache["k"], out["k_row"], pos),
-            "v": append_rows(cache["v"], out["v_row"], pos)}
+    return {name: append_rows(leaf, out[name + "_row"], pos, lead=lead)
+            for name, leaf in cache.items()}
+
+
+def _unscanned(cfg, kinds, params, caches, x, counts, **kw):
+    """Apply blocks one by one (leading or extra blocks)."""
+    new = []
+    for i, kind in enumerate(kinds):
+        c = caches[i] if caches is not None else None
+        x, nc = block_forward(cfg, kind, params[i], x, cache=c,
+                              counts=counts, **kw)
+        new.append(nc)
+    return x, new
 
 
 def run_stack(cfg, params, x, *, positions, caches=None, cross_states=None,
-              record=None):
-    """Scan over super-blocks (+ unrolled extra blocks).
+              record=None, valid=None):
+    """Leading blocks, a scan over super-blocks, then the extra blocks.
 
     Single-token decode against dense attention caches reads each layer's
-    cache as scan input only; the scan emits the new K/V rows, written
-    after the layer loop (``append_rows``, DESIGN.md §11).  ``record``, if
-    given, receives at trace time ``kv_append_leaves``: the number of cache
-    leaves this step writes by appending (0 when the path did not engage).
+    cache as scan input only; the scan emits the new K/V (or latent) rows,
+    written after the layer loop (``append_rows``, DESIGN.md §11).
+    ``record``, if given, receives at trace time ``kv_append_leaves``: the
+    number of cache leaves this step writes by appending (0 when the path
+    did not engage), and ``experts_held`` where dropless expert blocks
+    ran.  With ``caches``, those blocks' routed pairs per row and held
+    expert [B, n], over the tokens ``valid`` [B, S] marks, are added to
+    ``caches["expert_tokens"]`` (zero where absent) in the returned
+    caches.
     """
     x = logical(x, "batch", None, None)
     cache_len = caches["len"] if caches is not None else None
     cache_bt = caches.get("bt") if caches is not None else None
+    kw = dict(positions=positions, cache_len=cache_len, cache_bt=cache_bt,
+              cross_states=cross_states, valid=valid)
+    counts = []
+    x, new_lead = _unscanned(cfg, cfg.lead_blocks, params.get("lead", []),
+                             caches.get("lead") if caches is not None
+                             else None, x, counts, **kw)
 
     def body(h, xs):
         slot_params, slot_caches = xs
+        cnt = []
         h, new_caches = _superblock(cfg, slot_params, h,
-                                    positions=positions,
-                                    caches=slot_caches,
-                                    cache_len=cache_len,
-                                    cache_bt=cache_bt,
-                                    cross_states=cross_states)
-        return h, new_caches
+                                    caches=slot_caches, counts=cnt, **kw)
+        return h, (new_caches, sum(cnt) if cnt else None)
 
     if cfg.remat:
         policy = {
@@ -375,33 +450,37 @@ def run_stack(cfg, params, x, *, positions, caches=None, cross_states=None,
                                 (params["blocks"], scanned_caches))
             x, y = body_fn(x, xs_i)
             ys.append(y)
-        new_layer_caches = jax.tree.map(lambda *zs: jnp.stack(zs), *ys) \
-            if caches is not None else None
+        new_layer_caches, layer_counts = jax.tree.map(
+            lambda *zs: jnp.stack(zs), *ys)
     else:
-        x, new_layer_caches = jax.lax.scan(
+        x, (new_layer_caches, layer_counts) = jax.lax.scan(
             body_fn, x, (params["blocks"], scanned_caches))
+    if layer_counts is not None:
+        counts.append(layer_counts.sum(axis=0))
 
-    new_extra = []
-    for i, kind in enumerate(cfg.extra_blocks):
-        c = caches["extra"][i] if caches is not None else None
-        x, nc = block_forward(cfg, kind, params["extra"][i], x,
-                              positions=positions, cache=c,
-                              cache_len=cache_len, cache_bt=cache_bt,
-                              cross_states=cross_states)
-        new_extra.append(nc)
+    x, new_extra = _unscanned(cfg, cfg.extra_blocks, params["extra"],
+                              caches["extra"] if caches is not None
+                              else None, x, counts, **kw)
 
     if caches is None:
         return x, None
-    outs = list(new_layer_caches) + new_extra
+    outs = new_lead + list(new_layer_caches) + new_extra
     if record is not None:
-        record["kv_append_leaves"] = 2 * sum(
-            1 for o in outs if o is not None and "k_row" in o)
+        record["kv_append_leaves"] = sum(_appended(o) for o in outs)
+        if counts:
+            record["experts_held"] = cfg.held_experts[1]
     new_caches = {
-        "layers": [_settle(c, o, cache_len)
+        "layers": [_settle(c, o, cache_len, 1)
                    for c, o in zip(scanned_caches, new_layer_caches)],
-        "extra": [_settle(c, o, cache_len)
+        "extra": [_settle(c, o, cache_len, 0)
                   for c, o in zip(caches["extra"], new_extra)],
         "len": cache_len + x.shape[1]}
+    if cfg.lead_blocks:
+        new_caches["lead"] = [_settle(c, o, cache_len, 0)
+                              for c, o in zip(caches["lead"], new_lead)]
+    if counts:
+        new_caches["expert_tokens"] = (caches.get("expert_tokens", 0)
+                                       + sum(counts))
     return x, new_caches
 
 

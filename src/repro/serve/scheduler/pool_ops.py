@@ -35,6 +35,11 @@ block table ``bt`` [max_slots, nbps] fed each step; recurrent leaves
 (O(1) state per slot) stay dense.  Prefill scatters whole bucket rows
 block-wise through the admitted rows' tables (``bt_rows`` [b, nbps]).
 
+Leading blocks keep unscanned leaves (slot axis first).  Dropless expert
+blocks (``mla_moe``) add a pool leaf ``expert_tokens`` [2, held] int32:
+the routed (real token, held expert) pairs of decode (row 0) and prefill
+(row 1) steps, accumulated on the device (read by ``sch.stats``).
+
 Pytrees are flattened at the op boundary; a meta registry keeps the
 (static) treedefs and per-leaf scatter axes out of band.
 """
@@ -58,27 +63,39 @@ from repro.serve.meta import MetaRegistry
 # the valid length are masked out by the attention valid-length mask);
 # recurrent kinds fold every position into their state, so their prompts
 # must be admitted at exact length (no padding)
-PAD_SAFE_KINDS = ("attn", "attn_swa", "attn_local", "moe")
+PAD_SAFE_KINDS = ("attn", "attn_swa", "attn_local", "moe", "mla",
+                  "mla_moe")
 RECURRENT_KINDS = ("ssd", "rglru")
+COUNTER_AXIS = -1     # the expert counter's: no slot axis, steps add to it
 
 
-def check_supported(cfg) -> None:
+def _kinds(cfg):
+    return cfg.lead_blocks + cfg.block_pattern + cfg.extra_blocks
+
+
+def check_supported(cfg, page_size: int = 0) -> None:
     """The slot pool supports self-attention and recurrent decoder stacks;
     encoder/cross-attention families need per-request side inputs that the
-    pooled step has no lane for yet — the lock-step engine serves those."""
-    kinds = tuple(cfg.block_pattern) + tuple(cfg.extra_blocks)
+    pooled step has no lane for yet — the lock-step engine serves those.
+    A paged pool holds K/V block arenas only: latent (MLA) caches are
+    served from the dense pool."""
+    kinds = _kinds(cfg)
     bad = [k for k in kinds if k not in PAD_SAFE_KINDS + RECURRENT_KINDS]
     if bad or cfg.enc_layers:
         raise NotImplementedError(
             f"slot-pooled scheduling does not support {cfg.name}: block "
             f"kinds {bad or ['encoder']} need per-request cross/frontend "
             "state; use ServingEngine.run_batch for this family")
+    latent = sorted(set(kinds) & set(T.MLA_KINDS))
+    if page_size and latent:
+        raise NotImplementedError(
+            f"a paged pool has no arena for {cfg.name}'s latent cache "
+            f"({latent}); serve it from the dense pool (page_size=None)")
 
 
 def pads_allowed(cfg) -> bool:
     """True when prompts may be right-padded to their length bucket."""
-    kinds = tuple(cfg.block_pattern) + tuple(cfg.extra_blocks)
-    return all(k in PAD_SAFE_KINDS for k in kinds)
+    return all(k in PAD_SAFE_KINDS for k in _kinds(cfg))
 
 
 def build_pool_cache(cfg, max_slots: int, max_len: int, page_size: int = 0,
@@ -87,7 +104,8 @@ def build_pool_cache(cfg, max_slots: int, max_len: int, page_size: int = 0,
     ``len`` (replaced by the per-slot position vector).  Returns
     (leaves, treedef, batch_axes, paged): ``batch_axes[i]`` is the slot
     axis of leaf i — scanned layer caches carry a leading
-    n_pattern_blocks axis, extra-block caches do not — and ``paged[i]``
+    n_pattern_blocks axis, leading- and extra-block caches do not, and
+    the expert counter has none (``COUNTER_AXIS``) — and ``paged[i]``
     marks leaves laid out as block arenas instead of slot rows."""
     dt = jnp.dtype(cfg.dtype)
 
@@ -113,16 +131,30 @@ def build_pool_cache(cfg, max_slots: int, max_len: int, page_size: int = 0,
                      for k, s in zip(cfg.block_pattern, tmpl["layers"])],
           "extra": [pg_tree(k, s)
                     for k, s in zip(cfg.extra_blocks, tmpl["extra"])]}
+    if cfg.lead_blocks:
+        tmpl["lead"] = [slot(k, None) for k in cfg.lead_blocks]
+        axes_tree["lead"] = jax.tree.map(lambda _: 0, tmpl["lead"])
+        pg["lead"] = [pg_tree(k, s)
+                      for k, s in zip(cfg.lead_blocks, tmpl["lead"])]
+    if M.counts_experts(cfg):
+        tmpl["expert_tokens"] = jnp.zeros((2, cfg.held_experts[1]),
+                                          jnp.int32)
+        axes_tree["expert_tokens"] = COUNTER_AXIS
+        pg["expert_tokens"] = False
     leaves, treedef = jax.tree_util.tree_flatten(tmpl)
     axes = jax.tree_util.tree_leaves(axes_tree)
     paged = jax.tree_util.tree_leaves(pg)
     return leaves, treedef, tuple(axes), tuple(paged)
 
 
+POOL_KEYS = ("lead", "layers", "extra", "expert_tokens")
+
+
 def _flatten_cache(cache) -> List[Any]:
     """Flatten a run_stack cache pytree in pool-leaf order (minus len)."""
-    return jax.tree_util.tree_leaves({"layers": cache["layers"],
-                                      "extra": cache["extra"]})
+    return jax.tree_util.tree_leaves({k: cache[k] for k in POOL_KEYS
+                                      if k in cache})
+
 
 
 # --------------------------------------------------------------------------
@@ -162,8 +194,9 @@ def pool_meta(mid: int) -> PoolMeta:
 
 def kv_append_leaves(mid: int) -> int:
     """Cache leaves the compiled decode step writes by appending its new
-    K/V rows (set when the step is traced; 0 before, and on paged pools)."""
+    K/V or latent rows (set when it is traced; 0 before, and paged)."""
     return _META.get(mid).trace.get("kv_append_leaves", 0)
+
 
 
 # --------------------------------------------------------------------------
@@ -194,8 +227,11 @@ def _pool_prefill(meta: PoolMeta, params, cache_leaves, pos, tokf, tokens,
     # lock-step prefill (same shapes through run_stack), scattered whole-row
     fresh = M.init_cache(cfg, B, meta.max_len)
     x = L.embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
+    real = jnp.arange(S)[None, :] < lengths[:, None]
     x, fresh = T.run_stack(cfg, params, x, positions=jnp.arange(S)[None],
-                           caches=fresh)
+                           caches=fresh, valid=real)
+    # pad rows repeat the last real row (its slot too): count it once
+    first = jnp.concatenate([jnp.ones((1,), bool), slots[1:] != slots[:-1]])
     x = T._norm(cfg, params["final_norm"], x)                  # [b, S, d]
     last = jnp.take_along_axis(
         x, (lengths - 1).astype(jnp.int32)[:, None, None], axis=1)[:, 0]
@@ -206,7 +242,10 @@ def _pool_prefill(meta: PoolMeta, params, cache_leaves, pos, tokf, tokens,
     for pool_leaf, b_leaf, ax, pg in zip(cache_leaves, _flatten_cache(fresh),
                                          meta.batch_axes, meta.paged):
         b_leaf = b_leaf.astype(pool_leaf.dtype)
-        if pg:
+        if ax == COUNTER_AXIS:
+            new_leaves.append(pool_leaf.at[1].add(
+                jnp.where(first[:, None], b_leaf, 0).sum(axis=0)))
+        elif pg:
             # block-wise scatter of the dense bucket rows through the
             # admitted rows' block tables; unassigned table tail entries
             # are 0 -> the trash block (never read)
@@ -235,19 +274,25 @@ def _pool_decode(meta: PoolMeta, params, cache_leaves, pos, tokf,
     shape class forever."""
     cfg = meta.cfg
     cache = jax.tree_util.tree_unflatten(meta.cache_def, cache_leaves)
-    caches = {"layers": cache["layers"], "extra": cache["extra"],
-              "len": pos}
+    caches = {k: cache[k] for k in ("lead", "layers", "extra") if k in cache}
+    caches["len"] = pos
     if bt is not None:
         caches["bt"] = bt
     x = L.embed(params["embed"], tokf).astype(jnp.dtype(cfg.dtype))
     x, new_caches = T.run_stack(cfg, params, x, positions=pos[:, None],
-                                caches=caches, record=meta.trace)
+                                caches=caches, record=meta.trace,
+                                valid=mask[:, None])
     x = T._norm(cfg, params["final_norm"], x)
     tok = _sample(_head_logits(cfg, params, x[:, 0]), meta.temperature, rng)
     tok = jnp.where(mask, tok, 0)[:, None]
     new_pos = pos + mask.astype(pos.dtype)
     new_tokf = jnp.where(mask[:, None], tok, tokf)
-    return (tok,) + tuple(_flatten_cache(new_caches)) + (new_pos, new_tokf)
+    new_leaves = [old.at[0].add(new.sum(axis=0)) if ax == COUNTER_AXIS
+                  else new
+                  for old, new, ax in zip(cache_leaves,
+                                          _flatten_cache(new_caches),
+                                          meta.batch_axes)]
+    return (tok,) + tuple(new_leaves) + (new_pos, new_tokf)
 
 
 # --------------------------------------------------------------------------

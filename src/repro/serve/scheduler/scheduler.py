@@ -51,7 +51,7 @@ class ContinuousBatchingScheduler:
                  num_blocks: Optional[int] = None,
                  steady_state: int = 8, steady_probe: int = 128,
                  clock: Callable[[], float] = time.perf_counter):
-        pool_ops.check_supported(cfg)
+        pool_ops.check_supported(cfg, page_size or 0)
         self.cfg = cfg
         self.max_len = max_len
         self.temperature = temperature

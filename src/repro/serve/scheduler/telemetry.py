@@ -16,6 +16,8 @@ from __future__ import annotations
 import time
 from typing import Callable, Optional
 
+import numpy as np
+
 from repro.core.events import EventStream
 from repro.core.events import types as T
 from repro.serve.scheduler import pool_ops
@@ -50,16 +52,35 @@ def merged_stats(sch) -> dict:
     already holds the engine counters under co-execution), the callback /
     pool gauges, the decode step's appended cache leaves (set when it is
     traced), the process's GC pauses and compiles since the stream
-    was made, and the engine phase."""
+    was made, and the engine phase.  A stack with dropless expert blocks
+    adds ``experts_held`` (set when the decode step is traced),
+    ``expert_tokens`` (routed pairs of real tokens per held expert, decode
+    and prefill, read from the pool's device counter here and nowhere
+    else) and ``expert_dropped`` (0: the layer has no capacity)."""
     out = dict(sch.sched_stats)
     out["callbacks_delivered"] = sch.callbacks.delivered
     out["peak_resident_tokens"] = sch.pool.peak_resident_tokens
     out["kv_append_leaves"] = pool_ops.kv_append_leaves(sch._mid)
+    out.update(expert_counters(sch))
     out.update(sch.events.runtime_counters())
     if sch.use_terra:
         out.update(sch._tf.stats)
         out["phase"] = sch._tf.phase
     return out
+
+
+def expert_counters(sch) -> dict:
+    """The pool's expert counter, fetched from the device here; {} for a
+    stack without dropless expert blocks."""
+    meta = pool_ops.pool_meta(sch._mid)
+    if pool_ops.COUNTER_AXIS not in meta.batch_axes:
+        return {}
+    i = meta.batch_axes.index(pool_ops.COUNTER_AXIS)
+    leaf = (sch._tf.engine.variable_value(sch._cache_vars[i])
+            if sch.use_terra else sch._cache_leaves[i])
+    return {"experts_held": meta.trace.get("experts_held", 0),
+            "expert_tokens": np.asarray(leaf).sum(axis=0).tolist(),
+            "expert_dropped": 0}
 
 
 def request_submit(es: EventStream, req, rid: int) -> None:

@@ -5,7 +5,8 @@ The TPU compiler is installed even where no chip is attached: a described
 ``v5e:2x2`` topology lets XLA and Mosaic compile for the chip, and refuse
 what the chip would refuse (unaligned blocks, too much VMEM), which the
 interpret-mode tests in test_kernels.py cannot see.  Shapes are
-granite-3-2b's (32 query heads, 8 KV heads, head_dim 64, d_model 2048).
+granite-3-2b's (32 query heads, 8 KV heads, head_dim 64, d_model 2048),
+and deepseek-v2-lite's for its latent decode step.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every xdist worker
@@ -103,28 +104,35 @@ def _slot_decode(sharding, donate: bool):
     granite-3-2b widths (``LAYERS`` layers); returns (compiled, cache
     leaf shapes)."""
     cfg = dataclasses.replace(get_config("granite-3-2b"), n_layers=LAYERS)
+    compiled, shapes, mid = _compile_decode(sharding, cfg, SLOTS, donate)
+    assert pool_ops.kv_append_leaves(mid) == len(shapes) == 2
+    return compiled, shapes
+
+
+def _compile_decode(sharding, cfg, slots: int, donate: bool):
+    """Compile ``serve.slot_decode`` over a dense ``slots`` x 2048 pool;
+    returns (compiled, cache leaf shapes, pool meta id)."""
 
     def spec(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
     p_leaves, p_def = jax.tree_util.tree_flatten(M.abstract_params(cfg))
     c_leaves = jax.eval_shape(
-        lambda: pool_ops.build_pool_cache(cfg, SLOTS, POOL_LEN)[0])
+        lambda: pool_ops.build_pool_cache(cfg, slots, POOL_LEN)[0])
     _, c_def, axes, paged = pool_ops.build_pool_cache(cfg, 1, 8)
     mid = pool_ops.register_pool_meta(cfg, p_def, c_def, axes, 0.0,
                                       POOL_LEN, 0, 0, paged)
     attrs = dict(_meta=mid, _n_params=len(p_leaves),
                  _n_cache=len(c_leaves), _has_rng=False)
     state = [spec(x) for x in p_leaves + c_leaves] + [
-        _spec(sharding, SLOTS, dtype=jnp.int32),
-        _spec(sharding, SLOTS, 1, dtype=jnp.int32)]
+        _spec(sharding, slots, dtype=jnp.int32),
+        _spec(sharding, slots, 1, dtype=jnp.int32)]
     donated = (tuple(range(len(p_leaves), len(state))) if donate else ())
     step = jax.jit(pool_ops.program("serve.slot_decode"),
                    static_argnames=tuple(attrs), donate_argnums=donated)
-    compiled = step.lower(*state, _spec(sharding, SLOTS, dtype=jnp.bool_),
+    compiled = step.lower(*state, _spec(sharding, slots, dtype=jnp.bool_),
                           **attrs).compile()
-    assert pool_ops.kv_append_leaves(mid) == len(c_leaves) == 2
-    return compiled, [tuple(x.shape) for x in c_leaves]
+    return compiled, [tuple(x.shape) for x in c_leaves], mid
 
 
 def _copied_shapes(text: str):
@@ -149,3 +157,39 @@ def test_dense_slot_decode_appends_without_relayout(one_chip, donate):
     else:
         # the step may not write its input: one input-to-output copy each
         assert len(whole) <= len(leaf_shapes), whole
+
+
+# --------------------------------------------------------------------------
+# DeepSeek-V2's absorbed latent decode: appends its latent rows in place
+# --------------------------------------------------------------------------
+
+MLA_SLOTS = 32
+
+
+@pytest.mark.parametrize("donate", [False, True],
+                         ids=["co-execution", "donated"])
+def test_latent_decode_appends_without_relayout(one_chip, donate):
+    """deepseek-v2-lite at full width (the leading dense layer, two expert
+    layers holding 8 of 64 experts), a dense 32 x 2048 pool: the step
+    appends one latent row per slot to the leading leaf and the stacked
+    leaf, and copies no layer's slice of the latent cache."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), n_layers=3,
+                              experts_held=8)
+    compiled, shapes, mid = _compile_decode(one_chip, cfg, MLA_SLOTS,
+                                            donate)
+    assert pool_ops.kv_append_leaves(mid) == 2
+    assert pool_ops.pool_meta(mid).trace["experts_held"] == 8
+    stacked = (2, MLA_SLOTS, POOL_LEN, 576)
+    assert stacked in shapes and stacked[1:] in shapes
+    copies = _copied_shapes(compiled.as_text())
+    # a stacked layer's slice (1, ...) is never copied; (32, 2048, 576) is
+    # both a layer's slice and the leading block's whole leaf
+    assert (1,) + stacked[1:] not in copies, copies
+    whole = [c for c in copies if c in shapes]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if donate:
+        assert not whole and temp < 4 * MIB, (whole, temp / MIB)
+    else:
+        # the step may not write its input: one input-to-output copy each
+        assert len(whole) <= len(shapes), whole
+        assert temp < 8 * MIB, temp / MIB
