@@ -203,7 +203,7 @@ class FamilyManager:
         keys = set()
         for fam in self.families.values():
             if fam.gp is not None:
-                keys.update(sp.signature for sp in fam.gp.seg_progs)
+                keys.update(fam.gp.signatures())
         return keys
 
     def retain_live(self) -> None:

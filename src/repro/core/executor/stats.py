@@ -18,6 +18,7 @@ def init_stats() -> Dict[str, Any]:
         "graph_versions": 0, "segments_dispatched": 0,
         "segments_recompiled": 0, "segment_cache_hits": 0,
         "donated_bytes": 0,
+        "steady_donated_iters": 0,  # steady iterations that donated (§12)
         # hot-path counters (DESIGN.md §4.4, benchmarks/bench_hotpath)
         "dispatch_time": 0.0,       # Python-thread time in dispatch
         "feeds_defaulted": 0,       # zeros substituted for missing feeds

@@ -16,6 +16,16 @@ DispatchPlan — the user fn is **not executed** and no per-op validation
 runs.  Outputs come back as placeholder tensors carrying only a fetch
 future.
 
+A steady iteration takes no snapshot (there is no walker to diverge),
+and the hold on the last walker iteration's snapshot is cut before its
+closure is submitted, so the variable store is the only owner of what
+the segment rewrites.  It therefore dispatches the segment's donating
+form (``GraphProgram.steady_segment``): each variable the segment both
+reads and rewrites is donated and written in place, where a walker
+iteration, which keeps its iteration-start buffers for rollback, copies
+(DESIGN.md §4.2).  ``variable_value`` hands out a private copy of a
+variable the live plan donates.
+
 "Slower never wrong" is kept by construction where possible and by
 probing where not: any structural miss (arg treedef / shape / dtype /
 baked-constant change, variable-aval digest change, GraphProgram
@@ -31,12 +41,14 @@ the next probe.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 import warnings
 from concurrent.futures import Future
 from typing import Any, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.events import emit as ev
 from repro.core.tensor import TerraTensor
@@ -56,7 +68,8 @@ class SteadyPlan:
     executing the skeleton: the feed wiring (arg-leaf index per DispatchPlan
     feed key), the argument validity signature, and the output spec."""
     gp: Any                         # GraphProgram identity guard
-    sp: Any                         # its single SegProg
+    sp: Any                         # its single SegProg; at entry, the
+    #                                 donating form (gp.steady_segment)
     feed_slots: Tuple[int, ...]     # leaf index per plan.feed_keys entry
     in_treedef: Any
     leaf_sigs: Tuple                # ("t", shape, dtype) | ("c", baked value)
@@ -65,6 +78,7 @@ class SteadyPlan:
     out_specs: Tuple                # ((uid, oi), aval) per output leaf
     last_leaves: Optional[List[Any]] = None    # identity fast path
     count: int = 0                  # steady calls, drives probe cadence
+    donated_bytes: int = 0          # bytes the donating form donates a call
 
 
 # ---------------------------------------------------------------------------
@@ -160,6 +174,10 @@ def observe(eng, args, kwargs, out) -> None:
         fam.steady.last_leaves = plan.last_leaves
         return
     if fam.steady_streak >= threshold:
+        plan.sp = sp = eng.gp.steady_segment(eng.seg_cache)
+        plan.donated_bytes = sum(
+            math.prod(a.shape) * jnp.dtype(a.dtype).itemsize
+            for a in (eng.gp.var_avals[v] for v in sp.don_var_ids))
         fam.steady = plan
         eng.stats["steady_entries"] += 1
         ev.steady_enter(eng.events, eng.iter_id, fam.key)
@@ -191,8 +209,8 @@ def try_steady(eng, args, kwargs):
     :data:`MISS` to run the full walker path."""
     fam = eng.family
     plan = fam.steady if fam is not None else None
-    if plan is None:
-        return MISS
+    if plan is None or eng.mode != SKELETON:
+        return MISS                 # (a replay left the engine tracing)
     if plan.gp is not eng.gp:
         # graph regenerated since capture (growth, pass-token change):
         # the cached DispatchPlan is stale — drop and re-earn the streak
@@ -236,22 +254,27 @@ def try_steady(eng, args, kwargs):
 
 def _dispatch(eng, plan: SteadyPlan, leaves):
     """Mirror of SegmentDispatcher.dispatch_through for one pre-validated
-    segment: array fills from the DispatchPlan, fenced submit, no walker."""
+    segment: array fills from the DispatchPlan, fenced submit, no walker.
+    The segment's donating form writes the rewritten variables in place."""
     t0 = time.perf_counter()
     store, stats = eng.store, eng.stats
     buffers = store.buffers
     sp = plan.sp
     dp = sp.plan
+    donated_bytes = plan.donated_bytes
     stage = _walker_mod._STAGE_FEED or _walker_mod._feed_stager()
     feeds = tuple(stage(leaves[li]) for li in plan.feed_slots)
     futures = {k: Future() for k in dp.fetch_keys}
+    # ownership before donation: no snapshot may still reference a buffer
+    # the closure donates
+    eng.cut_snapshot_hold()
 
     def run():
         don_in = tuple(store.read(v) for v in dp.don_var_ids)
         keep_in = tuple(store.read(v) for v in dp.keep_var_ids)
         if don_in:
-            stats["donated_bytes"] += sum(
-                int(getattr(b, "nbytes", 0)) for b in don_in)
+            stats["donated_bytes"] += donated_bytes
+            stats["steady_donated_iters"] += 1
         try:
             with warnings.catch_warnings():
                 warnings.filterwarnings(
@@ -276,7 +299,6 @@ def _dispatch(eng, plan: SteadyPlan, leaves):
     # iteration read as stale (their values arrive through ``_future``) and
     # a later walker iteration starts from a clean binding map
     eng.iter_id += 1
-    eng.cut_snapshot_hold()
     eng._var_binding = {}
     stats["iterations"] += 1
     stats["steady_iters"] += 1

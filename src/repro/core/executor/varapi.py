@@ -102,12 +102,22 @@ class VariableOps:
         # block only on this variable's last pending writer (not the queue)
         self._await_fence(self.store.write_fence(var.var_id))
         val = self.store.buffers[var.var_id]
-        if (self._iter_open and self.mode == SKELETON and self.gp is not None
-                and var.var_id in self.gp.donatable_var_ids):
-            # a later segment of this iteration may donate this buffer;
-            # hand the caller a private copy (DESIGN.md §4.2)
+        if self._may_donate(var.var_id):
+            # a later dispatch may donate this buffer: hand the caller a
+            # private copy (DESIGN.md §4.2)
             val = jnp.array(val)
         return val
+
+    def _may_donate(self, var_id: int) -> bool:
+        """Whether a dispatch still to come may donate the variable's
+        current buffer: a later segment of the open walker iteration, or
+        the live steady plan, which donates what it rewrites (§12)."""
+        if self.mode != SKELETON or self.gp is None:
+            return False
+        if self._iter_open and var_id in self.gp.donatable_var_ids:
+            return True
+        plan = self.family.steady if self.family is not None else None
+        return plan is not None and var_id in plan.sp.don_var_ids
 
     def variable_read_ref(self, var: Variable):
         return VarRef(var.var_id)
@@ -127,7 +137,9 @@ class VariableOps:
         # variable only; rebinds between iterations no longer serialize
         # behind the whole previous iteration's queue
         self._await_fence(self.store.use_fence(var.var_id))
-        value = jnp.asarray(value)
+        # the store must own a buffer that a later dispatch donates
+        value = (jnp.array(value) if self._may_donate(var.var_id)
+                 else jnp.asarray(value))
         self.store.put(var.var_id, value)
         new_aval = Aval.of(value)
         if new_aval != var.aval:

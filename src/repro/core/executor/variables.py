@@ -11,9 +11,12 @@ Snapshot/restore implements the divergence-cancellation contract
 ``snapshot_into`` *on the runner thread* — after any still-pending work from
 the previous iteration, so the snapshot sees committed state — and on
 divergence the whole store is rolled back to that snapshot after a drain.
-Snapshots hold buffer *references*, not copies; this is what makes
-iteration-start buffers ineligible for donation (DESIGN.md §4.2) — donating
-one would delete the only rollback copy.
+Snapshots hold buffer *references*, not copies; this is why a walker
+iteration never donates an iteration-start buffer (DESIGN.md §4.2) —
+donating one would delete the only rollback copy.  A steady-state
+iteration (executor/steady.py, §12) takes no snapshot and runs after the
+last snapshot's hold is cut, so the store is the only owner of what it
+rewrites, and it donates those buffers.
 
 Per-value readiness (DESIGN.md §4.4): dispatchers register, per variable,
 the GraphRunner sequence number of the last submitted closure that reads or

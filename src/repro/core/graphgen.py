@@ -58,8 +58,9 @@ class SegProg:
     fetch_keys: List[Key]
     fn: Any = None                   # jitted callable
     # donation split of var_reads: ``don_var_ids`` buffers are donated to
-    # XLA (safe only for intermediates produced earlier in the same
-    # iteration — see _analyze_donation / DESIGN.md §4.2)
+    # XLA (on the walker path only intermediates produced earlier in the
+    # same iteration — _analyze_donation; a steady iteration's form donates
+    # what it rewrites — steady_segment; DESIGN.md §4.2, §12)
     don_var_ids: List[int] = dataclasses.field(default_factory=list)
     keep_var_ids: List[int] = dataclasses.field(default_factory=list)
     signature: Any = None            # structural key for the segment cache
@@ -211,6 +212,8 @@ class GraphProgram:
             self.seg_progs.append(sp)
 
         # ---- donation analysis + compilation (through the segment cache) --
+        self.jit_each = jit_each
+        self._steady_sp: Optional[SegProg] = None   # steady_segment()
         self._analyze_donation()
         self.donatable_var_ids = {v for sp in self.seg_progs
                                   for v in sp.don_var_ids}
@@ -231,31 +234,7 @@ class GraphProgram:
                 tuple(sp.var_writes), tuple(sp.carries_in),
                 tuple(sp.carries_out), tuple(sp.fetch_keys), kernel_ops)
         for sp in self.seg_progs:
-            if seg_cache is not None:
-                from repro.core.executor.segment_cache import \
-                    segment_signature
-                # signatures are computed strictly POST-pass (over the
-                # optimized graph + dead/alias/fold state), so a segment
-                # whose optimized form is unchanged is a cache hit even
-                # when coalescing or folding reshaped its neighbours
-                sp.signature = (jit_each, segment_signature(self, sp))
-                persist = getattr(seg_cache, "persist", None)
-                if persist is not None:
-                    # warm boot (DESIGN.md §14): consult the on-disk AOT
-                    # executable before compiling; a fresh compile is
-                    # serialized back into the store
-                    sp.fn = seg_cache.get_or_build(
-                        sp.signature,
-                        lambda sp=sp: persist.build_segment(
-                            self, sp, jit_each),
-                        loader=lambda sp=sp: persist.load_segment(
-                            self, sp, jit_each))
-                else:
-                    sp.fn = seg_cache.get_or_build(
-                        sp.signature,
-                        lambda sp=sp: self._compile_segment(sp, jit_each))
-            else:
-                sp.fn = self._compile_segment(sp, jit_each)
+            self._build_fn(sp, seg_cache)
 
         # Walker-facing boundary set (optimized sync flags) and the value
         # keys dispatched segments publish to iter_env (chain dispatch
@@ -300,17 +279,39 @@ class GraphProgram:
                     prods[vid] = None
         return prods
 
+    def _retained_writes(self, sp: SegProg) -> Dict[int, bool]:
+        """vid -> whether the buffer this segment leaves in the store for
+        v escapes the store: its producer is also a fetch output or a
+        carry, a switch phi or an alias, or it backs two variables.  An
+        escaping buffer is retained by every later reader and never
+        donated."""
+        prods = self._final_var_products(sp)
+        seen_products: Dict[Key, int] = {}
+        escaped = set(sp.fetch_keys) | set(sp.carries_out)
+        retained: Dict[int, bool] = {}
+        for v in sp.var_writes:
+            p = prods.get(v)
+            r = p is None or p in escaped
+            if p is not None:
+                if p in seen_products:          # two vars share one buffer
+                    r = True
+                    retained[seen_products[p]] = True
+                seen_products[p] = v
+            retained[v] = r
+        return retained
+
     def _analyze_donation(self) -> None:
-        """Static per-segment donation eligibility for variable buffers.
+        """Static per-segment donation eligibility for variable buffers on
+        the walker path.
 
         A segment may donate ``var_in[v]`` only when (a) it also writes v
         (so XLA has an output to alias the buffer into), and (b) the buffer
         it will read is an *intermediate* of this same iteration — produced
-        by an earlier segment — whose sole owner is the variable store.
-        Iteration-start buffers are never donatable: the divergence snapshot
-        holds them for rollback.  A producing value that is also a fetch
-        output or a carry (or a switch phi, or shared by two variables)
-        escapes the store, so it is retained and never donated either.
+        by an earlier segment — whose sole owner is the variable store
+        (``_retained_writes``).  A walker iteration never donates an
+        iteration-start buffer: its divergence snapshot holds them for
+        rollback.  A steady iteration takes no snapshot and donates what it
+        rewrites (``steady_segment``, DESIGN.md §12).
         """
         # vid -> retained?  (present only once some segment wrote the vid)
         last_write: Dict[int, bool] = {}
@@ -321,19 +322,66 @@ class GraphProgram:
             sp.don_var_ids = don
             don_set = set(don)
             sp.keep_var_ids = [v for v in sp.var_reads if v not in don_set]
+            last_write.update(self._retained_writes(sp))
 
-            prods = self._final_var_products(sp)
-            seen_products: Dict[Key, int] = {}
-            escaped = set(sp.fetch_keys) | set(sp.carries_out)
-            for v in sp.var_writes:
-                p = prods.get(v)
-                retained = p is None or p in escaped
-                if p is not None:
-                    if p in seen_products:      # two vars share one buffer
-                        retained = True
-                        last_write[seen_products[p]] = True
-                    seen_products[p] = v
-                last_write[v] = retained
+    def steady_segment(self, seg_cache=None) -> SegProg:
+        """The single segment's form for steady-state iterations
+        (executor/steady.py, DESIGN.md §12), built once per program.
+
+        A steady iteration takes no snapshot, and the hold on the last
+        walker iteration's snapshot is cut before it is submitted, so the
+        store is the only owner of each buffer the previous run of this
+        segment left in it.  This form donates every variable the segment
+        rewrites whose buffer does not escape (``_retained_writes``) and
+        retains the rest; the walker's form of the same segment is left as
+        it is.  The donation split is part of its cache signature."""
+        ssp = self._steady_sp
+        if ssp is None:
+            (sp,) = self.seg_progs
+            retained = self._retained_writes(sp)
+            don = [v for v in sp.var_reads if retained.get(v) is False]
+            keep = [v for v in sp.var_reads if retained.get(v) is not False]
+            ssp = dataclasses.replace(
+                sp, don_var_ids=don, keep_var_ids=keep, fn=None,
+                signature=None,
+                plan=dataclasses.replace(sp.plan, don_var_ids=tuple(don),
+                                         keep_var_ids=tuple(keep)))
+            self._build_fn(ssp, seg_cache)
+            self._steady_sp = ssp
+        return ssp
+
+    def signatures(self) -> set:
+        """Cache keys of every compiled form this program holds: each
+        segment's, and the steady form once built."""
+        keys = {sp.signature for sp in self.seg_progs}
+        if self._steady_sp is not None:
+            keys.add(self._steady_sp.signature)
+        return keys
+
+    def _build_fn(self, sp: SegProg, seg_cache) -> None:
+        """Set ``sp.fn``: through the segment cache when there is one."""
+        jit_each = self.jit_each
+        if seg_cache is None:
+            sp.fn = self._compile_segment(sp, jit_each)
+            return
+        from repro.core.executor.segment_cache import segment_signature
+        # signatures are computed strictly POST-pass (over the optimized
+        # graph + dead/alias/fold state), so a segment whose optimized form
+        # is unchanged is a cache hit even when coalescing or folding
+        # reshaped its neighbours
+        sp.signature = (jit_each, segment_signature(self, sp))
+        persist = getattr(seg_cache, "persist", None)
+        if persist is not None:
+            # warm boot (DESIGN.md §14): consult the on-disk AOT executable
+            # before compiling; a fresh compile is serialized back into the
+            # store
+            sp.fn = seg_cache.get_or_build(
+                sp.signature,
+                lambda: persist.build_segment(self, sp, jit_each),
+                loader=lambda: persist.load_segment(self, sp, jit_each))
+        else:
+            sp.fn = seg_cache.get_or_build(
+                sp.signature, lambda: self._compile_segment(sp, jit_each))
 
     # ------------------------------------------------------------------
     def _n_out(self, n: TGNode) -> int:

@@ -4,6 +4,7 @@ containment, and the feeds_defaulted / runner-time stat exports."""
 
 import threading
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -97,6 +98,78 @@ def test_variable_value_mid_iteration_under_donation():
     # the private copies were not clobbered by the donation
     for j, i in enumerate(range(4, 7)):
         np.testing.assert_allclose(seen[j], np.full(64, 6.0 * i))
+    step.close()
+
+
+def test_probe_diverging_after_a_donated_steady_step_rolls_back():
+    """A forced walker probe that diverges right after a donated steady
+    step restores the snapshot it took of the donated step's outputs,
+    replays, and finishes with exact values ("slower, never wrong")."""
+    v = Variable(np.zeros(4, np.float32), "don_probe_v")
+    late = [False]
+
+    @function(optimize="safe", steady_state=2, steady_probe=4)
+    def step(x):
+        y = ops.mul(x, 2.0)
+        v.assign(ops.add(v.read(), y))
+        # a Python value the steady plan cannot see: only a probe can
+        return ops.add(y, 10.0) if late[0] else ops.sub(y, 1.0)
+
+    one = np.ones(4, np.float32)
+    eng, calls = step.engine, 0
+    while True:
+        before = step.stats["steady_iters"]
+        np.testing.assert_allclose(np.asarray(step(one)), np.full(4, 1.0))
+        calls += 1
+        plan = eng.family.steady
+        if (step.stats["steady_iters"] > before
+                and (plan.count + 1) % 4 == 0):
+            break                       # a steady step; the next probes
+        assert calls < 40
+    step.wait()
+    assert step.stats["steady_donated_iters"] > 0
+    replays = step.stats["replays"]
+    late[0] = True
+    np.testing.assert_allclose(np.asarray(step(one)), np.full(4, 12.0))
+    calls += 1
+    assert step.stats["replays"] == replays + 1
+    assert eng.family.steady is None            # the plan was dropped
+    np.testing.assert_allclose(np.asarray(eng.variable_value(v)),
+                               np.full(4, 2.0 * calls))
+    for _ in range(8):                          # the new branch, walked
+        np.testing.assert_allclose(np.asarray(step(one)), np.full(4, 12.0))
+        calls += 1
+    step.wait()
+    np.testing.assert_allclose(np.asarray(eng.variable_value(v)),
+                               np.full(4, 2.0 * calls))
+    step.close()
+
+
+def test_assign_outside_a_call_keeps_the_callers_array():
+    """An out-of-band ``Variable.assign`` of a variable the live steady
+    plan donates stores a copy: the next donated step consumes the
+    store's buffer, never the caller's array."""
+    v = Variable(np.zeros(4, np.float32), "don_assign_v")
+
+    @function(optimize="safe", steady_state=2, steady_probe=100)
+    def step(x):
+        v.assign(ops.add(v.read(), x))
+        return ops.mul(x, 1.0)
+
+    one = np.ones(4, np.float32)
+    for _ in range(6):
+        np.asarray(step(one))
+    step.wait()
+    assert step.stats["steady_donated_iters"] > 0
+    mine = jnp.full(4, 5.0, jnp.float32)
+    v.assign(mine)
+    for _ in range(3):
+        np.asarray(step(one))
+    step.wait()
+    assert step.stats["steady_exits"] == 0
+    assert not mine.is_deleted()
+    np.testing.assert_allclose(np.asarray(mine), np.full(4, 5.0))
+    np.testing.assert_allclose(np.asarray(v.value()), np.full(4, 8.0))
     step.close()
 
 

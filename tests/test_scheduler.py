@@ -1,15 +1,21 @@
 """Continuous-batching scheduler tests (serve/scheduler/, DESIGN.md §11):
 slot alloc/free across retire-and-admit, mid-decode admission token
 correctness, variable-length bucketed prefill, streaming callback
-ordering, Terra-vs-baseline equality, and the lock-step run_batch
-satellite fixes (ragged rejection, latency fields, live-row budget)."""
+ordering, Terra-vs-baseline equality, the lock-step run_batch
+satellite fixes (ragged rejection, latency fields, live-row budget), and
+the steady state's donated decode step (DESIGN.md §12)."""
+
+import dataclasses
+import re
 
 import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs import smoke_config
+from repro.core.persist.aot import segment_specs
 from repro.models import model as M
 from repro.serve.engine import Request, ServingEngine
 from repro.serve.scheduler import (ContinuousBatchingScheduler, SlotPool,
@@ -323,3 +329,188 @@ def test_step_programs_are_named_after_their_role(llama):
     sch.close()
     assert "module @jit_serve.slot_decode" in decode
     assert "module @jit_serve.slot_prefill" in prefill
+
+
+# ==========================================================================
+# the steady state donates the pool (DESIGN.md §12)
+# ==========================================================================
+
+PROBE = 4                   # a forced walker probe every 4th steady call
+LENS, MAX_NEWS = [5, 8, 13, 8, 5, 11], [20, 25, 17, 30, 22, 9]
+
+
+def _pool_vars(sch):
+    return sch._cache_vars + [sch._pos_var, sch._tokf_var]
+
+
+def _pool_bytes(sch) -> int:
+    return sum(int(np.prod(v.aval.shape)) * jnp.dtype(v.aval.dtype).itemsize
+               for v in _pool_vars(sch))
+
+
+def _steady_scheduler(cfg, params, **kw):
+    return ContinuousBatchingScheduler(cfg, params, max_slots=3,
+                                       max_len=MAX_LEN, steady_probe=PROBE,
+                                       **kw)
+
+
+def test_steady_decode_donates_the_pool_every_step(llama):
+    """Once steady, each decode step donates the whole pool: one more
+    ``steady_donated_iters`` and the pool's bytes more ``donated_bytes``.
+    Walker probes and prefill splices raise neither."""
+    cfg, params = llama
+    sch = _steady_scheduler(cfg, params)
+    for r in make_requests(cfg, LENS, MAX_NEWS):
+        sch.submit(r)
+    pool, st = _pool_bytes(sch), sch._tf.stats
+    steady = probes = 0
+    while len(sch.queue) or sch.pool.active_count:
+        before = dict(st)
+        sch.run(max_steps=1)            # one plan, harvested and drained
+        d = {k: st[k] - before[k] for k in
+             ("steady_iters", "steady_donated_iters", "donated_bytes",
+              "iterations")}
+        assert d["steady_donated_iters"] == d["steady_iters"], d
+        assert d["donated_bytes"] == d["steady_iters"] * pool, d
+        steady += d["steady_iters"]
+        if st["steady_entries"] and d["iterations"] > d["steady_iters"]:
+            probes += 1                 # a walker decode after entry
+    assert steady >= 3 * PROBE and probes >= 2, (steady, probes)
+    assert st["steady_exits"] == 0 and st["replays"] == 0
+    sch.close()
+
+
+@pytest.mark.parametrize("arch,dtype", [("granite-3-2b", None),
+                                        ("deepseek-v2-lite", "float32")],
+                         ids=["dense", "mla-moe"])
+def test_donated_steady_decode_serves_the_jitted_tokens(arch, dtype):
+    """Greedy tokens of the co-executed scheduler, whose steady decode
+    steps donate the pool, equal the hand-jitted scheduler's over a run
+    that crosses several probes and prefill splices.  The MoE runs in
+    float32: under bfloat16 a near tie between two experts may route
+    differently in two compiled forms of the same step."""
+    cfg = smoke_config(arch)
+    if dtype:
+        cfg = dataclasses.replace(cfg, dtype=dtype, param_dtype=dtype)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    a = make_requests(cfg, LENS, MAX_NEWS)
+    b = make_requests(cfg, LENS, MAX_NEWS)
+    s1 = _steady_scheduler(cfg, params)
+    s2 = _steady_scheduler(cfg, params, use_terra=False)
+    s1.serve(a)
+    s2.serve(b)
+    assert [r.out_tokens for r in a] == [r.out_tokens for r in b]
+    st = s1.stats
+    assert st["steady_donated_iters"] == st["steady_iters"] >= 3 * PROBE
+    # walker iterations after the warm-up's 8: the probes
+    assert st["iterations"] - st["steady_iters"] >= 8 + 2
+    assert st["prefill_steps"] >= 3 and st["replays"] == 0
+    s1.close()
+    s2.close()
+
+
+def test_reads_between_donated_steps_stay_readable(llama):
+    """``Variable.read()`` and ``variable_value`` between steady steps
+    hand out private copies: the next donated step consumes the store's
+    buffer, not the reader's array."""
+    cfg, params = llama
+    sch = _steady_scheduler(cfg, params)
+    for r in make_requests(cfg, LENS, MAX_NEWS):
+        sch.submit(r)
+    sch.run(max_steps=14)
+    st, eng = sch._tf.stats, sch._tf.engine
+    assert st["steady_donated_iters"] > 0
+    checked = 0
+    while len(sch.queue) or sch.pool.active_count:
+        held = [v.read() for v in sch._cache_vars]
+        held.append(eng.variable_value(sch._pos_var))
+        saved = [np.asarray(x).copy() for x in held]
+        live = [eng.store.buffers[v.var_id] for v in _pool_vars(sch)]
+        before = dict(st)
+        sch.run(max_steps=1)
+        for x, want in zip(held, saved):
+            assert not x.is_deleted()
+            np.testing.assert_array_equal(np.asarray(x), want)
+        if (st["steady_donated_iters"] > before["steady_donated_iters"]
+                and st["prefill_steps"] == before["prefill_steps"]):
+            # the step read the store's buffers and consumed them in place
+            assert all(b.is_deleted() for b in live)
+            checked += 1
+    assert checked >= 3
+    sch.close()
+
+
+def test_checkpoint_after_donated_steps_serves_the_same_tokens(llama,
+                                                               tmp_path):
+    """A checkpoint taken after donated steps restores a scheduler that
+    finishes every request with the tokens of an uninterrupted run, and
+    the donor continues to the same tokens."""
+    cfg, params = llama
+    ref = make_requests(cfg, LENS, MAX_NEWS)
+    s0 = _steady_scheduler(cfg, params)
+    s0.serve(ref)
+    s0.close()
+    want = [r.out_tokens for r in ref]
+
+    reqs = make_requests(cfg, LENS, MAX_NEWS)
+    sch = _steady_scheduler(cfg, params)
+    for r in reqs:
+        sch.submit(r)
+    sch.run(max_steps=20)
+    assert sch._tf.stats["steady_donated_iters"] > 0
+    assert sch.pool.active_count > 0
+    path = str(tmp_path / "ckpt")
+    sch.checkpoint(path)
+    partial = {r.rid: list(r.out_tokens or []) for r in reqs}
+    sch.run()
+    assert [r.out_tokens for r in reqs] == want
+    sch.close()
+
+    rs = ContinuousBatchingScheduler.restore(path, cfg, params)
+    tracked = {r.rid: r for _, r in rs.pool.active_items()}
+    tracked.update({r.rid: r for r in rs.queue._queue})
+    rs.run()
+    assert rs._tf.stats["steady_donated_iters"] > 0
+    full = dict(partial)
+    full.update({rid: r.out_tokens for rid, r in tracked.items()})
+    assert [full[r.rid] for r in reqs] == want
+    rs.close()
+
+
+def _aliases(text: str):
+    """(output index, parameter) pairs of a compiled module's
+    ``input_output_alias``."""
+    head = text.splitlines()[0]
+    return {(int(o), int(p)) for o, p in
+            re.findall(r"\{(\d+)\}: \((\d+), \{\}", head)}
+
+
+def test_steady_form_aliases_every_pool_leaf(llama):
+    """The steady plan's compiled segment donates every pool leaf into the
+    output it rewrites; the walker's form of the same segment keeps its
+    retained split and aliases nothing."""
+    cfg, params = llama
+    sch = _steady_scheduler(cfg, params)
+    sch.serve(make_requests(cfg, [5, 7], [16, 14]))
+    eng = sch._tf.engine
+    gp, plan = eng.gp, eng.family.steady
+    pool = sorted(v.var_id for v in _pool_vars(sch))
+    weights = {v.var_id for v in sch._param_vars}
+    steady, walker = plan.sp, gp.seg_progs[0]
+    assert list(steady.don_var_ids) == pool == list(steady.var_writes)
+    assert set(steady.keep_var_ids) == weights
+    assert walker.don_var_ids == []
+    assert set(walker.keep_var_ids) == weights | set(pool)
+    assert steady.signature != walker.signature
+    assert {steady.signature, walker.signature} <= gp.signatures()
+    assert plan.donated_bytes == _pool_bytes(sch)
+
+    def compiled(sp):
+        return sp.fn.lower(*segment_specs(gp, sp)).compile().as_text()
+    text = compiled(steady)
+    assert "module jit_serve.slot_decode" in text.splitlines()[0].replace(
+        "HloModule", "module")
+    # var_out[i] is pool[i], and donated argument i is pool[i]
+    assert _aliases(text) == {(i, i) for i in range(len(pool))}
+    assert _aliases(compiled(walker)) == set()
+    sch.close()

@@ -19,15 +19,18 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core.persist.aot import segment_specs
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.rmsnorm import rmsnorm
 from repro.models import model as M
-from repro.serve.scheduler import pool_ops
+from repro.serve.engine import Request
+from repro.serve.scheduler import ContinuousBatchingScheduler, pool_ops
 
 Hq, Hkv, D, DM = 32, 8, 64, 2048        # granite-3-2b
 ROWS, TOKENS = 8, 2048
@@ -157,6 +160,35 @@ def test_dense_slot_decode_appends_without_relayout(one_chip, donate):
     else:
         # the step may not write its input: one input-to-output copy each
         assert len(whole) <= len(leaf_shapes), whole
+
+
+def test_steady_segment_writes_the_pool_in_place(one_chip):
+    """The co-executed decode segment's steady form, which donates every
+    pool leaf (DESIGN.md §12), moves no whole cache leaf on the chip; the
+    walker's form of the same segment copies its input leaves.  The
+    segment is built by a scheduler serving on the CPU (granite widths,
+    ``LAYERS`` layers) and compiled here for the described chip."""
+    cfg = dataclasses.replace(get_config("granite-3-2b"), n_layers=LAYERS)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    sch = ContinuousBatchingScheduler(cfg, params, max_slots=SLOTS,
+                                      max_len=POOL_LEN, prefill_batch_cap=1)
+    sch.serve([Request(prompt=np.arange(8, dtype=np.int32),
+                       max_new_tokens=12, arrival_time=0.0)])
+    eng = sch._tf.engine
+    gp, plan = eng.gp, eng.family.steady
+    leaf = gp.var_avals[sch._cache_vars[0].var_id].shape
+    sch.close()
+
+    def compiled(sp):
+        specs = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip),
+            segment_specs(gp, sp))
+        return gp._compile_segment(sp, jit_each=True).lower(*specs).compile()
+    steady = compiled(plan.sp)
+    assert leaf not in _copied_shapes(steady.as_text())
+    assert steady.memory_analysis().temp_size_in_bytes < 4 * MIB
+    assert leaf in _copied_shapes(compiled(gp.seg_progs[0]).as_text())
 
 
 # --------------------------------------------------------------------------
