@@ -26,17 +26,19 @@ def classify_fulljit(name: str, steps: int = 10):
     return "ok", ""
 
 
-def run_terra(name: str, steps: int = 10):
+def run_terra(name: str, steps: int = 10, optimize=None):
+    """Run the program under ``terra.function``; returns its losses, the
+    phase it ended in and the engine's counters."""
     step, _ = REGISTRY[name]("terra")
-    tf = terra_function(step)
+    tf = terra_function(step, optimize=optimize)
     losses = []
     for i in range(steps):
         l = tf(i)
         losses.append(float(l) if hasattr(l, "__float__") else l)
-    phase = tf.phase
+    tf.wait()
+    phase, stats = tf.phase, dict(tf.engine.stats)
     tf.close()
-    ok = all(np.isfinite(losses))
-    return ok, phase
+    return losses, phase, stats
 
 
 def main():
@@ -44,7 +46,8 @@ def main():
     rows = []
     print("program,terra,fulljit,failure_reason")
     for name in sorted(REGISTRY):
-        t_ok, phase = run_terra(name)
+        losses, _, _ = run_terra(name)
+        t_ok = bool(np.all(np.isfinite(losses)))
         fj_status, fj_detail = classify_fulljit(name)
         expected = NON_CONVERTIBLE.get(name, "")
         reason = expected if fj_status != "ok" else ""

@@ -6,8 +6,7 @@ mixed-length prompts) see examples/serve_continuous.py and
 serve/scheduler/.
 
     PYTHONPATH=src python examples/serve_demo.py --arch llama3-8b
-(the arch's reduced smoke config is served — full configs are exercised
-via the multi-pod dry-run)
+(the arch's reduced smoke config is served)
 """
 
 import argparse

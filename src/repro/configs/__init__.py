@@ -1,5 +1,4 @@
-from repro.configs.base import SHAPES, SMOKE_SHAPE, ModelConfig, ShapeConfig
+from repro.configs.base import ModelConfig
 from repro.configs.registry import ARCHS, get_config, smoke_config
 
-__all__ = ["SHAPES", "SMOKE_SHAPE", "ModelConfig", "ShapeConfig", "ARCHS",
-           "get_config", "smoke_config"]
+__all__ = ["ModelConfig", "ARCHS", "get_config", "smoke_config"]

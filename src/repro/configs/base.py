@@ -96,7 +96,7 @@ class ModelConfig:
     enc_layers: int = 0
 
     # modality frontend stub (audio frames / vision patches): number of
-    # frontend embedding tokens fed by input_specs()
+    # precomputed frontend embedding tokens the caller feeds
     frontend_tokens: int = 0
 
     # compute
@@ -108,11 +108,6 @@ class ModelConfig:
     # remat policy: "full" rematerializes everything; "dots" saves matmul
     # outputs (jax dots_saveable) trading HBM for ~25% less recompute
     remat_policy: str = "full"
-    # unroll every lax.scan (layers, attention blocks, SSD chunks).  Used by
-    # the dry-run cost probes: XLA cost_analysis counts a while-loop body
-    # ONCE regardless of trip count, so loops must be unrolled for honest
-    # FLOP/byte/collective accounting (launch/dryrun.py).
-    unroll: bool = False
 
     def __post_init__(self):
         if self.head_dim == 0:
@@ -141,22 +136,3 @@ class ModelConfig:
         assert first + held <= self.n_experts, \
             f"{self.name}: experts {first}..{first + held - 1} held of " \
             f"{self.n_experts}"
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    kind: str            # train | prefill | decode
-    seq_len: int
-    global_batch: int
-
-
-SHAPES = {
-    "train_4k": ShapeConfig("train_4k", "train", 4096, 256),
-    "prefill_32k": ShapeConfig("prefill_32k", "prefill", 32768, 32),
-    "decode_32k": ShapeConfig("decode_32k", "decode", 32768, 128),
-    "long_500k": ShapeConfig("long_500k", "decode", 524288, 1),
-}
-
-# smoke-test shapes (reduced, CPU-friendly)
-SMOKE_SHAPE = ShapeConfig("smoke", "train", 64, 2)

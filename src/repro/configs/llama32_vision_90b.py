@@ -1,6 +1,6 @@
 """llama-3.2-vision-90b [vlm] — cross-attn image layers every 5th layer
 [hf:meta-llama/Llama-3.2-90B-Vision].  The vision tower is a STUB per the
-brief: input_specs() provides precomputed patch embeddings [B, 1600, d]."""
+brief: the caller feeds precomputed patch embeddings [B, 1600, d]."""
 
 from repro.configs.base import ModelConfig
 

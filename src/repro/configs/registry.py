@@ -2,9 +2,7 @@
 
 ``get_config(arch_id)`` returns the exact published configuration;
 ``smoke_config(arch_id)`` returns a reduced config of the same family
-(small width, few layers/experts, tiny vocab) for CPU smoke tests — the
-full configs are exercised only through the dry-run (ShapeDtypeStruct, no
-allocation)."""
+(small width, few layers/experts, tiny vocab) for CPU smoke tests."""
 
 from __future__ import annotations
 
@@ -30,10 +28,6 @@ ARCHS = {
     "whisper-small": whisper_small.CONFIG,
     "recurrentgemma-2b": recurrentgemma_2b.CONFIG,
 }
-
-# archs with a sub-quadratic long-context path: long_500k runs for these
-LONG_CONTEXT_ARCHS = {"mixtral-8x22b", "mamba2-130m", "recurrentgemma-2b"}
-
 
 def get_config(arch: str) -> ModelConfig:
     if arch not in ARCHS:

@@ -1,10 +1,9 @@
 """whisper-small [audio] — enc-dec, conv frontend (stub) [arXiv:2212.04356].
 
 n_layers counts the DECODER layers; enc_layers the encoder.  The conv
-frontend is a stub: input_specs() provides precomputed frame embeddings
+frontend is a stub: the caller feeds precomputed frame embeddings
 [B, 1500, d].  Decoder uses RoPE instead of learned positions (deviation
-noted in DESIGN.md); assigned 32k shapes stress the architecture beyond its
-trained 448 positions but are structurally well-defined.
+noted in DESIGN.md).
 """
 
 from repro.configs.base import ModelConfig

@@ -11,12 +11,11 @@ their own — the four below cover the repo's needs:
   ``data`` dict reproduces the pre-event-layer ``engine.stats`` /
   scheduler counters bit for bit.
 * :class:`TimingProcessor` — per-step and per-segment host-time breakdown
-  (dispatch / fetch-wait / runner occupancy), replacing the benchmarks'
-  private accumulators.
+  (dispatch / fetch-wait / runner occupancy).
 * :class:`RequestTraceProcessor` — one JSON-serializable causal trace per
   serving request (submit → admit → prefill → tokens → retire).
 * :class:`JsonlSink` — buffered JSONL export of the full stream; the
-  artifact the schema validator (schema.py) checks in CI.
+  artifact the schema validator (schema.py) checks.
 * :class:`ListProcessor` — in-memory capture, for tests and ad-hoc
   debugging.
 """
@@ -75,7 +74,7 @@ class TimingProcessor(Processor):
     """Host-overhead breakdown from StepDispatch / StepHarvest /
     SegmentDispatch / RunnerComplete events.
 
-    ``summary()`` yields the numbers bench_serving reports per arm:
+    ``summary()`` yields, per scheduler or engine run:
     total dispatch and fetch-wait seconds (split by step kind), step
     counts, per-step microseconds, and GraphRunner occupancy (exec /
     stall) over the window since construction or the last ``reset()``."""
@@ -148,7 +147,7 @@ class RequestTraceProcessor(Processor):
     return them as JSON-serializable records with the stream clock's
     timestamps.  Events buffer as-is and serialize only on access — an
     emitted event is never mutated afterwards, and per-token dict
-    building would otherwise dominate the tracing cost the bench gates.
+    building would otherwise dominate the tracing cost.
     Retired traces stay available until ``pop()``/``reset()`` so a
     driver can export and drop them incrementally."""
 
@@ -178,7 +177,7 @@ class JsonlSink(Processor):
     per line, in emission order.  The per-event cost is ONE list append —
     an emitted event is never mutated afterwards, so serialization
     (event_to_dict + json.dumps) safely defers to ``flush``/``close``;
-    this is the path the bench's ≤2 % tracing-overhead gate measures."""
+    this keeps the cost of tracing on the hot path to one append."""
 
     def __init__(self, path: str):
         self.path = path

@@ -62,7 +62,7 @@ class TerraEngine(PythonRunnerOps, VariableOps):
                  strict_feeds: bool = True, optimize=None,
                  cache_dir: Optional[str] = None, cache_scope: str = ""):
         # the instrumentation substrate: counters + structured events
-        # (benchmarks: Fig. 6 breakdown, App. F transitions); the full
+        # (the paper's Fig. 6 breakdown, App. F transitions); the full
         # counter registry lives in executor/stats.py
         self.events = EventStream(counters=init_stats())
         self.stats = self.events.counters

@@ -1,8 +1,8 @@
 """Engine counter registry: every stat the TerraEngine exports, in one
-place so the coordinator stays a phase machine and the benchmarks
-(fig6_breakdown, bench_hotpath) have a single source of truth for what
-exists.  Groups follow the perf layers they instrument (DESIGN.md §4, §8,
-§10)."""
+place so the coordinator stays a phase machine and its readers (the
+tests, ``repro.obs``, the chip benchmark's window) have a single source
+of truth for what exists.  Groups follow the perf layers they
+instrument (DESIGN.md §4, §8, §10)."""
 
 from __future__ import annotations
 
@@ -19,7 +19,7 @@ def init_stats() -> Dict[str, Any]:
         "segments_recompiled": 0, "segment_cache_hits": 0,
         "donated_bytes": 0,
         "steady_donated_iters": 0,  # steady iterations that donated (§12)
-        # hot-path counters (DESIGN.md §4.4, benchmarks/bench_hotpath)
+        # hot-path counters (DESIGN.md §4.4, tests/test_hotpath.py)
         "dispatch_time": 0.0,       # Python-thread time in dispatch
         "feeds_defaulted": 0,       # zeros substituted for missing feeds
         "walker_fast_hits": 0,      # ops validated via the stamp path

@@ -7,7 +7,7 @@ Pallas TPU grids execute sequentially, so the scratch accumulator carries
 across the nk steps and is finalized on the last one.  Block shapes are
 MXU-aligned (multiples of 128 on the contracting/lane dims).
 
-This is the substrate's compute hot-spot (prefill_32k / train_4k cells);
+This is the substrate's compute hot-spot (long prefill and training);
 the paper itself has no kernel-level contribution (DESIGN.md §2).
 """
 

@@ -1,8 +1,8 @@
 """Where JAX keeps its persistent compilation cache.
 
-Each entry point (``chip_smoke.py``, ``launch/train.py``, the
-``benchmarks/`` entry points) calls :func:`enable` once before its first
-compile; nothing calls it at import, and tests never do.
+Each entry point (``chip_smoke.py``, ``launch/train.py``,
+``benchmarks/table1_coverage.py``) calls :func:`enable` once before its
+first compile; nothing calls it at import, and tests never do.
 
 * ``$JAX_COMPILATION_CACHE_DIR`` set: JAX reads it on start-up, and no
   other directory is set here.

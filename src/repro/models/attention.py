@@ -1,7 +1,7 @@
 """Attention: GQA / MQA, causal, sliding-window, local, cross; chunked
 memory-efficient XLA implementation (the Pallas flash kernel in
 repro.kernels is the TPU-optimized path; this module is the portable
-reference used by the dry-run and smoke tests).
+XLA path every serving and training step runs).
 
 The chunked implementation scans over query blocks and, within each, over
 key/value blocks with an online-softmax accumulator, so peak memory is
@@ -36,22 +36,9 @@ def _pick_block(s: int, target: int) -> int:
     return max(b, 1)
 
 
-def _scan_or_unroll(f, init, n, unroll):
-    """lax.scan over jnp.arange(n), or an unrolled Python loop (cost probes)."""
-    if not unroll:
-        return jax.lax.scan(f, init, jnp.arange(n))
-    carry, ys = init, []
-    for i in range(n):
-        carry, y = f(carry, i)
-        ys.append(y)
-    out = (jnp.stack(ys) if ys and ys[0] is not None else None)
-    return carry, out
-
-
 def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
                       q_block: int = 512, kv_block: int = 1024,
-                      q_offset=0, kv_valid_len=None, unroll: bool = False,
-                      scale=None):
+                      q_offset=0, kv_valid_len=None, scale=None):
     """q: [B,Sq,Hq,D], k: [B,Skv,Hkv,D], v: [B,Skv,Hkv,Dv] with
     Hq % Hkv == 0; returns [B,Sq,Hq,Dv].
 
@@ -109,11 +96,11 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
         init = (jnp.zeros((B, Hkv, G, qb, Dv), jnp.float32),
                 jnp.full((B, Hkv, G, qb), NEG_INF, jnp.float32),
                 jnp.zeros((B, Hkv, G, qb), jnp.float32))
-        (acc, m, l), _ = _scan_or_unroll(kv_step, init, nk, unroll)
+        (acc, m, l), _ = jax.lax.scan(kv_step, init, jnp.arange(nk))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return None, out.astype(q.dtype)
 
-    _, outs = _scan_or_unroll(q_step, None, nq, unroll)
+    _, outs = jax.lax.scan(q_step, None, jnp.arange(nq))
     # outs: [nq, B, Hkv, G, qb, Dv] -> [B, Sq, Hq, Dv]
     out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq, Hq, Dv)
     return out
@@ -277,14 +264,12 @@ def attention_block(p, x, cfg, *, positions=None, cache=None,
                                 window=window, q_offset=idx,
                                 kv_valid_len=idx + S,
                                 q_block=cfg.q_block,
-                                kv_block=cfg.kv_block,
-                                unroll=cfg.unroll)
+                                kv_block=cfg.kv_block)
     else:
         out = chunked_attention(q, k, v,
                                 causal=causal and cross_states is None,
                                 window=window,
-                                q_block=cfg.q_block, kv_block=cfg.kv_block,
-                                unroll=cfg.unroll)
+                                q_block=cfg.q_block, kv_block=cfg.kv_block)
 
     out = logical(out, "batch", None, "heads", None)
     out = dense(out.reshape(B, S, H * D), p["wo"])

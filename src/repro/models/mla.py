@@ -137,6 +137,6 @@ def mla_attention(p, x, cfg, *, positions, cache=None, causal=True):
         out = chunked_attention(jnp.concatenate([q_nope, q_pe], -1), k, v,
                                 causal=causal, q_offset=q_offset,
                                 kv_valid_len=kv_valid, q_block=cfg.q_block,
-                                kv_block=cfg.kv_block, unroll=cfg.unroll,
+                                kv_block=cfg.kv_block,
                                 scale=softmax_scale(cfg))
     return L.dense(out.reshape(B, S, H * dv), p["wo"]), new_cache

@@ -1,16 +1,14 @@
-"""Model entry points: init, cache management, input specs for every
-(arch × shape) cell, and the serve-path wrappers used by the dry-run and
-the serving engine."""
+"""Model entry points: init, cache management, and the serve-path
+wrappers used by the serving engine and the scheduler."""
 
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, ShapeConfig
+from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.parallel.sharding import logical
@@ -21,8 +19,9 @@ def init_params(cfg: ModelConfig, key):
 
 
 def abstract_params(cfg: ModelConfig):
-    """ShapeDtypeStruct pytree of the parameters — used by the dry-run so
-    no memory is ever allocated for full-size configs."""
+    """ShapeDtypeStruct pytree of the parameters — used by the trainer's
+    sharding specs and the parameter count, so no memory is ever allocated
+    for full-size configs."""
     return jax.eval_shape(lambda k: T.init_params(cfg, k),
                           jax.random.PRNGKey(0))
 
@@ -145,35 +144,3 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
 
 
 forward = T.forward
-
-
-# ==========================================================================
-# Input specs (ShapeDtypeStruct stand-ins for every model input)
-# ==========================================================================
-
-def input_specs(cfg: ModelConfig, shape: ShapeConfig) -> Dict[str, Any]:
-    """Abstract inputs for the given cell.  ``train``: tokens+labels;
-    ``prefill``: prompt tokens; ``decode``: one new token + a cache filled
-    to seq_len.  Modality frontends are stubs: precomputed frame/patch
-    embeddings (per the brief)."""
-    B, S = shape.global_batch, shape.seq_len
-    i32 = jnp.int32
-    bf = jnp.dtype(cfg.dtype)
-    sds = jax.ShapeDtypeStruct
-
-    extras: Dict[str, Any] = {}
-    if cfg.family == "vlm":
-        extras["cross_states"] = sds((B, cfg.frontend_tokens, cfg.d_model), bf)
-    if cfg.family == "audio":
-        extras["frontend_embeds"] = sds((B, cfg.frontend_tokens, cfg.d_model),
-                                        jnp.float32)
-
-    if shape.kind == "train":
-        return {"tokens": sds((B, S), i32), "labels": sds((B, S), i32),
-                **extras}
-    if shape.kind == "prefill":
-        return {"tokens": sds((B, S), i32), **extras}
-    if shape.kind == "decode":
-        cache = jax.eval_shape(lambda: init_cache(cfg, B, S))
-        return {"tokens": sds((B, 1), i32), "cache": cache, **extras}
-    raise ValueError(shape.kind)

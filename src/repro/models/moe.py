@@ -31,10 +31,11 @@ from repro.parallel.sharding import current_policy, logical
 def _moe_axes(E: int):
     """Pick buffer sharding: when the expert dim divides the model axis
     (fine-grained MoE, DeepSeek 64e) shard experts only — adding a capacity
-    axis makes XLA's scatter repartitioning pathological (measured 8.3s ->
-    77s collective on deepseek train_4k).  When experts cannot shard
-    (Mixtral 8e on a 16-way axis) shard capacity over data instead, which
-    keeps the expert FFN compute distributed (44s -> 8.7s compute)."""
+    axis makes XLA's scatter repartitioning pathological (8.3s -> 77s of
+    collectives on a 4k-token deepseek training step).  When experts
+    cannot shard (Mixtral 8e on a 16-way axis) shard capacity over data
+    instead, which keeps the expert FFN compute distributed (44s -> 8.7s
+    compute)."""
     pol = current_policy()
     if pol is None or pol.mesh is None:
         return "expert", None

@@ -7,7 +7,7 @@ RG-LRU recurrence (per channel):
     h_t = a_t * h_{t-1} + sqrt(1 - a_t^2) * (i_t * x_t)
 
 Training/prefill uses an associative scan (log-depth on the sequence);
-decode is a single recurrent update (why long_500k runs for this family).
+decode is a single recurrent update, whatever the context length.
 """
 
 from __future__ import annotations

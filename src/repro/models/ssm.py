@@ -31,8 +31,7 @@ def _segsum(a_chunk):
     return jnp.where(mask, diff, -jnp.inf)
 
 
-def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, return_final: bool = False,
-                unroll: bool = False):
+def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, return_final: bool = False):
     """SSD forward.
 
     x:  [B, S, H, P]   (inputs per head)
@@ -78,14 +77,7 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, return_final: bool = False,
     h0 = jnp.zeros((B, H, P, N), jnp.float32)
     xs = (states.astype(jnp.float32).transpose(1, 0, 2, 3, 4),
           a_tot.astype(jnp.float32).transpose(1, 0, 2))
-    if unroll:
-        h, ys = h0, []
-        for c in range(nc):
-            h, y = step(h, (xs[0][c], xs[1][c]))
-            ys.append(y)
-        h_final, prev_states = h, jnp.stack(ys)
-    else:
-        h_final, prev_states = jax.lax.scan(step, h0, xs)
+    h_final, prev_states = jax.lax.scan(step, h0, xs)
     prev_states = prev_states.transpose(1, 0, 2, 3, 4)  # [B,nc,H,P,N]
 
     # ---- contribution of carried state to each position --------------------
@@ -152,12 +144,11 @@ def mamba2_block(p, x, cfg, *, cache=None):
     A = -jnp.exp(p["a_log"])                         # [H], negative
     new_ssm_state = None
     if cache is None:
-        y = ssd_chunked(xc, dt, A, Bc, Cc, cfg.ssd_chunk,
-                        unroll=cfg.unroll)
+        y = ssd_chunked(xc, dt, A, Bc, Cc, cfg.ssd_chunk)
     elif S > 1:
         # prefill-with-cache: also return the final recurrent state
         y, new_ssm_state = ssd_chunked(xc, dt, A, Bc, Cc, cfg.ssd_chunk,
-                                       return_final=True, unroll=cfg.unroll)
+                                       return_final=True)
     else:
         y1, new_ssm_state = ssd_decode_step(
             cache["ssm"], xc[:, 0], dt[:, 0], A, Bc[:, 0], Cc[:, 0])

@@ -442,19 +442,8 @@ def run_stack(cfg, params, x, *, positions, caches=None, cross_states=None,
         body_fn = body
     scanned_caches = (caches["layers"] if caches is not None
                       else [None] * len(cfg.block_pattern))
-    if cfg.unroll:
-        # cost-probe mode: unrolled super-blocks (see configs/base.py)
-        ys = []
-        for i in range(cfg.n_pattern_blocks):
-            xs_i = jax.tree.map(lambda a: a[i],
-                                (params["blocks"], scanned_caches))
-            x, y = body_fn(x, xs_i)
-            ys.append(y)
-        new_layer_caches, layer_counts = jax.tree.map(
-            lambda *zs: jnp.stack(zs), *ys)
-    else:
-        x, (new_layer_caches, layer_counts) = jax.lax.scan(
-            body_fn, x, (params["blocks"], scanned_caches))
+    x, (new_layer_caches, layer_counts) = jax.lax.scan(
+        body_fn, x, (params["blocks"], scanned_caches))
     if layer_counts is not None:
         counts.append(layer_counts.sum(axis=0))
 
@@ -499,12 +488,7 @@ def encode(cfg, params, frontend_embeds):
         return h, None
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
-    if cfg.unroll:
-        for i in range(cfg.enc_layers):
-            x, _ = body_fn(x, jax.tree.map(lambda a: a[i],
-                                           params["encoder"]))
-    else:
-        x, _ = jax.lax.scan(body_fn, x, params["encoder"])
+    x, _ = jax.lax.scan(body_fn, x, params["encoder"])
     return _norm(cfg, params["enc_final_norm"], x)
 
 

@@ -1,15 +1,15 @@
 """Live metrics: streaming log-bucketed histograms + registry (§15).
 
-The serving benchmarks used to buffer every latency sample and call
-``np.percentile`` after the run; a serving process cannot do that — it
+Buffering every latency sample and calling ``np.percentile`` after the
+run does not serve a live process — it
 needs percentiles *online*, with bounded memory, updated from the same
 event stream everything else reads.  :class:`Histogram` is the standard
 log-bucketed answer: values map to geometric buckets (growth factor
 1.05 ⇒ any percentile is exact to within ±2.5 % relative error), stored
 sparsely, so an arbitrary stream costs O(occupied buckets) memory and
 one dict update per observation.  :class:`MetricsRegistry` names a set
-of histograms + gauges and renders them two ways — a JSON snapshot (the
-benchmarks' one formatting path for stats) and Prometheus text
+of histograms + gauges and renders them two ways — a JSON snapshot (one
+formatting path for stats) and Prometheus text
 exposition (scraped via :mod:`repro.obs.http`).  :class:`MetricsProcessor`
 is the event-stream adapter: a handler-dict processor (same shape as
 ``TimingProcessor``) that folds serving/request/engine events into the
@@ -103,8 +103,8 @@ class Histogram:
 class MetricsRegistry:
     """Named histograms + gauges with two render paths.
 
-    ``snapshot()`` is the JSON dict the benchmarks and the report CLI
-    print; ``prometheus_text()`` is the ``text/plain; version=0.0.4``
+    ``snapshot()`` is the JSON dict the report CLI prints;
+    ``prometheus_text()`` is the ``text/plain; version=0.0.4``
     exposition the scrape endpoint serves.  Counter dicts (the stream's
     flat counters) can be attached and are exported as untyped gauges.
     """
@@ -240,7 +240,7 @@ class MetricsProcessor(Processor):
 
 def counters_table(stats: Dict[str, Any],
                    keys: Optional[List[str]] = None) -> str:
-    """One formatting path for counter dicts (fig6_breakdown, report CLI):
+    """One formatting path for counter dicts (the report CLI):
     aligned ``name value`` rows over the numeric entries of ``stats``."""
     items = [(k, stats[k]) for k in (keys if keys is not None
                                      else sorted(stats))

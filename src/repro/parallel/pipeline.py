@@ -4,9 +4,9 @@ Beyond-paper scale feature: the ``pod`` axis of the multi-pod mesh can act
 as a pipeline axis — each pod holds a contiguous group of super-blocks, and
 microbatches stream through stages with ``jax.lax.ppermute`` moving
 activations between neighbours.  Bubble fraction = (S-1)/(M+S-1) for S
-stages and M microbatches; the dry-run §Perf log quantifies when this beats
-pure DP across pods (it wins when cross-pod DCN gradient all-reduce is the
-bottleneck, because PP sends activations instead of gradients).
+stages and M microbatches; it beats pure DP across pods when cross-pod DCN
+gradient all-reduce is the bottleneck, because PP sends activations instead
+of gradients.
 
 This module is deliberately self-contained and works on any 1-D axis: the
 unit tests run it on a host-device mesh.

@@ -1,11 +1,9 @@
 """Logical-axis sharding rules (DP / FSDP / TP / EP / SP).
 
 Model code annotates arrays with *logical* axis names; this module maps them
-to mesh axes via a rule table, MaxText-style.  The production meshes
-(launch/mesh.py) are:
-
-    single-pod:  (16, 16)            axes ("data", "model")
-    multi-pod:   (2, 16, 16)         axes ("pod", "data", "model")
+to mesh axes via a rule table, MaxText-style.  Meshes are built with
+``launch/mesh.make_mesh`` on axes ("data", "model"), optionally with a
+leading "pod" axis.
 
 Default rules:
     batch       -> ("pod", "data")      # DP across pods and data axis
@@ -16,8 +14,7 @@ Default rules:
     sp          -> ("model",)           # sequence parallel for long-context
     vocab       -> ("model",)
 
-Rules are plain data; the perf loop (§Perf) swaps rule tables to move
-roofline terms.
+Rules are plain data: a caller swaps rule tables to change the layout.
 """
 
 from __future__ import annotations

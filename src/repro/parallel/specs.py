@@ -1,4 +1,4 @@
-"""Parameter / batch / cache PartitionSpecs for the production meshes.
+"""Parameter / batch / cache PartitionSpecs for (data, model) meshes.
 
 Name-pattern rules (Megatron/MaxText-style):
   column-parallel weights  [d, X]      -> (fsdp, tp)       X = heads*hd | d_ff
@@ -53,8 +53,7 @@ def param_spec_for(mesh, path: str, shape: Tuple[int, ...],
     """PartitionSpec for one parameter leaf, identified by its tree path.
 
     ``fsdp=False`` (serve mode): parameters shard over the model axis only
-    — no per-layer all-gather of weight shards at inference (§Perf lever
-    for the collective-bound prefill cells)."""
+    — no per-layer all-gather of weight shards at inference."""
     name = path.split("|")[-1]
     data_axes = ("data",) if fsdp else ()
     nd = len(shape)

@@ -35,7 +35,7 @@ class Request:
     eos_id: int = -1                # -1: never
     out_tokens: Optional[list] = None
     done: bool = False
-    # latency accounting (bench_serving): all three on the same
+    # latency accounting: all three on the same
     # time.perf_counter() clock; arrival defaults to construction time
     arrival_time: Optional[float] = None
     first_token_time: Optional[float] = None

@@ -1,9 +1,9 @@
 """Serving steps: jit-compiled prefill and single-token decode.
 
-``serve_step`` (decode) is what the decode_32k / long_500k dry-run cells
-lower: one new token against a KV/recurrent cache of seq_len, with the
-cache donated for in-place buffer reuse.  Sampling is greedy or
-temperature-categorical; the batched engine drives continuous decoding.
+``serve_step`` (decode) is one new token against a KV/recurrent cache of
+seq_len, with the cache donated for in-place buffer reuse.  Sampling is
+greedy or temperature-categorical; the batched engine drives continuous
+decoding.
 """
 
 from __future__ import annotations

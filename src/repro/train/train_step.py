@@ -1,10 +1,10 @@
 """Train-step builder: loss, microbatched gradient accumulation, remat,
-mixed precision, and the pjit shardings for the production mesh.
+mixed precision, and the pjit shardings for a (data, model) mesh.
 
 ``build_train_step(cfg, opt_cfg, microbatches=k)`` returns a pure function
     step(params, opt_state, batch) -> (params, opt_state, metrics)
-suitable for jax.jit with in/out shardings (launch/dryrun.py) or for
-registration as a single Terra composite op (train/trainer.py).
+suitable for jax.jit with in/out shardings or for registration as a
+single Terra composite op (train/trainer.py).
 """
 
 from __future__ import annotations
@@ -27,8 +27,8 @@ def lm_loss(cfg: ModelConfig, params, tokens, labels, *, extras=None,
 
     The label logit is extracted with a one-hot contraction rather than
     take_along_axis: a gather across the vocab-sharded axis forces XLA to
-    all-gather the full logits (measured ~17 GB/device/step on llama3-8b
-    train_4k, EXPERIMENTS.md §Perf), while the one-hot einsum stays local
+    all-gather the full logits (~17 GB/device/step on llama3-8b at 4k
+    tokens), while the one-hot einsum stays local
     and reduces with a scalar psum."""
     kw = extras or {}
     logits = M.forward(cfg, params, tokens, **kw).astype(jnp.float32)

@@ -30,9 +30,9 @@ from repro.serve.scheduler import pool_ops
 ATTN = ("attn", "attn_swa", "attn_local", "moe")
 
 
-def _cfg(arch, dtype="float32", **kw):
+def _cfg(arch, dtype="float32"):
     return dataclasses.replace(smoke_config(arch), dtype=dtype,
-                               param_dtype=dtype, **kw)
+                               param_dtype=dtype)
 
 
 def _random_cache(cfg, B, max_len, key):
@@ -153,11 +153,10 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("unroll", [False, True], ids=["scan", "unroll"])
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_append_matches_write_then_attend(case, unroll):
+def test_append_matches_write_then_attend(case):
     arch, extra, max_len, positions = CASES[case]
-    cfg = _cfg(arch, unroll=unroll)
+    cfg = _cfg(arch)
     if extra is not None:
         cfg = dataclasses.replace(cfg, extra_blocks=extra,
                                   n_layers=cfg.n_layers + len(extra))

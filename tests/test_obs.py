@@ -3,8 +3,7 @@ validity (JSON, per-track monotone timestamps, request flow completeness)
 for a mid-decode-admission serving run under a profiler session,
 streaming-histogram accuracy against exact rank statistics, Prometheus
 exposition wellformedness, token equality + steady entry with the
-program's spans on, fork-observation distributions, and the
-perf-regression guard's injected-regression failure mode."""
+program's spans on, and fork-observation distributions."""
 
 import gc
 import glob
@@ -12,7 +11,6 @@ import json
 import math
 import os
 import re
-import sys
 
 import numpy as np
 import pytest
@@ -21,6 +19,7 @@ import jax
 
 from repro.configs import smoke_config
 from repro.core import function, ops
+from repro.core.events import JsonlSink, load_jsonl, validate_jsonl
 from repro.core.events import types as T
 from repro.core.events.processors import ListProcessor
 from repro.models import model as M
@@ -28,8 +27,6 @@ from repro.obs import (GROWTH, Histogram, MetricsProcessor, MetricsRegistry,
                        TraceViewerExporter, chrome_trace, counters_table)
 from repro.serve.engine import Request, ServingEngine
 from repro.serve.scheduler import ContinuousBatchingScheduler, SlotPool
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 MAX_LEN = 64
 
@@ -169,6 +166,29 @@ def test_trace_json_valid_and_tracks_monotone(served, tmp_path):
     exp.close()
     with open(exp.path) as f:
         assert json.load(f)["traceEvents"] == evs
+
+
+def test_served_trace_validates_and_exports_through_the_report_cli(
+        served, tmp_path):
+    """The served run's event stream, written by ``JsonlSink``, passes
+    the schema, and ``python -m repro.obs.report`` renders it to the same
+    Perfetto document the exporter builds."""
+    from repro.obs import report
+    path = str(tmp_path / "trace.jsonl")
+    sink = JsonlSink(path)
+    for e in served["events"]:
+        sink.process(e)
+    sink.close()
+    counts = validate_jsonl(path)
+    assert sum(counts.values()) == len(served["events"])
+    for kind in ("RequestSubmit", "StepDispatch", "RunnerComplete"):
+        assert counts.get(kind, 0) > 0, kind
+    out = str(tmp_path / "trace.perfetto.json")
+    assert report.main([path, "--out", out]) == 0
+    with open(out) as f:
+        evs = json.load(f)["traceEvents"]
+    assert len(evs) == len(chrome_trace(load_jsonl(path))["traceEvents"])
+    assert len(evs) > 50
 
 
 def test_trace_request_flows_complete(served):
@@ -312,51 +332,3 @@ def test_fork_observation_distribution():
     dist = next(iter(fam.sel_dist.values()))
     assert sorted(dist) == [0, 1]
     assert sum(dist.values()) == len(obs)
-
-
-# ==========================================================================
-# regression guard
-# ==========================================================================
-
-def _load_serving_baseline():
-    path = os.path.join(os.path.dirname(__file__), "..",
-                        "BENCH_serving.json")
-    with open(path) as f:
-        return json.load(f)
-
-
-def test_check_regression_passes_on_baseline_and_fails_on_injection():
-    from benchmarks.check_regression import SPECS, compare
-    base = _load_serving_baseline()
-    specs = SPECS["BENCH_serving.json"]
-    assert compare(json.loads(json.dumps(base)), base, specs) == []
-    bad = json.loads(json.dumps(base))
-    bad["gates"]["token_equality"] = False          # gate flip
-    bad["gates"]["tracing_ratio"] = 0.5             # profiling cost blowup
-    bad["gates"]["retraces_post_warmup"] = 7        # counter regression
-    del bad["gates"]["speedup_vs_lockstep"]         # schema regression
-    fails = compare(bad, base, specs)
-    assert len(fails) == 4
-    assert any("token_equality" in m for m in fails)
-    assert any("tracing_ratio" in m for m in fails)
-    assert any("retraces_post_warmup" in m for m in fails)
-    assert any("missing from fresh" in m for m in fails)
-
-
-def test_check_regression_cli(tmp_path):
-    from benchmarks.check_regression import main
-    base = _load_serving_baseline()
-    (tmp_path / "base").mkdir()
-    (tmp_path / "fresh").mkdir()
-    for d in ("base", "fresh"):
-        with open(tmp_path / d / "BENCH_serving.json", "w") as f:
-            json.dump(base, f)
-    ok = main(["--base", str(tmp_path / "base"),
-               "--fresh", str(tmp_path / "fresh"), "BENCH_serving.json"])
-    assert ok == 0
-    base["gates"]["terra_vs_noterra"] = 0.01
-    with open(tmp_path / "fresh" / "BENCH_serving.json", "w") as f:
-        json.dump(base, f)
-    bad = main(["--base", str(tmp_path / "base"),
-                "--fresh", str(tmp_path / "fresh"), "BENCH_serving.json"])
-    assert bad == 1

@@ -153,6 +153,7 @@ TRAIN_PROG = """
     print(json.dumps({"outs": outs, "retraces": st["retraces"],
                       "recompiled": st["segments_recompiled"],
                       "hits": st["artifact_hits"],
+                      "misses": st["artifact_misses"],
                       "warm": st["warm_families"],
                       "aot": st["aot_loads"],
                       "stored": st["artifacts_stored"]}))
@@ -169,6 +170,7 @@ def test_warmboot_cross_process(tmp_path):
     assert warm["retraces"] == 0
     assert warm["recompiled"] == 0
     assert warm["hits"] > 0 and warm["warm"] >= 1 and warm["aot"] >= 1
+    assert warm["misses"] == 0
     np.testing.assert_allclose(warm["outs"], cold["outs"], rtol=1e-6)
 
 

@@ -1,6 +1,6 @@
 """Per-architecture smoke tests: reduced same-family configs, one forward
-and one train step on CPU, asserting output shapes and no NaNs.  The FULL
-configs are exercised only via the dry-run (launch/dryrun.py)."""
+and one train step on CPU, asserting output shapes and no NaNs.  Full
+widths run on the chip (``bench/``, ``chip_smoke.py``)."""
 
 import jax
 import jax.numpy as jnp
