@@ -196,8 +196,19 @@ def phase_kernels(cfg, seed: int, *, rows: int = 8, tokens: int = 2048,
         errs["rmsnorm" + "x".join(map(str, shape))] = _max_err(
             f"rmsnorm {shape}", kops.rmsnorm(x, g),
             _oracle(ref.ref_rmsnorm, x, g))
-    return (f"interpret=False, tpu_custom_call in {len(errs)}/{len(errs)} "
-            f"executables, max|kernel-oracle| "
+    # the row append writes what the portable loop writes, bit for bit
+    from repro.models.transformer import append_rows
+    cache, new = normal(4, rows, tokens, Hkv, D), normal(4, rows, Hkv, D)
+    pos = jnp.asarray(([0, 127, 128, tokens - 1, tokens + 5]
+                       + list(rng.permutation(tokens)))[:rows], jnp.int32)
+    assert_compiled(kops.kv_append, cache, new, pos, lead=1)
+    same = np.array_equal(
+        np.asarray(kops.kv_append(cache, new, pos, lead=1)).view(np.uint16),
+        np.asarray(append_rows(cache, new, pos, lead=1)).view(np.uint16))
+    check(same, "kernels", "kv_append differs from the append loop")
+    return (f"interpret=False, tpu_custom_call in {len(errs) + 1}/"
+            f"{len(errs) + 1} executables, kv_append == loop bit for bit, "
+            f"max|kernel-oracle| "
             + " ".join(f"{k}={v:.3g}" for k, v in errs.items())
             + f" (tol {KERNEL_ATOL}+{KERNEL_RTOL}*|oracle|)")
 
@@ -264,6 +275,9 @@ def phase_serve_dense(cfg, params, workload, warm_lens, knobs):
                                       warm_lens=warm_lens, use_terra=True,
                                       **knobs)
     summary = check_served("serve(dense)", tre, s0, st)
+    check(st["kv_append_kernel_leaves"] == st["kv_append_leaves"] == 2,
+          "serve(dense)", f"kv_append_kernel_leaves="
+          f"{st['kv_append_kernel_leaves']} of {st['kv_append_leaves']}")
     nre, nwall, _, _, _ = serve_arm(cfg, params, workload,
                                     warm_lens=warm_lens, use_terra=False,
                                     **knobs)
@@ -275,8 +289,8 @@ def phase_serve_dense(cfg, params, workload, warm_lens, knobs):
           f"greedy tokens differ terra vs noterra in "
           f"{len(tre) - same}/{len(tre)} requests")
     ntok = sum(len(r.out_tokens) for r in tre)
-    return tre, (f"{summary}, greedy tokens terra == noterra "
-                 f"({ntok}/{ntok})")
+    return tre, (f"{summary}, kv_append writes 2/2 leaves, greedy tokens "
+                 f"terra (kernel) == noterra (loop) ({ntok}/{ntok})")
 
 
 def segment_text(sch, op_name: str) -> str:
@@ -309,7 +323,7 @@ def phase_serve_paged(cfg, params, workload, warm_lens, knobs, dense_reqs):
     reqs, wall, s0, st, text = serve_arm(
         cfg, params, workload, warm_lens=warm_lens, use_terra=True,
         page_size=PAGE_SIZE,
-        inspect=lambda sch: segment_text(sch, "kernel.slot_decode_paged"),
+        inspect=lambda sch: segment_text(sch, "kernel.slot_decode"),
         **knobs)
     summary = check_served("serve(paged)", reqs, s0, st)
     check(st["kernels_substituted"] >= 1, "serve(paged)",

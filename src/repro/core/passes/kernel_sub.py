@@ -22,10 +22,17 @@ Patterns:
   annotations are untouched; the intermediates must have no consumers
   outside the pattern (in particular no ``.vjp`` tape consumers — a
   differentiated attention keeps its unfused form) and fall to DCE.
+* **slot decode** — the serving pool's ``serve.slot_decode`` node is
+  retargeted to ``kernel.slot_decode``, the same op traced with the
+  decode kernels on, where its pool has one to run: a paged pool attends
+  through the paged-attention kernel, and a dense pool whose cache has a
+  leaf stored positions minor (``kv_append.engages``, DESIGN.md §11)
+  writes that leaf's appended rows with ``kv_append``.  The cache it
+  leaves is bit-identical.
 
 On TPU backends, where the kernels compile natively, ``optimize="all"``
 and ``"safe"`` both run the pass (it bakes no Python value into the
-graph), so the paged serving pool reaches its kernel by default.
+graph), so the serving pool reaches its kernels by default.
 Elsewhere it must be named explicitly (interpret-mode Pallas validates
 numerics but is not fast).
 """
@@ -62,27 +69,31 @@ def _kattn_impl(q, k, v, *, causal=True):
     return out[:, 0]
 
 
-def _kpaged_decode_impl(*leaves, **attrs):
+def _kslot_decode_impl(*leaves, **attrs):
     from repro.serve.scheduler import pool_ops
     return pool_ops._slot_decode_kernel_impl(*leaves, **attrs)
+
+
+def _decode_kernels_apply(n) -> bool:
+    """True when a ``serve.slot_decode`` node's pool runs a decode kernel:
+    it is paged, or a cache leaf (an output after the token, its slot axis
+    from the pool's meta) is one ``kv_append`` writes."""
+    from repro.kernels import kv_append
+    from repro.serve.scheduler import pool_ops
+    attrs = dict(n.attrs)
+    meta = pool_ops.pool_meta(attrs["_meta"])
+    leaves = n.out_avals[1:1 + attrs["_n_cache"]]
+    return bool(meta.page_size) or any(
+        ax >= 0 and kv_append.engages(a.shape, a.dtype, ax)
+        for a, ax in zip(leaves, meta.batch_axes))
 
 
 if "kernel.rms_norm" not in ops_mod.OPS:
     ops_mod.def_op("kernel.rms_norm", _krms_impl)
     ops_mod.def_op("kernel.attention", _kattn_impl)
-    ops_mod.def_op("kernel.slot_decode_paged", _kpaged_decode_impl)
+    ops_mod.def_op("kernel.slot_decode", _kslot_decode_impl)
     ops_mod._NONDIFF_OPS.update({"kernel.rms_norm", "kernel.attention",
-                                 "kernel.slot_decode_paged"})
-
-
-def _paged_decode_meta(n) -> bool:
-    """True when a ``serve.slot_decode`` node steps a paged pool — the
-    only decode class the paged-attention kernel applies to."""
-    try:
-        from repro.serve.scheduler import pool_ops
-        return pool_ops.pool_meta(dict(n.attrs)["_meta"]).page_size > 0
-    except Exception:
-        return False
+                                 "kernel.slot_decode"})
 
 
 # --------------------------------------------------------------------------
@@ -248,10 +259,11 @@ def run(ctx) -> None:
         n = otg.nodes[uid]
         if n.kind != "op" or uid in opt.dead or uid in opt.alias_nodes:
             continue
-        if n.op_name == "serve.slot_decode" and _paged_decode_meta(n):
-            # same leaves, same attrs, same outputs — only the attention
-            # inner loop changes (Pallas kernel vs gather + dense softmax)
-            n.op_name = "kernel.slot_decode_paged"
+        if n.op_name == "serve.slot_decode" and _decode_kernels_apply(n):
+            # same leaves, same attrs, same outputs — only the paged
+            # attention (Pallas kernel vs gather + dense softmax) or the
+            # dense pool's row append (kv_append vs a loop) changes
+            n.op_name = "kernel.slot_decode"
             n._sig_cache = None
             substituted += 1
         elif n.op_name == "rms_norm":

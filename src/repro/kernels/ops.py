@@ -12,6 +12,7 @@ import functools
 import jax
 
 from repro.kernels import flash_attention as _fa
+from repro.kernels import kv_append as _kv
 from repro.kernels import paged_attention as _pa
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import ssd_scan as _ss
@@ -58,3 +59,9 @@ def rmsnorm(x, g, *, eps: float = 1e-6, row_block: int = 256,
         return _ref.ref_rmsnorm(x, g, eps)
     return _rn.rmsnorm(x, g, eps=eps, row_block=row_block,
                        interpret=_interpret_default())
+
+
+@functools.partial(jax.jit, static_argnames=("lead",))
+def kv_append(cache, rows, pos, *, lead: int):
+    return _kv.kv_append(cache, rows, pos, lead=lead,
+                         interpret=_interpret_default())

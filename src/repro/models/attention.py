@@ -21,11 +21,13 @@ from repro.parallel.sharding import logical
 
 NEG_INF = -1e30
 
-# Trace-time switch: when True, paged-cache decode attends through the
-# Pallas paged-attention kernel instead of the gather + dense reference
-# path.  Flipped by the kernel-substituted ``kernel.slot_decode_paged``
-# op around its trace (pass pipeline ``kernels``, DESIGN.md §12).
-PAGED_KERNEL = False
+# Trace-time switch: when True, the decode step runs its Pallas kernels:
+# paged-cache decode attends through the paged-attention kernel instead of
+# the gather + dense reference path, and a dense pool writes its appended
+# rows with ``kv_append`` (``transformer._settle``).  Flipped by the
+# kernel-substituted ``kernel.slot_decode`` op around its trace (pass
+# pipeline ``kernels``, DESIGN.md §10-§12).
+DECODE_KERNELS = False
 
 
 def _pick_block(s: int, target: int) -> int:
@@ -224,7 +226,7 @@ def attention_block(p, x, cfg, *, positions=None, cache=None,
         kp = kp.reshape(nblk * bs, Hkv, D).at[dest].set(kv).reshape(kp.shape)
         vp = vp.reshape(nblk * bs, Hkv, D).at[dest].set(vv).reshape(vp.shape)
         new_cache = {"kp": kp, "len": idx + 1, "vp": vp}
-        if PAGED_KERNEL:
+        if DECODE_KERNELS:
             from repro.kernels import ops as kops
             out = kops.paged_attention(q, kp, vp, bt, idx + 1, window=window)
         else:

@@ -21,6 +21,7 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.models import attention as A
 from repro.models import layers as L
 from repro.models.attention import attention_block
 from repro.models.mla import mla_attention
@@ -377,14 +378,28 @@ def _appended(out) -> int:
     return 0 if out is None else sum(1 for k in out if k.endswith("_row"))
 
 
-def _settle(cache, out, pos, lead: int):
+def _settle(cache, out, pos, lead: int, record=None):
     """A block's cache after the step: the rows a single-token decode
     appended (``<leaf>_row``), written into ``cache``; else the cache the
-    block returned.  ``lead`` is 1 for a scanned (stacked) cache."""
+    block returned.  ``lead`` is 1 for a scanned (stacked) cache.  While
+    ``attention.DECODE_KERNELS`` is set, a leaf the TPU stores with
+    positions minor (``kv_append.engages``) is written by the ``kv_append``
+    kernel, counted in ``record["kv_append_kernel_leaves"]``; any other
+    leaf by ``append_rows``, the portable loop."""
     if not _appended(out):
         return out
-    return {name: append_rows(leaf, out[name + "_row"], pos, lead=lead)
-            for name, leaf in cache.items()}
+    from repro.kernels import kv_append, ops as kops
+    new = {}
+    for name, leaf in cache.items():
+        rows = out[name + "_row"]
+        if A.DECODE_KERNELS and kv_append.engages(leaf.shape, leaf.dtype,
+                                                  lead):
+            new[name] = kops.kv_append(leaf, rows, pos, lead=lead)
+            if record is not None:
+                record["kv_append_kernel_leaves"] += 1
+        else:
+            new[name] = append_rows(leaf, rows, pos, lead=lead)
+    return new
 
 
 def _unscanned(cfg, kinds, params, caches, x, counts, **kw):
@@ -407,11 +422,12 @@ def run_stack(cfg, params, x, *, positions, caches=None, cross_states=None,
     written after the layer loop (``append_rows``, DESIGN.md §11).
     ``record``, if given, receives at trace time ``kv_append_leaves``: the
     number of cache leaves this step writes by appending (0 when the path
-    did not engage), and ``experts_held`` where dropless expert blocks
-    ran.  With ``caches``, those blocks' routed pairs per row and held
-    expert [B, n], over the tokens ``valid`` [B, S] marks, are added to
-    ``caches["expert_tokens"]`` (zero where absent) in the returned
-    caches.
+    did not engage), ``kv_append_kernel_leaves``: how many of them the
+    ``kv_append`` kernel writes, and ``experts_held`` where dropless
+    expert blocks ran.  With ``caches``, those blocks' routed pairs per
+    row and held expert [B, n], over the tokens ``valid`` [B, S] marks,
+    are added to ``caches["expert_tokens"]`` (zero where absent) in the
+    returned caches.
     """
     x = logical(x, "batch", None, None)
     cache_len = caches["len"] if caches is not None else None
@@ -456,16 +472,17 @@ def run_stack(cfg, params, x, *, positions, caches=None, cross_states=None,
     outs = new_lead + list(new_layer_caches) + new_extra
     if record is not None:
         record["kv_append_leaves"] = sum(_appended(o) for o in outs)
+        record["kv_append_kernel_leaves"] = 0
         if counts:
             record["experts_held"] = cfg.held_experts[1]
     new_caches = {
-        "layers": [_settle(c, o, cache_len, 1)
+        "layers": [_settle(c, o, cache_len, 1, record)
                    for c, o in zip(scanned_caches, new_layer_caches)],
-        "extra": [_settle(c, o, cache_len, 0)
+        "extra": [_settle(c, o, cache_len, 0, record)
                   for c, o in zip(caches["extra"], new_extra)],
         "len": cache_len + x.shape[1]}
     if cfg.lead_blocks:
-        new_caches["lead"] = [_settle(c, o, cache_len, 0)
+        new_caches["lead"] = [_settle(c, o, cache_len, 0, record)
                               for c, o in zip(caches["lead"], new_lead)]
     if counts:
         new_caches["expert_tokens"] = (caches.get("expert_tokens", 0)

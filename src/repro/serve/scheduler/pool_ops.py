@@ -332,15 +332,15 @@ def _slot_decode_impl(*leaves, _meta: int, _n_params: int, _n_cache: int,
 
 
 def _slot_decode_kernel_impl(*leaves, **attrs):
-    """Paged decode with the Pallas paged-attention kernel enabled; the
-    flag is read at trace time, so the substituted node compiles the
+    """Decode with the Pallas kernels on (paged attention, ``kv_append``);
+    the flag is read at trace time, so the substituted node compiles the
     kernel path while the math (and the op signature) stays identical."""
     from repro.models import attention as A
-    prev, A.PAGED_KERNEL = A.PAGED_KERNEL, True
+    prev, A.DECODE_KERNELS = A.DECODE_KERNELS, True
     try:
         return _slot_decode_impl(*leaves, **attrs)
     finally:
-        A.PAGED_KERNEL = prev
+        A.DECODE_KERNELS = prev
 
 
 def program(op: str):

@@ -50,17 +50,20 @@ def make_stream(engine_events: Optional[EventStream],
 def merged_stats(sch) -> dict:
     """The scheduler's flat ``stats`` view: the shared counter dict (which
     already holds the engine counters under co-execution), the callback /
-    pool gauges, the decode step's appended cache leaves (set when it is
-    traced), the process's GC pauses and compiles since the stream
-    was made, and the engine phase.  A stack with dropless expert blocks
-    adds ``experts_held`` (set when the decode step is traced),
-    ``expert_tokens`` (routed pairs of real tokens per held expert, decode
-    and prefill, read from the pool's device counter here and nowhere
-    else) and ``expert_dropped`` (0: the layer has no capacity)."""
+    pool gauges, the decode step's appended cache leaves and those the
+    ``kv_append`` kernel writes (set when it is traced), the process's GC
+    pauses and compiles since the stream was made, and the engine phase.
+    A stack with dropless expert blocks adds ``experts_held`` (set when
+    the decode step is traced), ``expert_tokens`` (routed pairs of real
+    tokens per held expert, decode and prefill, read from the pool's
+    device counter here and nowhere else) and ``expert_dropped`` (0: the
+    layer has no capacity)."""
     out = dict(sch.sched_stats)
     out["callbacks_delivered"] = sch.callbacks.delivered
     out["peak_resident_tokens"] = sch.pool.peak_resident_tokens
     out["kv_append_leaves"] = pool_ops.kv_append_leaves(sch._mid)
+    out["kv_append_kernel_leaves"] = pool_ops.pool_meta(
+        sch._mid).trace.get("kv_append_kernel_leaves", 0)
     out.update(expert_counters(sch))
     out.update(sch.events.runtime_counters())
     if sch.use_terra:

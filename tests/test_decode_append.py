@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from repro.configs import smoke_config
+from repro.kernels import kv_append as K
 from repro.models import layers as L
 from repro.models import model as M
 from repro.models import transformer as T
@@ -234,7 +235,7 @@ def test_prefill_does_not_append():
     x = L.embed(params["embed"], jnp.zeros((2, 8), jnp.int32))
     T.run_stack(cfg, params, x, positions=jnp.arange(8)[None],
                 caches=M.init_cache(cfg, 2, 64), record=record)
-    assert record == {"kv_append_leaves": 0}
+    assert record == {"kv_append_leaves": 0, "kv_append_kernel_leaves": 0}
 
 
 def _serve(cfg, params, **kw):
@@ -270,3 +271,57 @@ def test_kv_append_leaves_zero_on_paged_pool():
     cfg = smoke_config("llama3-8b")
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     assert _serve(cfg, params, page_size=16) == (0, 0)
+
+
+# (leaf shape, lead, positions): Smax 256 holds two tiles; the rows sit on
+# both sides of a tile edge, at the last position, and beyond max_len
+# (clamped to it); an int ``positions`` is a lock-step scalar ``len``
+KERNEL_CASES = {
+    "heads-8x64": ((3, 5, 256, 8, 64), 1, [0, 127, 128, 255, 261]),
+    "latent-576": ((2, 5, 256, 576), 1, [0, 127, 128, 255, 261]),
+    "latent-576-lead0": ((5, 256, 576), 0, [261, 255, 128, 127, 0]),
+    "heads-scalar-pos": ((3, 4, 256, 8, 64), 1, 130),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kv_append_kernel_matches_loop(case):
+    """The Pallas row append (interpret mode) leaves the cache the loop
+    leaves, bit for bit, and changes no position but the rows' own."""
+    shape, lead, positions = KERNEL_CASES[case]
+    assert K.engages(shape, jnp.bfloat16, lead)
+    B, Smax = shape[lead], shape[lead + 1]
+    cache = jax.random.normal(jax.random.PRNGKey(7), shape, jnp.bfloat16)
+    rows = jax.random.normal(jax.random.PRNGKey(8),
+                             shape[:lead + 1] + shape[lead + 2:],
+                             jnp.bfloat16)
+    pos = jnp.asarray(positions, jnp.int32)
+    got = K.kv_append(cache, rows, pos, lead=lead, interpret=True)
+    want = T.append_rows(cache, rows, pos, lead=lead)
+    got, want, old = (np.asarray(x).view(np.uint16)
+                      for x in (got, want, cache))
+    np.testing.assert_array_equal(got, want)
+    # [Lr, B, Smax, F]: only each row's (clamped) position changed
+    got, old = (x.reshape((-1, B, Smax, int(np.prod(shape[lead + 2:]))))
+                for x in (got, old))
+    keep = np.ones((B, Smax), bool)
+    keep[np.arange(B), np.minimum(np.broadcast_to(positions, (B,)),
+                                  Smax - 1)] = False
+    np.testing.assert_array_equal(got[:, keep], old[:, keep])
+    assert not np.array_equal(got[:, ~keep], old[:, ~keep])
+
+
+@pytest.mark.parametrize("shape,dtype,lead,want", [
+    ((40, 16, 2048, 8, 64), jnp.bfloat16, 1, True),     # granite K/V
+    ((26, 32, 2048, 576), jnp.bfloat16, 1, True),       # dsv2 latent
+    ((32, 2048, 576), jnp.bfloat16, 0, True),           # dsv2 lead block
+    ((2, 4, 256, 2, 16), jnp.float32, 1, True),         # smoke widths
+    ((4, 16, 2048, 8, 128), jnp.bfloat16, 1, False),    # position-major
+    ((2, 4, 64, 2, 16), jnp.float32, 1, False),         # under one tile
+    ((2, 4, 256, 2, 8), jnp.bfloat16, 1, False),        # half a bf16 tile
+], ids=["granite", "dsv2", "dsv2-lead", "smoke-f32", "head_dim-128",
+        "smax-64", "d8-bf16"])
+def test_kv_append_engages_positions_minor_leaves(shape, dtype, lead, want):
+    """The kernel takes a leaf the TPU stores positions-minor; the others
+    keep the loop."""
+    assert K.engages(shape, dtype, lead) is want

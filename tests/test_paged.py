@@ -234,6 +234,36 @@ def test_paged_kernel_substitution_in_scheduler(llama):
         assert x.out_tokens == y.out_tokens, f"request {i}"
 
 
+@pytest.mark.parametrize("max_len, kernel_leaves", [(128, 2), (64, 0)])
+def test_dense_append_kernel_substitution_in_scheduler(max_len, kernel_leaves):
+    """With the ``kernels`` pass named, a dense pool's decode node is
+    retargeted too, and its appended K/V rows go through the Pallas
+    ``kv_append`` kernel (interpret-mode off-TPU): both cache leaves of a
+    granite-like pool whose 128 positions fill one tile; tokens identical
+    to the loop's run, where no leaf takes the kernel.  A pool of 64
+    positions, less than a tile, has no leaf the kernel writes: its
+    decode node keeps its op and counts no substitution."""
+    cfg = smoke_config("granite-3-2b")
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    lens, mns = [5, 9], [4, 3]
+    served = {}
+    for optimize in (("cse", "kernels", "dce", "coalesce"), "none"):
+        sch = ContinuousBatchingScheduler(cfg, params, max_slots=2,
+                                          max_len=max_len, optimize=optimize)
+        reqs = make_requests(cfg, lens, mns)
+        sch.serve(reqs)
+        st = sch.stats
+        served[optimize == "none"] = (
+            [r.out_tokens for r in reqs], st["kv_append_leaves"],
+            st["kv_append_kernel_leaves"], st["kernels_substituted"])
+        sch.close()
+    kern, loop = served[False], served[True]
+    assert kern[1:3] == (2, kernel_leaves), kern[1:]
+    assert (kern[3] >= 1) == (kernel_leaves > 0), kern[1:]
+    assert loop[1:] == (2, 0, 0), loop[1:]
+    assert kern[0] == loop[0]
+
+
 # ==========================================================================
 # zero-walker steady state
 # ==========================================================================

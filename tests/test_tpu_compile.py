@@ -24,7 +24,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core.passes import kernel_sub  # noqa: F401 - kernel.slot_decode
 from repro.core.persist.aot import segment_specs
+from repro.kernels import ops as kops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.rmsnorm import rmsnorm
@@ -112,9 +114,10 @@ def _slot_decode(sharding, donate: bool):
     return compiled, shapes
 
 
-def _compile_decode(sharding, cfg, slots: int, donate: bool):
-    """Compile ``serve.slot_decode`` over a dense ``slots`` x 2048 pool;
-    returns (compiled, cache leaf shapes, pool meta id)."""
+def _compile_decode(sharding, cfg, slots: int, donate: bool,
+                    op: str = "serve.slot_decode"):
+    """Compile ``op`` (a slot-pool decode) over a dense ``slots`` x 2048
+    pool; returns (compiled, cache leaf shapes, pool meta id)."""
 
     def spec(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
@@ -131,7 +134,7 @@ def _compile_decode(sharding, cfg, slots: int, donate: bool):
         _spec(sharding, slots, dtype=jnp.int32),
         _spec(sharding, slots, 1, dtype=jnp.int32)]
     donated = (tuple(range(len(p_leaves), len(state))) if donate else ())
-    step = jax.jit(pool_ops.program("serve.slot_decode"),
+    step = jax.jit(pool_ops.program(op),
                    static_argnames=tuple(attrs), donate_argnums=donated)
     compiled = step.lower(*state, _spec(sharding, slots, dtype=jnp.bool_),
                           **attrs).compile()
@@ -225,3 +228,49 @@ def test_latent_decode_appends_without_relayout(one_chip, donate):
         # the step may not write its input: one input-to-output copy each
         assert len(whole) <= len(shapes), whole
         assert temp < 8 * MIB, temp / MIB
+
+
+# --------------------------------------------------------------------------
+# the kernels pass's decode: appended rows through the kv_append kernel
+# --------------------------------------------------------------------------
+
+KERNEL_DECODES = {
+    # (config, slots, leaves the kernel writes)
+    "granite": (dataclasses.replace(get_config("granite-3-2b"),
+                                    n_layers=LAYERS), SLOTS, 2),
+    "dsv2": (dataclasses.replace(get_config("deepseek-v2-lite"), n_layers=3,
+                                 experts_held=8), MLA_SLOTS, 2),
+    # head_dim 128: the leaf is stored position-major, so the loop stays
+    "head_dim-128": (dataclasses.replace(get_config("granite-3-2b"),
+                                         n_layers=LAYERS, head_dim=128),
+                     SLOTS, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_DECODES))
+def test_kernel_slot_decode_appends_in_place(one_chip, monkeypatch, case):
+    """``kernel.slot_decode``, donated, as the ``kernels`` pass leaves the
+    co-executed decode on a TPU: granite's K/V leaves and DeepSeek-V2's two
+    latent leaves are written by the ``kv_append`` Pallas call, in their
+    stored layout; no copy as large as a layer's slice of a leaf appears,
+    and the temp stays small.  A head_dim-128 leaf keeps the loop and
+    copies no whole leaf (its layer slices are re-laid out for attention
+    inside the scan, with or without the kernel)."""
+    cfg, slots, want = KERNEL_DECODES[case]
+    monkeypatch.setattr(kops, "_interpret_default", lambda: False)
+    compiled, shapes, mid = _compile_decode(one_chip, cfg, slots, True,
+                                            op="kernel.slot_decode")
+    text = compiled.as_text()
+    assert pool_ops.kv_append_leaves(mid) == 2
+    assert pool_ops.pool_meta(mid).trace["kv_append_kernel_leaves"] == want
+    calls = re.findall(r"%kv_append\S* = \S+ custom-call\(.*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == want, calls
+    copies = _copied_shapes(text)
+    assert not [c for c in copies if c in shapes], copies
+    if want:
+        slice_size = min(np.prod(s[1:]) for s in shapes if len(s) >= 4)
+        big = [c for c in copies if np.prod(c) >= slice_size]
+        assert not big, big
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 4 * MIB, temp / MIB
